@@ -252,7 +252,7 @@ def test_half_open_not_advanced_by_sigcache_hits(monkeypatch):
     reach the device dispatch, so a flush served entirely from the
     verified-signature cache must NOT count as a breaker success — only
     a REAL device round-trip may advance half_open → closed. A wedged
-    tunnel would otherwise be declared healthy on the strength of
+    device would otherwise be declared healthy on the strength of
     verifications it never ran."""
     from tmtpu.config.config import CryptoConfig
     from tmtpu.crypto import batch as crypto_batch

@@ -97,269 +97,65 @@ def test_verify_commit_10k_device_tally_counts_only_block_votes():
 
 
 def test_100_validator_net_commits_through_device_batches(monkeypatch):
-    """BASELINE's 100-validator config through LIVE consensus: one running
-    validator node (power 1000) plus 99 scripted co-signers (power 10
-    each; 2/3 of 1990 needs the node + >=33 of them). When the node
-    proposes height 1, the harness injects all 99 prevotes and 99
-    precommits at once; the consensus batch-drain loop verifies those
-    bursts through the device graph in fused ~99-lane dispatches with the
-    on-device power tally. Asserts height 1 commits and that at least one
-    dispatch actually rode the 128-lane device bucket."""
-    import time as _time
-
-    from tmtpu.abci.example.kvstore import KVStoreApplication
-    from tmtpu.consensus.state import ConsensusState
-    from tmtpu.config.config import ConsensusConfig
-    from tmtpu.libs.db import MemDB
-    from tmtpu.proxy import AppConns, LocalClientCreator
-    from tmtpu.state.execution import BlockExecutor
-    from tmtpu.state.state import state_from_genesis
-    from tmtpu.state.store import StateStore
-    from tmtpu.store.block_store import BlockStore
+    """BASELINE's 100-validator config through LIVE consensus
+    (tmtpu/e2e/flood_round.py): one running validator plus 99 scripted
+    co-signers. When the node proposes height 1, all 99 prevotes and 99
+    precommits arrive at once; the consensus batch-drain loop verifies
+    those bursts through the device graph in fused ~99-lane dispatches
+    with the on-device power tally. Asserts height 1 commits and that
+    the flood rode wide device dispatches."""
+    from tmtpu.e2e import flood_round
     from tmtpu.tpu import verify as tv
-    from tmtpu.types.event_bus import EventBus
-    from tmtpu.types.genesis import GenesisDoc, GenesisValidator
-    from tmtpu.types.priv_validator import MockPV
 
-    monkeypatch.setattr(crypto_batch, "_TPU_MIN_BATCH", 16)
+    # restored by monkeypatch after flood_round.run() selects "tpu"
     monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
-    monkeypatch.setattr(crypto_batch, "_tpu_usable", True)
-    # one jit shape for everything: sub-16 batches verify serially, larger
-    # bursts pad to the single 128-lane bucket (one ~90 s CPU compile
-    # instead of one per drain size)
+    # one jit shape for everything (one ~90 s XLA:CPU compile instead of
+    # one per drain size); chip_smoke.py runs the production buckets
     monkeypatch.setattr(tv, "_pad_to_bucket", lambda n: 128)
-    # the warmup adds one vote 16x and the asserts count raw dispatch
-    # lanes — verify-once dedup/caching would collapse both, so run this
-    # scenario cache-off (tests/test_sigcache.py covers cache-on)
-    from tmtpu.crypto import sigcache
 
-    sigcache.DEFAULT.set_enabled(False)
-
-    live_pv = MockPV()
-    co_pvs = [MockPV() for _ in range(99)]
-    gen = GenesisDoc(
-        chain_id=CHAIN_ID, genesis_time=time.time_ns(),
-        validators=[GenesisValidator(live_pv.get_pub_key(), 1000)]
-        + [GenesisValidator(pv.get_pub_key(), 10) for pv in co_pvs],
-    )
-    genesis_state = state_from_genesis(gen)
-    vals = genesis_state.validators
-    assert vals.get_proposer().pub_key.equals(live_pv.get_pub_key()), \
-        "highest-power validator must propose height 1"
-    idx_by_addr = {v.address: i for i, v in enumerate(vals.validators)}
-
-    # warm the single bucket for the fused verify+tally graph
-    bv = crypto_batch.new_batch_verifier("tpu")
-    wvals, wpvs = mk_valset(1)
-    warm = mk_vote(wpvs[0], wvals, 0)
-    for _ in range(16):
-        bv.add(wvals.validators[0].pub_key, warm.sign_bytes(CHAIN_ID),
-               warm.signature, power=1)
-    all_ok, *_ = bv.verify_tally()
-    assert all_ok
-
-    app = KVStoreApplication()
-    conns = AppConns(LocalClientCreator(app))
-    conns.start()
-    state_store = StateStore(MemDB())
-    state_store.save(genesis_state)
-    bus = EventBus()
-    exec_ = BlockExecutor(state_store, conns.consensus, event_bus=bus)
-    cs = ConsensusState(
-        ConsensusConfig.test_config(), genesis_state, exec_,
-        BlockStore(MemDB()), event_bus=bus, priv_validator=live_pv,
-    )
-    cs.verify_backend = "tpu"
-
-    dispatched = []
-    real_run = crypto_batch.TPUBatchVerifier._verify_pending
-
-    def spy_run(self, items, tally):
-        if len(items) >= 16:
-            dispatched.append(len(items))
-        return real_run(self, items, tally)
-
-    monkeypatch.setattr(crypto_batch.TPUBatchVerifier, "_verify_pending",
-                        spy_run)
-
-    def on_proposal(proposal, parts):
-        if proposal.height != 1:
-            return
-        for vtype in (PREVOTE, PRECOMMIT):
-            for pv in co_pvs:
-                addr = pv.get_pub_key().address()
-                v = Vote(type=vtype, height=proposal.height,
-                         round=proposal.round, block_id=proposal.block_id,
-                         timestamp=_time.time_ns(),
-                         validator_address=addr,
-                         validator_index=idx_by_addr[addr])
-                pv.sign_vote(CHAIN_ID, v)
-                # one relay peer for all co-signers: the consensus drain
-                # groups votes per peer before dispatching, exactly like a
-                # gossiping reactor peer relaying the whole net's votes
-                cs.add_vote_msg(v, peer_id="relay")
-
-    cs.on_own_proposal = on_proposal
-    try:
-        cs.start()
-        # wait_for_height(h) waits for rs.height > h, i.e. height h
-        # committed; the scripted co-signers only vote at height 1, so the
-        # chain ends there by design
-        assert cs.wait_for_height(1, timeout=600), \
-            f"stuck at {cs.rs.height_round_step()}"
-    finally:
-        cs.stop()
-        conns.stop()
-    blk = cs.block_store.load_block(1)
-    assert blk is not None
-    commit = cs.block_store.load_seen_commit(1)
-    assert commit is not None and len(commit.signatures) == 100
-    assert dispatched and max(dispatched) >= 33, \
-        f"expected a fused >=33-lane device dispatch, got {dispatched}"
+    r = flood_round.run(99, backend="tpu", timeout=600)
+    assert r["precommits_in_commit"] >= 67
+    assert [w[1] for w in r["warmed"]] == [8, 100]   # one 128 shape
+    assert r["lanes_dispatched"] >= 99
+    assert r["lanes_dispatched"] / r["dispatches"] >= 16, r
 
 
 def test_10k_validator_live_consensus_round(monkeypatch):
-    """MaxVotesCount-scale LIVE consensus (VERDICT r2 weak #5): one running
-    validator node plus 9,999 MockPV co-signers whose prevotes + precommits
-    flood the receive loop when the node proposes height 1. The batch-drain
-    window (consensus/state.py receive loop) must absorb the ~20k-vote
-    flood in a handful of fused device dispatches — votes/dispatch >> 1 —
-    and the height must commit. Records round latency and dispatch shapes
-    (PERF.md "10k live consensus" entry)."""
-    import threading
-    import time as _time
-
-    from tmtpu.abci.example.kvstore import KVStoreApplication
-    from tmtpu.consensus.state import ConsensusState
-    from tmtpu.config.config import ConsensusConfig
-    from tmtpu.libs.db import MemDB
-    from tmtpu.proxy import AppConns, LocalClientCreator
-    from tmtpu.state.execution import BlockExecutor
-    from tmtpu.state.state import state_from_genesis
-    from tmtpu.state.store import StateStore
-    from tmtpu.store.block_store import BlockStore
+    """MaxVotesCount-scale LIVE consensus: one running validator node
+    plus 9,999 MockPV co-signers whose prevotes + precommits flood the
+    receive loop when the node proposes height 1. The batch-drain window
+    (consensus/state.py receive loop) must absorb the ~20k-vote flood in
+    a handful of fused device dispatches — votes/dispatch >> 1 — and the
+    height must commit."""
+    from tmtpu.e2e import flood_round
     from tmtpu.tpu import verify as tv
-    from tmtpu.types.event_bus import EventBus
-    from tmtpu.types.genesis import GenesisDoc, GenesisValidator
-    from tmtpu.types.priv_validator import MockPV
 
     n_co = 9_999
-    monkeypatch.setattr(crypto_batch, "_TPU_MIN_BATCH", 16)
     monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
-    monkeypatch.setattr(crypto_batch, "_tpu_usable", True)
-    # ONE jit shape: every >=16-lane burst pads to the 10240 bucket the
-    # real 10k VoteSet uses (sub-16 bursts — the node's own votes — go
-    # serial), so the minutes-scale XLA:CPU compile happens once, up front
+    # ONE jit shape: every device burst pads to the 10240 bucket the
+    # real 10k VoteSet uses, so the minutes-scale XLA:CPU compile
+    # happens once, in the warm-up before consensus starts — and on one
+    # device: a whole-commit warm-up flush would otherwise compile the
+    # 8-virtual-device mesh graph this test never dispatches to
     monkeypatch.setattr(tv, "_pad_to_bucket", lambda n: 10_240)
-    # identical-vote warmup + raw dispatch-lane accounting: cache-off
-    # (see test_100_validator_net note)
-    from tmtpu.crypto import sigcache
+    monkeypatch.setenv("TMTPU_MESH_DEVICES", "1")
 
-    sigcache.DEFAULT.set_enabled(False)
-
-    live_pv = MockPV()
-    co_pvs = [MockPV() for _ in range(n_co)]
-    gen = GenesisDoc(
-        chain_id=CHAIN_ID, genesis_time=time.time_ns(),
-        validators=[GenesisValidator(live_pv.get_pub_key(), 40)]
-        + [GenesisValidator(pv.get_pub_key(), 1) for pv in co_pvs],
-    )
-    genesis_state = state_from_genesis(gen)
-    vals = genesis_state.validators
-    assert vals.get_proposer().pub_key.equals(live_pv.get_pub_key())
-    idx_by_addr = {v.address: i for i, v in enumerate(vals.validators)}
-
-    # warm the single 10240-lane bucket for the fused verify+tally graph
-    bv = crypto_batch.new_batch_verifier("tpu")
-    wvals, wpvs = mk_valset(1)
-    warm = mk_vote(wpvs[0], wvals, 0)
-    for _ in range(16):
-        bv.add(wvals.validators[0].pub_key, warm.sign_bytes(CHAIN_ID),
-               warm.signature, power=1)
-    t0 = time.perf_counter()
-    all_ok, *_ = bv.verify_tally()
-    assert all_ok
-    print(f"10240-bucket warmup compile: {time.perf_counter() - t0:.1f}s")
-
-    app = KVStoreApplication()
-    conns = AppConns(LocalClientCreator(app))
-    conns.start()
-    state_store = StateStore(MemDB())
-    state_store.save(genesis_state)
-    bus = EventBus()
-    exec_ = BlockExecutor(state_store, conns.consensus, event_bus=bus)
-    cs = ConsensusState(
-        ConsensusConfig.test_config(), genesis_state, exec_,
-        BlockStore(MemDB()), event_bus=bus, priv_validator=live_pv,
-    )
-    cs.verify_backend = "tpu"
-
-    dispatched = []
-    real_run = crypto_batch.TPUBatchVerifier._verify_pending
-
-    def spy_run(self, items, tally):
-        if len(items) >= 16:
-            dispatched.append(len(items))
-        return real_run(self, items, tally)
-
-    monkeypatch.setattr(crypto_batch.TPUBatchVerifier, "_verify_pending",
-                        spy_run)
-
-    t_prop = {}
-
-    def flood(proposal):
-        """Sign + inject the 19,998-vote flood. Runs on its OWN thread
-        like a real relay peer's recv thread: add_vote_msg blocks on the
-        bounded peer queue (backpressure) while the consensus thread
-        drains it — calling it from on_own_proposal directly would
-        deadlock the single-writer loop against its own queue."""
-        for vtype in (PREVOTE, PRECOMMIT):
-            for pv in co_pvs:
-                addr = pv.get_pub_key().address()
-                v = Vote(type=vtype, height=proposal.height,
-                         round=proposal.round, block_id=proposal.block_id,
-                         timestamp=_time.time_ns(),
-                         validator_address=addr,
-                         validator_index=idx_by_addr[addr])
-                pv.sign_vote(CHAIN_ID, v)
-                cs.add_vote_msg(v, peer_id="relay")
-
-    def on_proposal(proposal, parts):
-        if proposal.height != 1 or "t" in t_prop:
-            return
-        t_prop["t"] = _time.perf_counter()
-        threading.Thread(target=flood, args=(proposal,),
-                         daemon=True, name="vote-relay").start()
-
-    cs.on_own_proposal = on_proposal
-    try:
-        cs.start()
-        assert cs.wait_for_height(1, timeout=900), \
-            f"stuck at {cs.rs.height_round_step()}"
-        round_s = _time.perf_counter() - t_prop["t"]
-    finally:
-        cs.stop()
-        conns.stop()
-    commit = cs.block_store.load_seen_commit(1)
-    assert commit is not None and len(commit.signatures) == n_co + 1
-    signed = sum(1 for s in commit.signatures if not s.is_absent())
-    total_flood = sum(dispatched)
-    votes_per_dispatch = total_flood / len(dispatched)
-    print(f"10k live round: {round_s:.1f}s proposal->commit, "
-          f"{len(dispatched)} dispatches of {dispatched}, "
+    r = flood_round.run(n_co, backend="tpu", timeout=900)
+    votes_per_dispatch = r["lanes_dispatched"] / r["dispatches"]
+    print(f"10k live round: {r['round_s']:.1f}s proposal->commit, "
+          f"{r['dispatches']} dispatches, "
           f"votes/dispatch={votes_per_dispatch:.0f}, "
-          f"{signed} precommits in commit")
+          f"{r['precommits_in_commit']} precommits in commit")
     # the flood (19,998 votes) must ride LARGE dispatches, not thousands
     # of small ones. Each drain is bounded by the peer queue's 1000-item
     # backpressure cap (relay threads block, consensus drains), so the
     # expected shape is ~20 dispatches of ~1000 — votes/dispatch >> 1
-    assert votes_per_dispatch >= 500, \
-        f"batching window collapsed: {dispatched}"
+    assert votes_per_dispatch >= 500, r
     # all ~10k prevotes plus at least the 2/3 of precommits that closed
     # the commit must have ridden batched dispatches; the precommit tail
     # queued behind the commit point is legitimately dropped as stale
     # when the state advances to height 2
-    assert total_flood >= 1.5 * n_co, f"only {total_flood} votes batched"
+    assert r["lanes_dispatched"] >= 1.5 * n_co, r
 
 
 def test_consensus_commits_blocks_on_tpu_backend(monkeypatch):

@@ -34,7 +34,7 @@ from typing import Dict, Iterator, List, Optional, Set, Tuple
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-DEFAULT_SCAN = ("tmtpu", "tools", "tests", "bench.py")
+DEFAULT_SCAN = ("tmtpu", "tools", "tests", "bench.py", "chip_smoke.py")
 
 # ---------------------------------------------------------------- catalogs
 # (regexes ported verbatim from tools/check_failpoints.py /

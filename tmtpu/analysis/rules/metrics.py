@@ -38,7 +38,7 @@ _METRICS_MOD = "tmtpu/libs/metrics.py"
 @rule("metrics",
       doc="registered metrics have write sites, writes name registered "
           "metrics, and no metric bypasses the DEFAULT registry",
-      triggers=("tmtpu", "tools", "tests", "bench.py"))
+      triggers=("tmtpu", "tools", "tests", "bench.py", "chip_smoke.py"))
 def check(index: RepoIndex) -> List[Finding]:
     attrs = index.metric_defs()
     written = set()

@@ -242,6 +242,9 @@ def cmd_sidecar(args) -> int:
     from tmtpu.crypto import batch as crypto_batch
 
     crypto_batch.configure(cfg.crypto)
+    # the one process on the chip: place the compile cache, open JAX,
+    # and refuse an explicit "tpu" that found no TPU (SystemExit)
+    dev = crypto_batch.start_backend(cfg.sidecar.backend, "sidecar")
     server = SidecarServer(
         addr,
         backend=cfg.sidecar.backend,
@@ -261,7 +264,9 @@ def cmd_sidecar(args) -> int:
         print(f"Warm-up done in {warm_s:.1f}s "
               f"(backend={server.backend_name()})")
     print(f"Sidecar listening on {server.addr} "
-          f"backend={server.backend_name()} id={server.server_id}")
+          f"backend={server.backend_name()} platform={dev['platform']} "
+          f"device_kind={dev['kind']!r} devices={dev['count']} "
+          f"native={dev['native']} id={server.server_id}", flush=True)
     # SIGINT stops immediately (operator ^C); SIGTERM drains first —
     # stop accepting, answer OVERLOADED (clients fall back in-process
     # penalty-free), finish in-flight joint dispatches, exit 0
@@ -325,6 +330,7 @@ def cmd_lightserve(args) -> int:
     if backend == "sidecar":
         crypto_batch.configure_sidecar(
             cfg.sidecar, home=os.path.expanduser(args.home))
+    crypto_batch.start_backend(backend, "lightserve")
     server = LightserveServer(
         addr, HTTPProvider(chain_id, upstream),
         TrustOptions(period_ns=ls.trusting_period_ns,

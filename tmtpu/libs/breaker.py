@@ -21,8 +21,8 @@ backoff window a single caller is let through as a *probe batch*
 (HALF_OPEN); its outcome decides whether the device is trusted again.
 Backoff grows exponentially from ``backoff_base_s`` to
 ``backoff_max_s`` with deterministic seeded jitter (±``jitter_ratio``)
-so a fleet of validators does not re-probe a shared wedged tunnel in
-lockstep.
+so a fleet of validators does not re-probe a shared wedged device
+runtime in lockstep.
 
 Every transition lands in the ``tendermint_crypto_breaker_*`` metric
 set, the per-height timeline journal (event ``crypto.breaker``), and
@@ -30,10 +30,11 @@ the structured log — a node that degraded and healed leaves a complete
 audit trail (docs/RESILIENCE.md).
 
 ``call_with_deadline`` is the companion primitive: a hung ``jax``
-dispatch (wedged PJRT plugin / tunnel RPC) never returns, so breaker
-accounting alone cannot save the *current* batch. Running the device
-call on a worker thread with a hard join timeout turns "hung forever"
-into an exception the caller converts into a CPU-verified result.
+dispatch (a wedged device runtime, a chip another process took) never
+returns, so breaker accounting alone cannot save the *current* batch.
+Running the device call on a worker thread with a hard join timeout
+turns "hung forever" into an exception the caller converts into a
+CPU-verified result.
 """
 
 from __future__ import annotations

@@ -9,7 +9,9 @@ crypto/ed25519/ed25519.go:148-155 (h = SHA-512(R||A||M)) and scMinimal
 The library is built lazily with the system C compiler (cc -O2 -shared
 -pthread) into this directory and loaded over ctypes; when no toolchain is
 available, callers fall back to the vectorized numpy/hashlib path in
-tmtpu/tpu/verify.py — same results, more host CPU.
+tmtpu/tpu/verify.py — same results, more host CPU. The start-up log of a
+node or sidecar says which of the two it got (crypto/batch.py
+``start_backend``).
 """
 
 from __future__ import annotations
@@ -77,6 +79,19 @@ def load():
         if not _build():
             return None
         _lib = _load_and_bind()
+        return _lib
+
+
+def rebuild():
+    """Build the library from ``hostprep.c`` NOW, whatever binary is on
+    disk, and bind it: the handle, or None when the toolchain or the
+    binding fails. ``load()`` trusts any ``_hostprep.so`` newer than the
+    source; a run that must prove what git would commit (chip_smoke.py)
+    cannot."""
+    global _lib, _tried
+    with _lock:
+        _tried = True
+        _lib = _load_and_bind() if _build() else None
         return _lib
 
 

@@ -70,12 +70,13 @@ class Node(BaseService):
         # in-process on first use
         crypto_batch.configure_sidecar(
             config.sidecar, home=os.path.expanduser(config.base.home))
-        # warm the native helper library now: its lazy first load may
-        # COMPILE hostprep.c (seconds), which must never land inside the
-        # consensus verify hot path on first use
-        from tmtpu import native as _native
-
-        _native.load()
+        # open the verify engine now, not on the consensus thread: loads
+        # (and, first time, compiles) the native host-prep library, and
+        # for the in-process device backends places the compile cache,
+        # checks an explicit "tpu" against what JAX found, and logs the
+        # platform once. A sidecar or cpu node never imports JAX.
+        self.verify_device = crypto_batch.start_backend(
+            config.base.crypto_backend, "node")
 
         # --- DBs + state (node.go initDBs / LoadStateFromDBOrGenesis) ---
         self.block_store = BlockStore(_make_db(config, "blockstore"))
@@ -442,7 +443,10 @@ class Node(BaseService):
             wd.register("crypto", wdg.tpu_backend_check(
                 hc.fallback_storm_window_ns / 1e9,
                 hc.fallback_storm_threshold,
-                expect_device=self.config.base.crypto_backend == "tpu"))
+                # under JAX_PLATFORMS=cpu emulation the gauge is
+                # truthfully 0 and that is what was asked for
+                expect_device=self.config.base.crypto_backend == "tpu"
+                and self.verify_device["platform"] == "tpu"))
             wd.register("breaker", wdg.breaker_check())
         if self.config.base.crypto_backend == "sidecar":
             wd.register("sidecar", wdg.sidecar_check(
@@ -591,6 +595,16 @@ class Node(BaseService):
         return self.state.validators.validators[0].address == addr
 
     def on_start(self) -> None:
+        if self.verify_device["backend"] == "tpu":
+            # compile this validator set's flush shapes before any peer
+            # can send a vote (crypto/batch.py warm_validator_set)
+            from tmtpu.libs import log
+
+            warmed = crypto_batch.warm_validator_set(
+                self.consensus.state.validators)
+            log.default_logger().with_fields(module="crypto").info(
+                "verify shapes warmed", shapes=len(warmed),
+                seconds=round(sum(w[3] for w in warmed), 1))
         self.indexer_service.start()
         if self.switch is not None:
             self.switch.start()

@@ -1,10 +1,12 @@
 """The sidecar daemon: socket listener, protocol loop, verify engine.
 
-One daemon process owns the JAX device for a whole host. It compiles
-the Pallas verify kernels ONCE (``warm()`` forces the compile at
-startup instead of on the first client's request) and serves every node
-process through the cross-client coalescer, so N validators pay one
-~35s compile instead of N, and their lanes merge into joint dispatches.
+One daemon process owns the JAX device for a whole host — the ONLY
+process on the chip: its clients never open JAX. It compiles the
+Pallas verify kernels once
+(``warm()`` forces the compiles at startup instead of on the first
+client's request) and serves every node process through the
+cross-client coalescer, so N validators pay each shape's compile once
+instead of N times, and their lanes merge into joint dispatches.
 
 The verify engine is :func:`tmtpu.crypto.batch.new_batch_verifier` —
 the daemon inherits the whole in-process stack for free: the
@@ -42,6 +44,7 @@ from tmtpu.crypto.keys import KEY_TYPES
 from tmtpu.libs import breaker as _bk
 from tmtpu.sidecar import protocol as proto
 from tmtpu.sidecar.coalescer import Coalescer, Overloaded
+from tmtpu.tpu import compat
 
 _FAILURE_STATUS = {
     "expired": proto.STATUS_OVERLOADED,
@@ -91,6 +94,8 @@ class SidecarServer:
         self._draining = False
         self._started_at = 0.0
         self._warmed = False
+        # (curve, lanes, tally, seconds) per warm() flush
+        self.warmed_shapes: List[tuple] = []
 
     # --- verify engine ---
 
@@ -109,16 +114,29 @@ class SidecarServer:
             tallied = 0
         return mask, tallied
 
+    def _device_engine(self) -> bool:
+        """Does the engine dispatch to JAX (vs the serial CPU verifier)?
+        ``auto`` answers by the probe, so an open ``crypto.tpu`` breaker
+        reads as the CPU engine it currently is."""
+        return self._backend == "tpu" or (
+            self._backend == "auto" and crypto_batch._tpu_available())
+
     def backend_name(self) -> str:
-        b = self._backend
-        if b == "auto":
-            b = "tpu" if crypto_batch._tpu_available() else "cpu"
-        return b
+        """What ``HelloAck``/``Pong``/``/healthz`` report: ``cpu`` for
+        the serial engine, ``tpu`` only when the device engine's JAX
+        platform is a TPU, and ``xla:<platform>`` for the device graph
+        emulated on anything else (``JAX_PLATFORMS=cpu``)."""
+        if not self._device_engine():
+            return "cpu"
+        platform = compat.device_platform()
+        return "tpu" if platform == "tpu" else f"xla:{platform}"
 
     def warm(self) -> float:
-        """Force kernel compilation NOW by pushing one self-signed batch
-        through the engine, so the first client request doesn't eat the
-        compile latency. Returns the warm-up wall seconds."""
+        """Prove the engine with one self-signed batch and, on the
+        device engine, compile every ed25519 shape up to the dispatch
+        cap (crypto/batch.py ``warm_daemon``) NOW, so no client request
+        waits out a first-sight compile past its deadline. Returns the
+        warm-up wall seconds."""
         from tmtpu.crypto import ed25519 as _ed
 
         t0 = time.perf_counter()
@@ -133,6 +151,9 @@ class SidecarServer:
         if not all(mask):
             raise RuntimeError("sidecar warm-up verify returned invalid "
                                "for self-signed lanes")
+        if self._device_engine():
+            self.warmed_shapes = crypto_batch.warm_daemon(
+                self._max_lanes_per_dispatch)
         self._warmed = True
         return time.perf_counter() - t0
 
@@ -246,13 +267,28 @@ class SidecarServer:
                 pass
 
     def snapshot(self) -> Dict:
+        from tmtpu.libs import metrics as _m
+
         with self._conns_lock:
             n_conns = len(self._conns)
         return {
             "server_id": self.server_id,
             "addr": self.addr,
             "backend": self.backend_name(),
+            # as JAX reports it; empty for the serial engine (no JAX)
+            "device": (compat.device_info() if self._device_engine()
+                       else {}),
+            # engine dispatches by the batch metric set's own labels
+            # (curve, platform, impl): whether lanes ran the Pallas
+            # kernel on a TPU or something slower, and how many
+            "dispatched": _m.crypto_verify_latency.summary_series(),
+            "dispatched_lanes": _m.crypto_batch_size.summary_series(),
+            "cpu_fallback": _m.crypto_cpu_fallback.summary_series(),
             "warmed": self._warmed,
+            "warmed_shapes": [
+                {"curve": c, "lanes": b, "tally": t,
+                 "seconds": round(sec, 3)}
+                for c, b, t, sec in self.warmed_shapes],
             "draining": self._draining,
             "uptime_s": round(max(0.0, time.monotonic() -
                                   self._started_at), 3),

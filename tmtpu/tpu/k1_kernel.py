@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tmtpu.tpu import fe_k1 as fe
 from tmtpu.tpu import k1_verify as kv
+from tmtpu.tpu.kernel import _default_interpret
 
 NLIMBS = fe.NLIMBS
 RADIX = fe.RADIX
@@ -272,13 +273,6 @@ def _k1_verify_pallas_jit(pkx_b, parity, u1_b, u2_b, r_b, rpn_b,
       u1_b.astype(jnp.int32), u2_b.astype(jnp.int32),
       r_b.astype(jnp.int32), rpn_b.astype(jnp.int32))
     return out[0]
-
-
-def _default_interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 def k1_verify_compact_kernel(pkx_b, parity, u1_b, u2_b, r_b, rpn_b, *,

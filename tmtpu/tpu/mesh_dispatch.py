@@ -10,8 +10,11 @@ and the per-curve sharded callables, and routes any flush of at least
 - ed25519 rides the fused verify+tally step with the voting-power
   reduction psum'd ON DEVICE, so the host reads back one packed mask
   plus five int32 limb sums regardless of mesh size;
-- sr25519 / secp256k1 ride their lane-sharded XLA graphs (verification
-  is embarrassingly parallel — no collective at all).
+- sr25519 / secp256k1, and every mask-only ed25519 flush, ride their
+  lane-sharded XLA graphs (verification is embarrassingly parallel — no
+  collective at all). Only the ed25519 tally step runs the fused Pallas
+  kernel under shard_map; the metric label says which one ran
+  (``mesh-pallas`` / ``mesh-xla``).
 
 Contract with the callers: every entry point here either returns the
 EXACT single-device result or raises. ``crypto.batch.TPUBatchVerifier``
@@ -34,7 +37,7 @@ including CPU-only ones that must not pay backend init.
 Tier-1 testability: under ``XLA_FLAGS=--xla_force_host_platform_device_
 count=N`` (tests/conftest.py) the whole path runs on a virtual CPU
 mesh; ``TMTPU_MESH_DEVICES`` / ``TMTPU_SHARD_MIN_LANES`` are call-time
-env overrides for tests and the bench flood mode.
+env overrides for tests and tools.
 """
 
 from __future__ import annotations
@@ -65,7 +68,7 @@ _state: Dict = {
     "mesh_key": None,      # (n, device ids) the cache was built for
     "fns": {},             # (kind, mesh_key) -> jitted sharded callable
     "dispatches": 0,
-    "occupancy": {},       # device index -> cumulative sharded lanes
+    "occupancy": {},       # device id -> cumulative lanes placed there
     "last": None,          # last dispatch summary (sidecar Stats)
 }
 
@@ -115,7 +118,7 @@ def reset() -> None:
 def mesh_devices() -> int:
     """Configured mesh width; 0 = every visible device. The env var is
     read at call time (same pattern as batch_deadline_s) so tests and
-    the bench flood child can steer without a config file."""
+    tools can steer without a config file."""
     raw = os.environ.get("TMTPU_MESH_DEVICES", "")
     if raw:
         try:
@@ -166,10 +169,18 @@ def _get_mesh():
 
 def device_count() -> int:
     """Devices a sharded dispatch would span right now; 0 when the mesh
-    cannot be built (never raises — route() gates on it)."""
+    cannot be built (never raises — route() gates on it). A one-device
+    host is the ordinary case and silent; any other failure to build
+    the mesh is counted, so a host whose chips went missing shows up in
+    ``crypto_mesh_fallback_total{reason="mesh-init"}``."""
     try:
         return int(_get_mesh().devices.size)
+    except MeshUnavailable:
+        return 0
     except Exception:  # noqa: BLE001 — unavailable == 0
+        from tmtpu.libs import metrics as _m
+
+        _m.crypto_mesh_fallback_total.inc(curve="any", reason="mesh-init")
         return 0
 
 
@@ -223,22 +234,33 @@ def _fn(kind: str, mesh, builder):
     return f
 
 
-def _note_dispatch(curve: str, lanes: int, padded: int, n: int,
-                   psum_s: float, total_s: float) -> None:
+def _shard_lanes(mask) -> Dict[int, int]:
+    """Observed placement of a sharded result: device id -> lanes of
+    the mask that device computed (read off the output's own shards, so
+    a mesh that put everything on device 0 shows as such)."""
+    out: Dict[int, int] = {}
+    for s in mask.addressable_shards:
+        out[s.device.id] = out.get(s.device.id, 0) + int(s.data.shape[0])
+    return out
+
+
+def _note_dispatch(curve: str, lanes: int, padded: int,
+                   shard_lanes: Dict[int, int], psum_s: float,
+                   total_s: float, impl: str) -> None:
     from tmtpu.libs import metrics as _m
     from tmtpu.libs import timeline as _tl
 
+    n = len(shard_lanes)
+    per_shard = max(shard_lanes.values())
     with _lock:
         _state["dispatches"] += 1
         seq = _state["dispatches"]
-        per_shard = padded // n
-        for d in range(n):
-            _state["occupancy"][d] = \
-                _state["occupancy"].get(d, 0) + per_shard
+        for d, got in shard_lanes.items():
+            _state["occupancy"][d] = _state["occupancy"].get(d, 0) + got
         _state["last"] = {
             "seq": seq, "curve": curve, "lanes": lanes,
             "padded": padded, "devices": n, "shard_lanes": per_shard,
-            "seconds": round(total_s, 6),
+            "impl": impl, "seconds": round(total_s, 6),
         }
     _m.crypto_mesh_devices.set(n)
     _m.crypto_mesh_dispatches_total.inc(curve=curve)
@@ -256,8 +278,9 @@ def dispatch_count() -> int:
 
 
 def snapshot() -> Dict:
-    """Mesh occupancy for sidecar Stats / health surfaces: per-device
-    cumulative sharded lanes plus the last dispatch's shape."""
+    """Mesh occupancy for sidecar Stats / health surfaces: cumulative
+    sharded lanes per device id, as placed, plus the last dispatch's
+    shape and implementation."""
     with _lock:
         return {
             "devices": (_state["mesh_key"][0]
@@ -305,7 +328,8 @@ def batch_verify_tally_mesh(pks, msgs, sigs, powers
 
             q = tk.DEFAULT_TILE * n
             padded = ((padded + q - 1) // q) * q
-        sp.set(padded=padded, impl="pallas" if use_kernel else "xla")
+        impl = "mesh-pallas" if use_kernel else "mesh-xla"
+        sp.set(padded=padded, impl=impl)
         # pad lanes replicate lane 0's BYTES only — their power limbs
         # stay zero, so padding can never leak into the tally
         power_limbs = np.zeros((sh.POWER_LIMBS, padded), dtype=np.int32)
@@ -325,13 +349,14 @@ def batch_verify_tally_mesh(pks, msgs, sigs, powers
         t_mask = time.perf_counter()
         tallied = sh.limb_sums_to_int(power_sums)   # the psum readback
         psum_s = time.perf_counter() - t_mask
+        placed = _shard_lanes(mask)
         mask = np.asarray(mask)[:b] & host_ok
     total = time.perf_counter() - t0
-    _note_dispatch(ED25519, b, padded, n, psum_s, total)
+    _note_dispatch(ED25519, b, padded, placed, psum_s, total, impl)
     breaker().record_success()
     from tmtpu.libs import metrics as _m
 
-    _m.observe_crypto_batch(ED25519, tv.backend_label(), "mesh", b,
+    _m.observe_crypto_batch(ED25519, tv.backend_label(), impl, b,
                             padded, total)
     return mask, tallied
 
@@ -376,7 +401,9 @@ def batch_verify_mesh(curve: str, pks, msgs, sigs) -> np.ndarray:
         else:
             raise ValueError(f"unsupported mesh curve {curve!r}")
         padded = padded_lanes(b, n)
-        sp.set(padded=padded)
+        # every mask-only mesh route is the lane-sharded XLA graph
+        impl = "mesh-xla"
+        sp.set(padded=padded, impl=impl)
         packed_h = tv.pad_packed(packed, padded)
         if curve == ED25519:
             # reuse the fused tally callable with zero powers: one jit
@@ -387,12 +414,14 @@ def batch_verify_mesh(curve: str, pks, msgs, sigs) -> np.ndarray:
         else:
             fn = _fn(curve, mesh, build)
             mask = fn(jnp.asarray(packed_h), table)
-        mask = np.asarray(jax.block_until_ready(mask))[:b] & host_ok
+        mask = jax.block_until_ready(mask)
+        placed = _shard_lanes(mask)
+        mask = np.asarray(mask)[:b] & host_ok
     total = time.perf_counter() - t0
-    _note_dispatch(curve, b, padded, n, 0.0, total)
+    _note_dispatch(curve, b, padded, placed, 0.0, total, impl)
     breaker().record_success()
     from tmtpu.libs import metrics as _m
 
-    _m.observe_crypto_batch(curve, tv.backend_label(), "mesh", b,
+    _m.observe_crypto_batch(curve, tv.backend_label(), impl, b,
                             padded, total)
     return mask

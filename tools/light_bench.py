@@ -48,10 +48,14 @@ def main():
                          "of an in-process backend")
     args = ap.parse_args()
 
-    if args.backend == "cpu" and not args.sidecar:
-        from tmtpu.tpu.compat import force_cpu_backend
+    # one process per chip: "cpu" and --sidecar never import JAX (the
+    # daemon owns the device); "tpu" makes THIS process the one on the
+    # chip and refuses anything that is not one
+    if args.backend == "tpu" and not args.sidecar:
+        from tmtpu.tpu import compat
 
-        force_cpu_backend(1)
+        compat.setup_compile_cache()
+        compat.require_tpu("light_bench", allow_emulation=False)
     from tmtpu.crypto import batch as crypto_batch
 
     if args.sidecar:

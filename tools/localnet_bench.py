@@ -16,12 +16,10 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 import tests.conftest  # noqa: F401  (forces jax onto CPU devices)
 
 from tests.test_p2p import _mk_net_nodes  # noqa: E402
-from tools import measure_lock  # noqa: E402
 
 
 def main(duration_s: float = 20.0):
-    with measure_lock.hold("localnet_bench"):
-        return _run(duration_s)
+    return _run(duration_s)
 
 
 def _run(duration_s: float):

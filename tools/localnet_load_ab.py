@@ -61,7 +61,6 @@ from tmtpu.libs import metrics as _m  # noqa: E402
 from tmtpu.libs import txlat  # noqa: E402
 from tmtpu.mempool import signed_tx  # noqa: E402
 from tools import ab_common  # noqa: E402
-from tools import measure_lock  # noqa: E402
 
 
 def _mk_net_nodes(tmp, pipelined: bool):
@@ -223,32 +222,31 @@ def sweep(rates, window_s: float = 12.0, settle_s: float = 4.0):
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="load-sweep-"))
     nodes = _mk_net_nodes(tmp, pipelined=True)
     rows = []
-    with measure_lock.hold("localnet_load_sweep"):
-        try:
-            ab_common.boot(nodes, height=2, timeout_s=60)
-            idx = 0
-            for r, n_arm in zip(rates, budget):
-                txlat.clear()
-                lat0 = _m.tx_latency_submit_to_commit.bucket_counts()
-                size0 = _app_size(nodes[0])
-                shard = txs[idx:idx + n_arm]
-                idx += n_arm
-                offered = _paced_offer(nodes, shard, r, window_s)
-                time.sleep(settle_s)  # let the tail commit (or not)
-                committed = _app_size(nodes[0]) - size0
-                row = {
-                    "offered_rate": r,
-                    "offered_txs": offered,
-                    "committed_txs": committed,
-                    "committed_tx_per_s": round(committed / window_s, 1),
-                    "commit_rate": round(committed / max(1, offered), 4),
-                }
-                row.update(_lat_delta(lat0))
-                rows.append(row)
-                print(json.dumps(row), file=sys.stderr)
-        finally:
-            for nd in nodes:
-                nd.stop()
+    try:
+        ab_common.boot(nodes, height=2, timeout_s=60)
+        idx = 0
+        for r, n_arm in zip(rates, budget):
+            txlat.clear()
+            lat0 = _m.tx_latency_submit_to_commit.bucket_counts()
+            size0 = _app_size(nodes[0])
+            shard = txs[idx:idx + n_arm]
+            idx += n_arm
+            offered = _paced_offer(nodes, shard, r, window_s)
+            time.sleep(settle_s)  # let the tail commit (or not)
+            committed = _app_size(nodes[0]) - size0
+            row = {
+                "offered_rate": r,
+                "offered_txs": offered,
+                "committed_txs": committed,
+                "committed_tx_per_s": round(committed / window_s, 1),
+                "commit_rate": round(committed / max(1, offered), 4),
+            }
+            row.update(_lat_delta(lat0))
+            rows.append(row)
+            print(json.dumps(row), file=sys.stderr)
+    finally:
+        for nd in nodes:
+            nd.stop()
     out = {"metric": "localnet_load_sweep", "window_s": window_s,
            "rows": rows}
     print(json.dumps(out))
@@ -261,11 +259,10 @@ def main(n_txs: int = 2000):
     txs = [signed_tx.encode(b"ld-%d=%d" % (i, i), priv)
            for i in range(n_txs)]
     report = ab_common.ABReport("localnet_load_ab")
-    with measure_lock.hold("localnet_load_ab"):
-        serial = report.add_arm(
-            _run_arm(False, txs, drain_timeout_s=600.0))
-        pipelined = report.add_arm(
-            _run_arm(True, txs, drain_timeout_s=600.0))
+    serial = report.add_arm(
+        _run_arm(False, txs, drain_timeout_s=600.0))
+    pipelined = report.add_arm(
+        _run_arm(True, txs, drain_timeout_s=600.0))
     return report.finish(
         txs=n_txs,
         speedup=round(pipelined["committed_tx_per_s"] /

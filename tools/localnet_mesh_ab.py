@@ -11,8 +11,8 @@ rate holds), mesh_dispatches ≈ device flushes in arm B and exactly 0 in
 arm A, and the per-chip occupancy spread shows every device carrying an
 equal lane share (the padding quantum guarantees equal shards). On this
 CPU-forced host the mesh is 4 virtual XLA:CPU devices, so the numbers
-prove ROUTING and EXACTNESS, not chip speedup — the flood bench
-(``TMTPU_BENCH_FLOOD=1 python bench.py``) owns the wall-time claim.
+prove ROUTING and EXACTNESS, not chip speedup — ``chip_smoke.py`` on a
+multi-chip host prints mesh seconds beside single-device seconds.
 
 Prints one JSON line per arm plus a combined summary
 (tools/ab_common.py schema):
@@ -40,7 +40,6 @@ from tmtpu.crypto import batch as crypto_batch  # noqa: E402
 from tmtpu.libs import breaker as _bk  # noqa: E402
 from tmtpu.tpu import mesh_dispatch as md  # noqa: E402
 from tools import ab_common  # noqa: E402
-from tools import measure_lock  # noqa: E402
 
 
 def _mk_net_nodes(tmp):
@@ -118,13 +117,12 @@ def _run_arm(name: str, duration_s: float, mesh_devices: int,
 
 def main(duration_s: float = 20.0):
     report = ab_common.ABReport("localnet_mesh_ab")
-    with measure_lock.hold("localnet_mesh_ab"):
-        single = report.add_arm(_run_arm("single_device", duration_s,
-                                         mesh_devices=1,
-                                         shard_min_lanes=1))
-        mesh = report.add_arm(_run_arm("mesh", duration_s,
-                                       mesh_devices=4,
-                                       shard_min_lanes=1))
+    single = report.add_arm(_run_arm("single_device", duration_s,
+                                     mesh_devices=1,
+                                     shard_min_lanes=1))
+    mesh = report.add_arm(_run_arm("mesh", duration_s,
+                                   mesh_devices=4,
+                                   shard_min_lanes=1))
     occ = [v for v in mesh["occupancy_lanes"].values()]
     return report.finish(
         mesh_dispatch_share=mesh["mesh_dispatch_share"],

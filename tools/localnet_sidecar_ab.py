@@ -36,7 +36,6 @@ from tmtpu.libs import breaker as _bk  # noqa: E402
 from tmtpu.libs import metrics as _m  # noqa: E402
 from tmtpu.sidecar.server import SidecarServer  # noqa: E402
 from tools import ab_common  # noqa: E402
-from tools import measure_lock  # noqa: E402
 
 
 def _mk_net_nodes(tmp, backend="cpu", sidecar_addr=""):
@@ -172,9 +171,8 @@ def _run_sidecar(duration_s: float) -> dict:
 
 def main(duration_s: float = 20.0):
     report = ab_common.ABReport("localnet_sidecar_ab")
-    with measure_lock.hold("localnet_sidecar_ab"):
-        pp = report.add_arm(_run_per_process(duration_s))
-        sc = report.add_arm(_run_sidecar(duration_s))
+    pp = report.add_arm(_run_per_process(duration_s))
+    sc = report.add_arm(_run_sidecar(duration_s))
     reduction = 1.0 - (sc["dispatches_per_block"] /
                        max(1e-9, pp["dispatches_per_block"]))
     return report.finish(

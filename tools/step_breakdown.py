@@ -21,7 +21,7 @@ import tests.conftest  # noqa: F401  (forces jax onto CPU devices)
 
 from tmtpu.consensus.types import STEP_NAMES  # noqa: E402
 from tmtpu.libs import metrics  # noqa: E402
-from tools import localnet_bench, measure_lock  # noqa: E402
+from tools import localnet_bench  # noqa: E402
 
 
 def _snapshot():
@@ -36,8 +36,7 @@ def main(duration_s: float = 20.0):
     # run) and diff afterwards; the residual warm-up inside _run is a
     # couple of NewHeight samples, not the seconds-scale node boot.
     before = _snapshot()
-    with measure_lock.hold("step_breakdown"):
-        bench = localnet_bench._run(duration_s)
+    bench = localnet_bench._run(duration_s)
     after = _snapshot()
     out = {"localnet": bench, "steps": {}}
     for name in STEP_NAMES.values():
