@@ -254,7 +254,9 @@ def cmd_sidecar(args) -> int:
         request_deadline_s=cfg.sidecar.request_deadline_ns / 1e9,
         health_laddr=args.health_laddr or cfg.sidecar.health_laddr,
         mesh_devices=cfg.sidecar.mesh_devices,
-        shard_min_lanes=cfg.sidecar.shard_min_lanes)
+        shard_min_lanes=cfg.sidecar.shard_min_lanes,
+        profile_dir=os.path.join(os.path.expanduser(args.home), "data",
+                                 "profile"))
     warm = cfg.sidecar.warm_on_start and not args.no_warm
     server.start()
     if warm:

@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Tuple
 from tmtpu.crypto import keys, sigcache
 from tmtpu.crypto.keys import PubKey
 from tmtpu.libs import breaker as _bk
+from tmtpu.libs import trace
 
 ED25519 = "ed25519"
 SR25519 = "sr25519"
@@ -551,6 +552,17 @@ class BatchVerifier(keys.BatchVerifier):
         raise NotImplementedError
 
     def _resolve(self, tally: bool) -> Tuple[bool, List[bool], int]:
+        with trace.span("batch.resolve") as sp:
+            out = self._resolve_stages(tally)
+            sp.set(**self.cache_stats)   # lanes, hits, dedup, dispatched
+        return out
+
+    def _resolve_stages(self, tally: bool) -> Tuple[bool, List[bool], int]:
+        """The resolve, one span a stage (never one a lane): ``batch.keys``
+        and ``batch.lookup`` are the sigcache key and the contains + dedup
+        grouping as two passes over the lanes, ``batch.fold`` the miss
+        list with folded powers, then the backend hook (its own spans),
+        ``batch.insert`` the cache inserts and the members' mask."""
         items = self._items
         n = len(items)
         cache = sigcache.DEFAULT
@@ -565,40 +577,45 @@ class BatchVerifier(keys.BatchVerifier):
         tallied = 0
         hits = 0
         dedup = 0
-        ks = [sigcache.cache_key(pk.type_value(), pk.bytes(), msg, sig)
-              for pk, msg, sig, _p in items]
+        with trace.span("batch.keys"):
+            ks = [sigcache.cache_key(pk.type_value(), pk.bytes(), msg, sig)
+                  for pk, msg, sig, _p in items]
         group_of: Dict[bytes, int] = {}
         pending: List[int] = []       # representative index per unique miss
         members: List[List[int]] = []  # all indices sharing that triple
-        for i, k in enumerate(ks):
-            if cache.contains(k):
-                mask[i] = True
-                tallied += items[i][3]
-                hits += 1
-                continue
-            pos = group_of.get(k)
-            if pos is None:
-                group_of[k] = len(pending)
-                pending.append(i)
-                members.append([i])
-            else:
-                members[pos].append(i)
-                dedup += 1
+        with trace.span("batch.lookup"):
+            for i, k in enumerate(ks):
+                if cache.contains(k):
+                    mask[i] = True
+                    tallied += items[i][3]
+                    hits += 1
+                    continue
+                pos = group_of.get(k)
+                if pos is None:
+                    group_of[k] = len(pending)
+                    pending.append(i)
+                    members.append([i])
+                else:
+                    members[pos].append(i)
+                    dedup += 1
         if pending:
-            sub_items = []
-            for pos, i in enumerate(pending):
-                pk, msg, sig, _p = items[i]
-                # fold dup-group powers into the unique lane so the
-                # fused device tally counts every member exactly once
-                sub_items.append((pk, msg, sig,
-                                  sum(items[j][3] for j in members[pos])))
+            with trace.span("batch.fold"):
+                sub_items = []
+                for pos, i in enumerate(pending):
+                    pk, msg, sig, _p = items[i]
+                    # fold dup-group powers into the unique lane so the
+                    # fused device tally counts every member exactly once
+                    sub_items.append((pk, msg, sig,
+                                      sum(items[j][3]
+                                          for j in members[pos])))
             sub_mask, sub_tallied = self._verify_pending(sub_items, tally)
             tallied += sub_tallied
-            for pos, ok in enumerate(sub_mask):
-                if ok:
-                    cache.add(ks[pending[pos]])
-                for j in members[pos]:
-                    mask[j] = bool(ok)
+            with trace.span("batch.insert"):
+                for pos, ok in enumerate(sub_mask):
+                    if ok:
+                        cache.add(ks[pending[pos]])
+                    for j in members[pos]:
+                        mask[j] = bool(ok)
         if dedup:
             from tmtpu.libs import metrics as _m
 
@@ -633,7 +650,6 @@ class CPUBatchVerifier(BatchVerifier):
         import time
 
         from tmtpu.libs import metrics as _m
-        from tmtpu.libs import trace
 
         t0 = time.perf_counter()
         mask = [False] * len(items)
@@ -712,8 +728,9 @@ class TPUBatchVerifier(BatchVerifier):
         from tmtpu.libs import metrics as _m
 
         t0 = _time.perf_counter()
-        (ed_idx, ed_pks, ed_msgs, ed_sigs, ed_powers,
-         sr_idx, k1_idx, cpu_idx) = self._split(items)
+        with trace.span("batch.split"):
+            (ed_idx, ed_pks, ed_msgs, ed_sigs, ed_powers,
+             sr_idx, k1_idx, cpu_idx) = self._split(items)
         if cpu_idx:
             _m.crypto_cpu_fallback.inc(len(cpu_idx), curve="other",
                                        reason="unsupported")
@@ -729,25 +746,28 @@ class TPUBatchVerifier(BatchVerifier):
             k1_idx = []
         mask: List[bool] = [False] * len(items)
         tallied = 0
-        for i in cpu_idx:
-            pk, msg, sig, power = items[i]
-            mask[i] = pk.verify_signature(msg, sig)
-            if mask[i]:
-                tallied += power
+
+        def _verify_serially(idx_list, reason):
+            nonlocal tallied
+            with trace.span("batch.serial", lanes=len(idx_list),
+                            reason=reason):
+                for i in idx_list:
+                    pk, msg, sig, power = items[i]
+                    mask[i] = pk.verify_signature(msg, sig)
+                    if mask[i]:
+                        tallied += power
+
+        if cpu_idx:
+            _verify_serially(cpu_idx, "cpu-lanes")
         br = _tpu_breaker()
         deadline = batch_deadline_s()
 
         def _serial(idx_list, curve, reason):
             # CPU-serial fallback for lanes whose device batch failed
             # (or was never attempted: open breaker / small batch)
-            nonlocal tallied
             _m.crypto_cpu_fallback.inc(len(idx_list), curve=curve,
                                        reason=reason)
-            for i in idx_list:
-                pk, msg, sig, power = items[i]
-                mask[i] = pk.verify_signature(msg, sig)
-                if mask[i]:
-                    tallied += power
+            _verify_serially(idx_list, reason)
 
         def _dispatch(curve, idx_list, thunk, apply):
             """One per-curve device batch under the breaker and the
@@ -756,27 +776,37 @@ class TPUBatchVerifier(BatchVerifier):
             breaker and re-verifies exactly these lanes serially, so
             the flush always returns an exact mask. Successful
             round-trips feed the adaptive flush scheduler's RTT
-            estimate (cache hits and serial fallbacks never do)."""
-            if not br.allow():
-                _serial(idx_list, curve, "breaker-open")
+            estimate (cache hits and serial fallbacks never do).
+            ``batch.dispatch`` is the wait on the device path: the
+            worker's ``crypto.batch_verify*`` spans are its children."""
+            failed = None
+            with trace.span("batch.dispatch", curve=curve,
+                            lanes=len(idx_list)) as sp:
+                if not br.allow():
+                    failed = "breaker-open"
+                else:
+                    d0 = _time.perf_counter()
+                    try:
+                        out = _bk.call_with_deadline(thunk, deadline)
+                    except _bk.DeadlineExceeded as e:
+                        _m.crypto_batch_deadline_exceeded.inc(curve=curve)
+                        br.record_failure(e)
+                        failed = "deadline"
+                    except Exception as e:  # noqa: BLE001 — a broken
+                        # device path must never take down verification
+                        br.record_failure(e)
+                        failed = "device-error"
+                    else:
+                        br.record_success()
+                        SCHEDULER.note_dispatch(len(idx_list),
+                                                _time.perf_counter() - d0)
+                if failed:
+                    sp.set(failed=failed)
+            if failed:
+                _serial(idx_list, curve, failed)
                 return
-            d0 = _time.perf_counter()
-            try:
-                out = _bk.call_with_deadline(thunk, deadline)
-            except _bk.DeadlineExceeded as e:
-                _m.crypto_batch_deadline_exceeded.inc(curve=curve)
-                br.record_failure(e)
-                _serial(idx_list, curve, "deadline")
-                return
-            except Exception as e:  # noqa: BLE001 — a broken device
-                # path must never take down verification
-                br.record_failure(e)
-                _serial(idx_list, curve, "device-error")
-                return
-            br.record_success()
-            SCHEDULER.note_dispatch(len(idx_list),
-                                    _time.perf_counter() - d0)
-            apply(out)
+            with trace.span("batch.apply"):
+                apply(out)
 
         def _apply_mask(idx_list):
             def apply(dev_mask):

@@ -44,6 +44,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from tmtpu.libs import trace
+
 CLOSED = "closed"
 OPEN = "open"
 HALF_OPEN = "half_open"
@@ -66,7 +68,9 @@ def call_with_deadline(fn: Callable, timeout_s: float, *args, **kwargs):
     with a hard timeout. Returns the result, re-raises the function's
     exception, or raises DeadlineExceeded if the call is still running
     at the deadline (the worker is abandoned — it holds no locks the
-    caller needs, and a later completion is discarded).
+    caller needs, and a later completion is discarded). The worker goes
+    on under the caller's open span and trace context (libs/trace
+    ``handoff``/``resume``), so what ``fn`` records names its cause.
 
     ``timeout_s <= 0`` means no deadline: call inline (no thread hop).
     """
@@ -74,10 +78,12 @@ def call_with_deadline(fn: Callable, timeout_s: float, *args, **kwargs):
         return fn(*args, **kwargs)
     box: Dict = {}
     done = threading.Event()
+    caller = trace.handoff()
 
     def run():
         try:
-            box["result"] = fn(*args, **kwargs)
+            with trace.resume(caller):
+                box["result"] = fn(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001 — re-raised below
             box["error"] = e
         finally:

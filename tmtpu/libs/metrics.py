@@ -13,6 +13,8 @@ import math
 import threading
 from typing import Dict, List, Optional, Tuple
 
+from tmtpu.libs import trace as _trace
+
 _NAMESPACE = "tendermint"
 
 
@@ -205,6 +207,31 @@ class Histogram(_Metric):
             }
 
 
+class SummaryView(_Metric):
+    """A family of Prometheus summaries (``_count`` and ``_sum``, no
+    quantiles) whose numbers another module keeps: ``read()`` gives
+    {label value: (count, sum)} when the family is rendered. In
+    ``summary()`` a series has a histogram's shape, {"count", "sum"}."""
+
+    def __init__(self, name: str, help_: str, label: str, read):
+        super().__init__(name, help_, (label,))
+        self._read = read
+
+    def render(self, kind: str) -> List[str]:
+        out = [f"# HELP {self.name} {_esc_help(self.help)}",
+               f"# TYPE {self.name} summary"]
+        for value, (count, total) in sorted(self._read().items()):
+            lbl = f'{{{self.label_names[0]}="{_esc_label(value)}"}}'
+            out.append(f"{self.name}_sum{lbl} {_fmt(total)}")
+            out.append(f"{self.name}_count{lbl} {count}")
+        return out
+
+    def summary_series(self) -> Dict[str, Dict[str, float]]:
+        return {_series_key(self.label_names, (value,)):
+                {"count": count, "sum": round(total, 6)}
+                for value, (count, total) in sorted(self._read().items())}
+
+
 class Registry:
     def __init__(self):
         self._metrics: Dict[str, Tuple[str, _Metric]] = {}
@@ -226,6 +253,12 @@ class Registry:
               labels: Tuple[str, ...] = ()) -> Gauge:
         return self._get(subsystem, name, "gauge",
                          lambda full: Gauge(full, help_, tuple(labels)))
+
+    def summary_view(self, subsystem: str, name: str, help_: str,
+                     label: str, read) -> SummaryView:
+        return self._get(
+            subsystem, name, "summary",
+            lambda full: SummaryView(full, help_, label, read))
 
     def _get(self, subsystem, name, kind, make):
         full = f"{_NAMESPACE}_{subsystem}_{name}"
@@ -449,6 +482,14 @@ trace_clock_offset_ms = DEFAULT.gauge(
     "trace", "clock_offset_ms",
     "Last wall-clock offset estimate (reader minus this node, ms) "
     "reported by a traces RPC caller that supplied its own clock")
+
+# Kept by libs/trace under the lock a span takes as it ends, never evicted
+# or drained: what a window adds to a stage's seconds in a process nobody
+# can profile (the node behind a sidecar). One series a span call site.
+trace_span_seconds = DEFAULT.summary_view(
+    "trace", "span_seconds",
+    "Spans ended since the process started and their total seconds, by "
+    "span name (libs/trace.py)", "name", _trace.span_totals)
 
 
 # --- the node health engine metric set (libs/watchdog.py) -------------------
@@ -740,6 +781,13 @@ sidecar_server_dispatch_clients = DEFAULT.histogram(
 sidecar_server_queue_lanes = DEFAULT.gauge(
     "sidecar", "server_queue_lanes",
     "Lanes currently queued in the coalescer awaiting dispatch")
+sidecar_server_queue_wait = DEFAULT.histogram(
+    "sidecar", "server_queue_wait_seconds",
+    "Time a verify request waited in the coalescer's queue, from submit "
+    "to the cut of the joint batch that took it (expired ones included)",
+    labels=("curve",),
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+             0.025, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 30))
 sidecar_server_overloads_total = DEFAULT.counter(
     "sidecar", "server_overloads_total",
     "Verify requests rejected by admission control (queue full)")

@@ -15,10 +15,19 @@ ring buffer:
     def _enter_propose(self, ...): ...
 
 Spans nest per thread (a thread-local stack carries the current parent),
-carry arbitrary JSON-able attrs, and cost ~1 µs each — cheap enough to
-leave on permanently. The ring holds the most recent ``capacity`` spans
+carry arbitrary JSON-able attrs, and cost a few µs each (≈6 on the
+builders' CPU host) — cheap enough to leave on permanently. The ring holds the most recent ``capacity`` spans
 (default 8192, env ``TMTPU_TRACE_CAPACITY``); older spans are evicted and
-counted, never blocking the hot path.
+counted, never blocking the hot path. Two things outlive the ring:
+
+- per-name cumulative ``(count, seconds)`` totals (``span_totals()``),
+  served as ``tendermint_trace_span_seconds{name}`` by libs/metrics, so a
+  process nobody can profile is still differenced over a window;
+- while a ``jax.profiler`` session runs in this process every span is also
+  a ``jax.profiler.TraceAnnotation`` of the same name, so the profiler's
+  trace holds the program's stages on the device trace's clock. JAX is
+  only ever looked up in ``sys.modules``: a process that has not loaded it
+  (a sidecar node must not) is never made to.
 
 Export formats:
 - ``to_chrome_trace(spans)``: the Chrome trace-event JSON (load in
@@ -38,11 +47,12 @@ import itertools
 import json
 import os
 import struct
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 _DEFAULT_CAPACITY = int(os.environ.get("TMTPU_TRACE_CAPACITY", "8192"))
 
@@ -161,6 +171,21 @@ def height_trace_id(chain_id: str, height: int) -> str:
     return h.hexdigest()[:16]
 
 
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` when JAX is already
+    loaded here and a profiler session is running, else None. Never
+    imports: with no session the cost is this lookup and one
+    ``is_enabled()`` (≈0.1 µs)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    # a jax still being imported on another thread has no profiler yet
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is None or not cls.is_enabled():
+        return None
+    return cls(name)
+
+
 class Span:
     """One completed (or in-flight) timed region. Times are
     ``time.perf_counter()`` seconds — monotonic, comparable across spans
@@ -228,6 +253,9 @@ class Tracer:
         self._tls = threading.local()
         self._enabled = True
         self._dropped = 0
+        # {span name: [count, seconds]} since process start; names are
+        # static strings, so its size is the number of call sites
+        self._totals: Dict[str, List] = {}
         # fleet identity + sampling for cross-process contexts
         self._node_id = ""
         self._chain_id = ""
@@ -294,6 +322,9 @@ class Tracer:
             sp.ctx_parent = ctx.parent_span_id
             sp.origin = ctx.origin
         stack.append(sp)
+        ann = _profiler_annotation(name)
+        if ann is not None:
+            ann.__enter__()
         try:
             yield sp
         except BaseException:
@@ -301,11 +332,16 @@ class Tracer:
             raise
         finally:
             sp.end_s = time.perf_counter()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             stack.pop()
             with self._lock:
                 if len(self._buf) == self._buf.maxlen:
                     self._dropped += 1
                 self._buf.append(sp)
+                tot = self._totals.setdefault(name, [0, 0.0])
+                tot[0] += 1
+                tot[1] += sp.end_s - sp.start_s
 
     def traced(self, name: Optional[str] = None):
         """Decorator form: the whole call body becomes one span."""
@@ -323,6 +359,31 @@ class Tracer:
             return wrapper
 
         return deco
+
+    # -- cross-thread handoff -----------------------------------------------
+
+    def handoff(self):
+        """What a worker thread needs to go on under the caller's span:
+        (the innermost open span or None, the current context or None).
+        Pass it to ``resume`` on the worker."""
+        stack = getattr(self._tls, "stack", None)
+        return (stack[-1] if stack else None), self.current_context()
+
+    @contextmanager
+    def resume(self, token):
+        """On a worker thread: spans opened inside record ``token``'s
+        span as their parent and carry its trace context, as if the
+        caller had run the body itself."""
+        parent, ctx = token
+        stack = self._stack()
+        if parent is not None:
+            stack.append(parent)
+        try:
+            with self.activate(ctx):
+                yield
+        finally:
+            if parent is not None:
+                stack.pop()
 
     # -- cross-process contexts ---------------------------------------------
 
@@ -432,6 +493,12 @@ class Tracer:
             self._dropped = 0
             return out
 
+    def span_totals(self) -> Dict[str, Tuple[int, float]]:
+        """{name: (count, seconds)} over every span ended since the
+        process started: unlike the ring, never evicted or drained."""
+        with self._lock:
+            return {n: (t[0], t[1]) for n, t in self._totals.items()}
+
     def summary(self) -> Dict:
         """Aggregate per span name: {name: {count, total_s, max_s}} plus
         ring bookkeeping — the cheap form served by the ``metrics``
@@ -531,6 +598,18 @@ def drain() -> List[Span]:
 
 def summary() -> Dict:
     return DEFAULT.summary()
+
+
+def span_totals() -> Dict[str, Tuple[int, float]]:
+    return DEFAULT.span_totals()
+
+
+def handoff():
+    return DEFAULT.handoff()
+
+
+def resume(token):
+    return DEFAULT.resume(token)
 
 
 def set_enabled(flag: bool) -> None:

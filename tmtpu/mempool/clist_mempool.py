@@ -28,7 +28,7 @@ from typing import Callable, List, Optional
 
 from tmtpu.abci import types as abci
 from tmtpu.crypto import tmhash
-from tmtpu.libs import txlat
+from tmtpu.libs import trace, txlat
 from tmtpu.libs.clist import CElement, CList
 
 
@@ -305,8 +305,6 @@ class BatchCheckMixin:
                 break
 
     def _process_admit_batch(self, batch: List[_AdmitEntry]) -> None:
-        from tmtpu.libs import metrics as _m
-
         # 1) signature screen: every signed-tx envelope in the gather
         #    resolves through ONE batch-verifier flush — sigcache hits
         #    cost no lane, duplicates collapse, breakers guard the
@@ -316,25 +314,34 @@ class BatchCheckMixin:
 
             lanes: List[_AdmitEntry] = []
             verifier = None
-            for en in batch:
-                if not _stx.is_signed(en.tx):
-                    continue
-                parsed = _stx.parse(en.tx)
-                if parsed is None:
-                    en.sig_failed = True
-                    continue
-                pub, sig, payload = parsed
-                if verifier is None:
-                    from tmtpu.crypto import batch as _crypto_batch
+            with trace.span("mempool.screen", txs=len(batch)):
+                for en in batch:
+                    if not _stx.is_signed(en.tx):
+                        continue
+                    parsed = _stx.parse(en.tx)
+                    if parsed is None:
+                        en.sig_failed = True
+                        continue
+                    pub, sig, payload = parsed
+                    if verifier is None:
+                        from tmtpu.crypto import batch as _crypto_batch
 
-                    verifier = _crypto_batch.new_batch_verifier()
-                verifier.add(pub, _stx.sign_bytes(payload), sig)
-                lanes.append(en)
+                        verifier = _crypto_batch.new_batch_verifier()
+                    verifier.add(pub, _stx.sign_bytes(payload), sig)
+                    lanes.append(en)
             if lanes:
-                _ok, mask = verifier.verify()
+                # the node's wait on the daemon (or the in-process flush)
+                with trace.span("mempool.verify", lanes=len(lanes)):
+                    _ok, mask = verifier.verify()
                 for en, ok in zip(lanes, mask):
                     if not ok:
                         en.sig_failed = True
+        with trace.span("mempool.check_tx", txs=len(batch)):
+            self._check_tx_batch(batch)
+
+    def _check_tx_batch(self, batch: List[_AdmitEntry]) -> None:
+        from tmtpu.libs import metrics as _m
+
         survivors: List[_AdmitEntry] = []
         for en in batch:
             if en.sig_failed:

@@ -206,7 +206,9 @@ class _Handler(BaseHTTPRequestHandler):
                 secs = float(q.get("seconds", ["5"])[0])
                 body = cpu_profile(min(secs, 60.0))
             elif path.endswith("/cmdline"):
-                body = "\x00".join(sys.argv)
+                # the whole command line, interpreter first, as Go's
+                # os.Args: sys.argv of a ``python -c`` child is ['-c']
+                body = "\x00".join(sys.orig_argv)
             else:
                 self.send_error(404)
                 return

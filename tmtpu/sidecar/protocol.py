@@ -242,12 +242,18 @@ class FrameReader:
         self._rd = DelimitedReader(stream, max_size=max_frame_bytes)
         self._message_types = message_types
 
-    def read_msg(self) -> ProtoMessage:
+    def read_body(self) -> bytes:
+        """Block until one whole frame body has arrived."""
         try:
-            body = self._rd.read_msg()
+            return self._rd.read_msg()
         except ValueError as exc:  # oversized frame / runaway varint
             raise ProtocolError(str(exc)) from exc
+
+    def decode(self, body: bytes) -> ProtoMessage:
         return decode_frame(body, self._message_types)
+
+    def read_msg(self) -> ProtoMessage:
+        return self.decode(self.read_body())
 
 
 def pack_mask(mask: List[bool]) -> bytes:

@@ -19,9 +19,10 @@ error verdict the client treats as "no answer, verify locally".
 
 Introspection: ``Ping``/``StatsRequest`` on the protocol socket, plus
 an optional HTTP listener (``health_laddr``) serving ``/healthz``
-(JSON snapshot, 200/503 by backend-breaker state) and ``/metrics``
+(JSON snapshot, 200/503 by backend-breaker state), ``/metrics``
 (Prometheus text) for curl/scrapers that don't speak the frame
-protocol.
+protocol, and ``/debug/profile?seconds=N`` (a ``jax.profiler`` trace of
+the daemon, the program's spans in it).
 
 Run it: ``python -m tmtpu sidecar --addr unix:///tmp/tmtpu-sidecar.sock``
 (cmd/__main__.py), point nodes at it with ``crypto.backend=sidecar``.
@@ -42,6 +43,7 @@ from tmtpu.crypto import encoding as _enc  # noqa: F401 — registers all
 # against that registry before anything else imports the curve modules)
 from tmtpu.crypto.keys import KEY_TYPES
 from tmtpu.libs import breaker as _bk
+from tmtpu.libs import trace
 from tmtpu.sidecar import protocol as proto
 from tmtpu.sidecar.coalescer import Coalescer, Overloaded
 from tmtpu.tpu import compat
@@ -63,7 +65,8 @@ class SidecarServer:
                  health_laddr: str = "",
                  server_id: str = "",
                  mesh_devices: Optional[int] = None,
-                 shard_min_lanes: Optional[int] = None):
+                 shard_min_lanes: Optional[int] = None,
+                 profile_dir: str = ""):
         self.addr = addr
         self._kind, self._target = proto.parse_addr(addr)
         if backend not in ("auto", "cpu", "tpu"):
@@ -79,6 +82,9 @@ class SidecarServer:
         self._max_frame_bytes = max_frame_bytes
         self._default_deadline_s = request_deadline_s
         self._health_laddr = health_laddr
+        # where GET /debug/profile writes (cmd_sidecar: <home>/data/profile)
+        self._profile_dir = profile_dir
+        self._profile_lock = threading.Lock()
         self.server_id = server_id or f"sidecar-{os.getpid()}"
         self.coalescer = Coalescer(
             self._engine_verify,
@@ -156,6 +162,38 @@ class SidecarServer:
                 self._max_lanes_per_dispatch)
         self._warmed = True
         return time.perf_counter() - t0
+
+    def profile(self, seconds: float) -> str:
+        """Run ``jax.profiler`` over this process for ``seconds`` (at
+        most 60) and return the directory that holds the trace. The
+        program's spans (libs/trace) are in it beside the device's
+        operations, on one clock. One profile at a time; only the device
+        engine has JAX open to profile."""
+        if not self._profile_dir:
+            raise RuntimeError("the daemon was started without a profile "
+                               "directory")
+        if not self._device_engine():
+            raise RuntimeError("the engine is the serial CPU verifier: "
+                               "jax.profiler has nothing to trace")
+        if not self._profile_lock.acquire(blocking=False):
+            raise RuntimeError("a profile is already running")
+        try:
+            import jax
+            from jax.profiler import ProfileOptions
+
+            os.makedirs(self._profile_dir, exist_ok=True)
+            opts = ProfileOptions()
+            opts.python_tracer_level = 0    # it slows every Python call
+            opts.host_tracer_level = 2      # TraceAnnotations: our spans
+            jax.profiler.start_trace(self._profile_dir,
+                                     profiler_options=opts)
+            try:
+                time.sleep(min(max(seconds, 0.0), 60.0))
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            self._profile_lock.release()
+        return self._profile_dir
 
     # --- lifecycle ---
 
@@ -339,7 +377,8 @@ class SidecarServer:
         wlock = threading.Lock()
 
         def send(msg) -> None:
-            data = proto.encode_frame(msg)
+            # a frame already encoded (the verify reply's) goes as it is
+            data = msg if isinstance(msg, bytes) else proto.encode_frame(msg)
             with wlock:
                 conn.sendall(data)
 
@@ -385,8 +424,16 @@ class SidecarServer:
                 max_lanes=self._max_lanes_per_dispatch,
                 max_frame_bytes=self._max_frame_bytes))
             while self._running:
+                items = None
                 try:
-                    msg = reader.read_msg()
+                    # the wait for the client's next frame gets no span
+                    body = reader.read_body()
+                    with trace.span("sidecar.conn.decode",
+                                    bytes=len(body)):
+                        msg = reader.decode(body)
+                        if isinstance(msg, proto.VerifyRequest):
+                            items = [(ln.pub_key, ln.msg, ln.sig, ln.power)
+                                     for ln in msg.lanes]
                 except proto.ProtocolError as exc:
                     _m.sidecar_server_protocol_errors.inc(kind="bad-frame")
                     try:
@@ -397,7 +444,7 @@ class SidecarServer:
                     return  # framing is lost; the stream cannot recover
                 if isinstance(msg, proto.VerifyRequest):
                     _m.sidecar_server_requests.inc(type="verify")
-                    self._handle_verify(client_id, msg, send)
+                    self._handle_verify(client_id, msg, items, send)
                 elif isinstance(msg, proto.Ping):
                     _m.sidecar_server_requests.inc(type="ping")
                     send(proto.Pong(
@@ -420,7 +467,7 @@ class SidecarServer:
             self._drop_conn(conn)
 
     def _handle_verify(self, client_id: str, req: proto.VerifyRequest,
-                       send) -> None:
+                       items: List[tuple], send) -> None:
         def reject(status: int, error: str) -> None:
             send(proto.VerifyResponse(
                 request_id=req.request_id, status=status,
@@ -443,8 +490,6 @@ class SidecarServer:
                    f"{len(req.lanes)} lanes exceeds per-request cap "
                    f"{self._max_lanes_per_dispatch}")
             return
-        items = [(ln.pub_key, ln.msg, ln.sig, ln.power)
-                 for ln in req.lanes]
         deadline_s = (req.deadline_ms / 1000.0 if req.deadline_ms
                       else self._default_deadline_s)
         # v2 piggybacked trace context: strict decode, garbage ⇒ untraced
@@ -452,9 +497,8 @@ class SidecarServer:
         trace_ctx = None
         if req.trace_ctx:
             from tmtpu.libs import metrics as _m
-            from tmtpu.libs import trace as _trace
 
-            trace_ctx = _trace.adopt(bytes(req.trace_ctx))
+            trace_ctx = trace.adopt(bytes(req.trace_ctx))
             if trace_ctx is None:
                 _m.trace_context_invalid.inc(transport="sidecar")
             else:
@@ -485,8 +529,9 @@ class SidecarServer:
                 except OSError:
                     pass
                 return
-            try:
-                send(proto.VerifyResponse(
+            with trace.span("sidecar.conn.encode",
+                            lanes=len(pending.mask)):
+                frame = proto.encode_frame(proto.VerifyResponse(
                     request_id=req.request_id,
                     status=proto.STATUS_OK,
                     mask=proto.pack_mask(pending.mask),
@@ -496,6 +541,8 @@ class SidecarServer:
                     dispatch_lanes=pending.dispatch_lanes,
                     dispatch_clients=pending.dispatch_clients,
                     dispatch_traces=pending.dispatch_traces))
+            try:
+                send(frame)
             except OSError:
                 pass  # client gone; the dispatch already happened
 
@@ -531,6 +578,20 @@ class SidecarServer:
                     body = _m.render_prometheus().encode()
                     self.send_response(200)
                     ctype = "text/plain; version=0.0.4"
+                elif self.path.startswith("/debug/profile"):
+                    from urllib.parse import parse_qs, urlparse
+
+                    query = parse_qs(urlparse(self.path).query)
+                    try:
+                        seconds = float(query.get("seconds", ["5"])[0])
+                        body = json.dumps({
+                            "seconds": seconds,
+                            "dir": server.profile(seconds)}).encode()
+                        self.send_response(200)
+                    except (ValueError, RuntimeError) as exc:
+                        body = json.dumps({"error": str(exc)}).encode()
+                        self.send_response(409)
+                    ctype = "application/json"
                 else:
                     body = b"not found\n"
                     self.send_response(404)

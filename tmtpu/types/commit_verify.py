@@ -74,18 +74,19 @@ def verify_commit(vals: ValidatorSet, chain_id: str, block_id: BlockID,
     with trace.span("commit_verify.verify_commit", height=height,
                     sigs=len(commit.signatures)):
         bv = crypto_batch.new_batch_verifier(backend)
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            # Verification is purely by index; sign bytes don't include the
-            # validator address (validator_set.go:692 does no address
-            # check). Power rides the batch so the +2/3 tally comes back
-            # fused from the device: only BlockIDFlagCommit votes count
-            # toward the threshold.
-            bv.add(vals.validators[idx].pub_key,
-                   commit.vote_sign_bytes(chain_id, idx), cs.signature,
-                   power=vals.validators[idx].voting_power if cs.for_block()
-                   else 0)
+        with trace.span("commit_verify.collect"):
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                # Verification is purely by index; sign bytes don't include
+                # the validator address (validator_set.go:692 does no
+                # address check). Power rides the batch so the +2/3 tally
+                # comes back fused from the device: only BlockIDFlagCommit
+                # votes count toward the threshold.
+                bv.add(vals.validators[idx].pub_key,
+                       commit.vote_sign_bytes(chain_id, idx), cs.signature,
+                       power=vals.validators[idx].voting_power
+                       if cs.for_block() else 0)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError(f"wrong signature (#{mask.index(False)})")
@@ -103,12 +104,13 @@ def verify_commit_light(vals: ValidatorSet, chain_id: str, block_id: BlockID,
     with trace.span("commit_verify.verify_commit_light", height=height,
                     sigs=len(commit.signatures)):
         bv = crypto_batch.new_batch_verifier(backend)
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val = vals.validators[idx]
-            bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
-                   cs.signature, power=val.voting_power)
+        with trace.span("commit_verify.collect"):
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                val = vals.validators[idx]
+                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
+                       cs.signature, power=val.voting_power)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError("wrong signature in commit")
@@ -137,21 +139,22 @@ def verify_commit_light_trusting(vals: ValidatorSet, chain_id: str,
         # address comparisons would dwarf the batch dispatch)
         by_address = {v.address: (i, v)
                       for i, v in enumerate(vals.validators)}
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            entry = by_address.get(cs.validator_address)
-            if entry is None:
-                continue  # unknown validator: skip (not in the trusted set)
-            val_idx, val = entry
-            if val_idx in seen:
-                raise VerificationError(
-                    f"double vote from validator "
-                    f"{cs.validator_address.hex()}"
-                )
-            seen.add(val_idx)
-            bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
-                   cs.signature, power=val.voting_power)
+        with trace.span("commit_verify.collect"):
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                entry = by_address.get(cs.validator_address)
+                if entry is None:
+                    continue  # unknown validator: not in the trusted set
+                val_idx, val = entry
+                if val_idx in seen:
+                    raise VerificationError(
+                        f"double vote from validator "
+                        f"{cs.validator_address.hex()}"
+                    )
+                seen.add(val_idx)
+                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
+                       cs.signature, power=val.voting_power)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError("wrong signature in commit")
