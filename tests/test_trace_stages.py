@@ -237,11 +237,11 @@ def test_queue_wait_observed_once_a_request_expired_ones_too():
     co.start()
     try:
         # the first holds the batch open 50 ms; the second has expired by
-        # the cut and is answered without a lane dispatched
-        a = co.submit("c1", "ed25519", _request_lanes(3, b"qa"), False,
-                      deadline_s=5.0)
-        b = co.submit("c2", "ed25519", _request_lanes(2, b"qb"), False,
-                      deadline_s=0.001)
+        # the cut and is answered without a lane dispatched (lanes signed
+        # beforehand: on a loaded host signing can outlast the 50 ms)
+        lanes_a, lanes_b = _request_lanes(3, b"qa"), _request_lanes(2, b"qb")
+        a = co.submit("c1", "ed25519", lanes_a, False, deadline_s=5.0)
+        b = co.submit("c2", "ed25519", lanes_b, False, deadline_s=0.001)
         assert a.wait(10) and b.wait(10)
         assert a.mask == [True] * 3 and b.failure == "expired"
         c = co.submit("c1", "ed25519", _request_lanes(1, b"qc"), False)

@@ -16,7 +16,7 @@ from tmtpu.types.part_set import PartSet
 from tmtpu.types.priv_validator import MockPV
 from tmtpu.types.validator import Validator, ValidatorSet
 from tmtpu.types.vote import PRECOMMIT, PREVOTE, ErrVoteConflictingVotes, \
-    Vote, VoteError
+    Vote, VoteError, vote_sign_bytes_template
 from tmtpu.types.vote_set import VoteSet
 
 CHAIN_ID = "test_chain"
@@ -134,6 +134,99 @@ def test_nil_vote_sign_bytes_differ():
     assert v1.sign_bytes(CHAIN_ID) != v2.sign_bytes(CHAIN_ID)
 
 
+# --- Sign bytes from a template ---------------------------------------------
+
+BID = BlockID(b"\x01" * 32, 1, b"\x02" * 32)
+GO_ZERO_TIME = -62_135_596_800 * 10**9  # time.Time{}: a ten-byte varint
+
+TIMESTAMPS = {
+    "zero": 0,                                    # 2a 00
+    "whole_seconds": 1_700_000_000 * 10**9,       # nanos 0
+    "nanos_only": 123_456_789,                    # seconds 0
+    "go_zero_time": GO_ZERO_TIME,
+    "nanos_max": 1_700_000_000 * 10**9 + 999_999_999,
+    "just_before_1970": -1,                       # seconds -1, nanos 999,999,999
+    "seconds_and_nanos": 1_700_000_000 * 10**9 + 5,
+}
+CHAIN_IDS = {
+    "empty": "",
+    "15_chars": "test-chain-0015",
+    "50_chars": "c" * 50,                         # the two-byte length prefix
+}
+
+
+def _vote_sign_bytes(chain_id, type, height, round, block_id, ts):
+    return Vote(type, height, round, block_id, ts, b"\xaa" * 20,
+                0).sign_bytes(chain_id)
+
+
+@pytest.mark.parametrize("block_id", [BID, BlockID()], ids=["block", "nil"])
+@pytest.mark.parametrize("chain_id", CHAIN_IDS.values(), ids=CHAIN_IDS.keys())
+@pytest.mark.parametrize("ts", TIMESTAMPS.values(), ids=TIMESTAMPS.keys())
+def test_template_equals_vote_sign_bytes(ts, chain_id, block_id):
+    got = vote_sign_bytes_template(chain_id, PRECOMMIT, 7, 2, block_id)(ts)
+    assert got == _vote_sign_bytes(chain_id, PRECOMMIT, 7, 2, block_id, ts)
+
+
+@pytest.mark.parametrize("type", [PREVOTE, PRECOMMIT],
+                         ids=["prevote", "precommit"])
+@pytest.mark.parametrize("round", [0, 3])
+@pytest.mark.parametrize("height", [0, 1, 2**40])
+def test_template_height_round_type(height, round, type):
+    template = vote_sign_bytes_template(CHAIN_ID, type, height, round, BID)
+    for ts in TIMESTAMPS.values():
+        assert template(ts) == _vote_sign_bytes(CHAIN_ID, type, height,
+                                                round, BID, ts)
+
+
+@pytest.mark.parametrize("chain_id,ts,want", [
+    # types/vote_test.go TestVoteSignBytesTestVectors: precommit, height 1,
+    # round 1, time.Time{}, no chain id
+    ("", GO_ZERO_TIME,
+     "2108021101000000000000001901000000000000002a0b088092b8c398feffffff01"),
+    ("test_chain_id", 0,
+     "2508021101000000000000001901000000000000002a00"
+     "320d746573745f636861696e5f6964"),
+], ids=["reference_vector", "timestamp_0_with_chain_id"])
+def test_template_reference_vectors(chain_id, ts, want):
+    got = vote_sign_bytes_template(chain_id, PRECOMMIT, 1, 1, BlockID())(ts)
+    assert got.hex() == want
+    assert got == _vote_sign_bytes(chain_id, PRECOMMIT, 1, 1, BlockID(), ts)
+
+
+def _signed_commit(vals, pvs, height=1, round=0, absent=(), nil=()):
+    """A commit signed vote by vote over ``Vote.sign_bytes``, timestamps a
+    third of a second apart so seconds and nanos both vary."""
+    sigs = []
+    for i, pv in enumerate(pvs):
+        if i in absent:
+            sigs.append(CommitSig.absent())
+            continue
+        v = mk_vote(pv, vals, i, height=height, round=round,
+                    block_id=BlockID() if i in nil else BID,
+                    ts=1_700_000_000 * 10**9 + i * 333_333_333)
+        sigs.append(CommitSig(
+            BLOCK_ID_FLAG_NIL if i in nil else BLOCK_ID_FLAG_COMMIT,
+            v.validator_address, v.timestamp, v.signature))
+    return Commit(height, round, BID, sigs)
+
+
+@pytest.mark.parametrize("chain_id", CHAIN_IDS.values(), ids=CHAIN_IDS.keys())
+@pytest.mark.parametrize("height,round", [(1, 0), (1, 1), (2**40, 0),
+                                          (2**40, 9)])
+def test_commit_vote_sign_bytes_nil_beside_block(height, round, chain_id):
+    vals, pvs = mk_valset(5)
+    commit = _signed_commit(vals, pvs, height, round, absent=(1,), nil=(3,))
+    sign_bytes = commit.vote_sign_bytes_for(chain_id)
+    for idx, cs in enumerate(commit.signatures):
+        want = _vote_sign_bytes(chain_id, PRECOMMIT, height, round,
+                                cs.block_id(BID), cs.timestamp)
+        assert sign_bytes(cs) == want
+        assert commit.vote_sign_bytes(chain_id, idx) == want
+    assert sign_bytes(commit.signatures[3]) != sign_bytes(
+        commit.signatures[2])
+
+
 # --- VoteSet ----------------------------------------------------------------
 
 
@@ -246,6 +339,98 @@ def test_verify_commit_light_trusting_different_valset():
     commit = _make_commit(vals, pvs, bid)
     # trusting verify against the same set but trust level 2/3
     vals.verify_commit_light_trusting(CHAIN_ID, commit, 2, 3)
+
+
+# the four entries, one calling convention: raise what the entry reports
+def _entry_verify_commit(vals, commit, height=1):
+    vals.verify_commit(CHAIN_ID, BID, height, commit, backend="cpu")
+
+
+def _entry_light(vals, commit, height=1):
+    vals.verify_commit_light(CHAIN_ID, BID, height, commit, backend="cpu")
+
+
+def _entry_light_trusting(vals, commit, height=1):
+    vals.verify_commit_light_trusting(CHAIN_ID, commit, 2, 3, backend="cpu")
+
+
+def _entry_light_batch(vals, commit, height=1):
+    err, = commit_verify.verify_commits_light_batch(
+        [(vals, CHAIN_ID, BID, height, commit)], backend="cpu")
+    if err is not None:
+        raise err
+
+
+ENTRIES = pytest.mark.parametrize("entry", [
+    _entry_verify_commit, _entry_light, _entry_light_trusting,
+    _entry_light_batch,
+], ids=["verify_commit", "light", "light_trusting", "light_batch"])
+
+
+@ENTRIES
+def test_commit_entries_accept_block_nil_and_absent(entry):
+    vals, pvs = mk_valset(7)
+    entry(vals, _signed_commit(vals, pvs, absent=(2,), nil=(5,)))
+
+
+@ENTRIES
+def test_commit_entries_refuse_tampered_signature(entry):
+    vals, pvs = mk_valset(7)
+    commit = _signed_commit(vals, pvs, absent=(2,), nil=(5,))
+    sig = commit.signatures[4].signature
+    commit.signatures[4].signature = sig[:-1] + bytes([sig[-1] ^ 1])
+    with pytest.raises(commit_verify.VerificationError) as e:
+        entry(vals, commit)
+    # verify_commit names the lane: validator 4 is the fourth signature
+    # present (2 is absent), so #3
+    assert str(e.value) == ("wrong signature (#3)"
+                            if entry is _entry_verify_commit
+                            else "wrong signature in commit")
+
+
+@ENTRIES
+def test_commit_entries_too_many_nil_votes(entry):
+    vals, pvs = mk_valset(7)
+    commit = _signed_commit(vals, pvs, absent=(2,), nil=(0, 1, 5))
+    with pytest.raises(commit_verify.ErrNotEnoughVotingPowerSigned) as e:
+        entry(vals, commit)
+    assert (e.value.got, e.value.needed) == (30, 46)
+
+
+@ENTRIES
+def test_commit_entries_keep_nothing_between_calls(entry):
+    vals, pvs = mk_valset(7)
+    commit = _signed_commit(vals, pvs, absent=(2,), nil=(5,))
+    entry(vals, commit)
+    commit.signatures[4].timestamp += 1
+    with pytest.raises(commit_verify.VerificationError) as e:
+        entry(vals, commit)
+    assert "wrong signature" in str(e.value)
+    commit.signatures[4].timestamp -= 1
+    entry(vals, commit)
+
+
+def test_verify_commits_light_batch_one_bad_entry_in_a_run():
+    vals, pvs = mk_valset(7)
+    commits = [_signed_commit(vals, pvs, height=h, absent=(2,), nil=(5,))
+               for h in (1, 2, 3, 4)]
+    commits[1].signatures[6].signature = bytes(64)
+    entries = [(vals, CHAIN_ID, BID, c.height, c) for c in commits]
+    entries.append((vals, CHAIN_ID, BID, 6, commits[0]))
+    entries.append((vals, CHAIN_ID, BID, 5, _signed_commit(
+        vals, pvs, height=5, nil=(0, 1, 5))))
+    errs = commit_verify.verify_commits_light_batch(entries, backend="cpu")
+    assert [(type(e), str(e)) if e else None for e in errs] == [
+        None,
+        (commit_verify.VerificationError, "wrong signature in commit"),
+        None,
+        None,
+        (commit_verify.VerificationError,
+         "Invalid commit -- wrong height: 6 vs 1"),
+        (commit_verify.ErrNotEnoughVotingPowerSigned,
+         "invalid commit -- insufficient voting power: got 40, "
+         "needed more than 46"),
+    ]
 
 
 # --- Header / Block / PartSet ----------------------------------------------

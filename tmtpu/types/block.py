@@ -6,7 +6,7 @@ matches the reference's nanosecond-precision time.Time canonicalization).
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 from tmtpu.crypto import tmhash
 from tmtpu.crypto.merkle import hash_from_byte_slices
@@ -190,24 +190,47 @@ class Commit:
     def size(self) -> int:
         return len(self.signatures)
 
-    def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
-        """Reconstruct validator val_idx's canonical precommit sign bytes
-        (block.go:807 Commit.VoteSignBytes) — per-validator timestamps make
-        each one distinct."""
+    def vote_sign_bytes_for(self, chain_id: str
+                            ) -> Callable[[CommitSig], bytes]:
+        """One call's way from a CommitSig of this commit to its canonical
+        precommit sign bytes. Type, height, round, block id and chain id
+        are the commit's, so they are encoded once
+        (``vote.vote_sign_bytes_template``) and each signature fills in its
+        timestamp; votes for the block and nil votes differ in the block id
+        only, so there are two templates, the nil one made when the first
+        nil vote asks for it.
+
+        Nothing is kept on the Commit or its CommitSigs: a node that
+        replays a chain sees each commit once, so a memo would only help a
+        caller that verifies the same object again, and a CommitSig edited
+        in place must never meet bytes made before the edit. The templates
+        live as long as the function returned."""
         from tmtpu.types import vote as vote_mod
 
-        cs = self.signatures[val_idx]
-        v = vote_mod.Vote(
-            type=pb.SIGNED_MSG_TYPE_PRECOMMIT,
-            height=self.height,
-            round=self.round,
-            block_id=cs.block_id(self.block_id),
-            timestamp=cs.timestamp,
-            validator_address=cs.validator_address,
-            validator_index=val_idx,
-            signature=cs.signature,
-        )
-        return v.sign_bytes(chain_id)
+        def template(block_id: BlockID) -> Callable[[int], bytes]:
+            return vote_mod.vote_sign_bytes_template(
+                chain_id, pb.SIGNED_MSG_TYPE_PRECOMMIT, self.height,
+                self.round, block_id)
+
+        for_block = template(self.block_id)
+        for_nil = None
+
+        def sign_bytes(cs: CommitSig) -> bytes:
+            nonlocal for_nil
+            if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT:
+                return for_block(cs.timestamp)
+            if for_nil is None:
+                for_nil = template(BlockID())
+            return for_nil(cs.timestamp)
+
+        return sign_bytes
+
+    def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
+        """Validator val_idx's canonical precommit sign bytes (block.go:807
+        Commit.VoteSignBytes): the single-signature entry to
+        ``vote_sign_bytes_for``, which a caller that wants more than one
+        should take itself."""
+        return self.vote_sign_bytes_for(chain_id)(self.signatures[val_idx])
 
     def bit_array(self):
         from tmtpu.libs.bits import BitArray

@@ -16,6 +16,12 @@ commit. Semantics preserved:
   fails the call, which is strictly stricter than the reference's
   early-exit, never weaker: a commit accepted here is accepted there.
 
+Sign bytes: every entry asks the commit once a call for
+``Commit.vote_sign_bytes_for(chain_id)`` and hands it each CommitSig. What
+a commit's precommits share (type, height, round, block id, chain id) is
+encoded once there and each signature fills in its timestamp; nothing is
+kept on the Commit, so a call starts from the CommitSigs as they are.
+
 Bound onto ValidatorSet at import (kept separate to avoid a module cycle
 between validator.py and block.py).
 """
@@ -75,6 +81,7 @@ def verify_commit(vals: ValidatorSet, chain_id: str, block_id: BlockID,
                     sigs=len(commit.signatures)):
         bv = crypto_batch.new_batch_verifier(backend)
         with trace.span("commit_verify.collect"):
+            sign_bytes = commit.vote_sign_bytes_for(chain_id)
             for idx, cs in enumerate(commit.signatures):
                 if cs.is_absent():
                     continue
@@ -83,10 +90,9 @@ def verify_commit(vals: ValidatorSet, chain_id: str, block_id: BlockID,
                 # address check). Power rides the batch so the +2/3 tally
                 # comes back fused from the device: only BlockIDFlagCommit
                 # votes count toward the threshold.
-                bv.add(vals.validators[idx].pub_key,
-                       commit.vote_sign_bytes(chain_id, idx), cs.signature,
-                       power=vals.validators[idx].voting_power
-                       if cs.for_block() else 0)
+                val = vals.validators[idx]
+                bv.add(val.pub_key, sign_bytes(cs), cs.signature,
+                       power=val.voting_power if cs.for_block() else 0)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError(f"wrong signature (#{mask.index(False)})")
@@ -105,12 +111,13 @@ def verify_commit_light(vals: ValidatorSet, chain_id: str, block_id: BlockID,
                     sigs=len(commit.signatures)):
         bv = crypto_batch.new_batch_verifier(backend)
         with trace.span("commit_verify.collect"):
+            sign_bytes = commit.vote_sign_bytes_for(chain_id)
             for idx, cs in enumerate(commit.signatures):
                 if not cs.for_block():
                     continue
                 val = vals.validators[idx]
-                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
-                       cs.signature, power=val.voting_power)
+                bv.add(val.pub_key, sign_bytes(cs), cs.signature,
+                       power=val.voting_power)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError("wrong signature in commit")
@@ -140,7 +147,8 @@ def verify_commit_light_trusting(vals: ValidatorSet, chain_id: str,
         by_address = {v.address: (i, v)
                       for i, v in enumerate(vals.validators)}
         with trace.span("commit_verify.collect"):
-            for idx, cs in enumerate(commit.signatures):
+            sign_bytes = commit.vote_sign_bytes_for(chain_id)
+            for cs in commit.signatures:
                 if not cs.for_block():
                     continue
                 entry = by_address.get(cs.validator_address)
@@ -153,8 +161,8 @@ def verify_commit_light_trusting(vals: ValidatorSet, chain_id: str,
                         f"{cs.validator_address.hex()}"
                     )
                 seen.add(val_idx)
-                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
-                       cs.signature, power=val.voting_power)
+                bv.add(val.pub_key, sign_bytes(cs), cs.signature,
+                       power=val.voting_power)
         all_ok, mask, tallied = bv.verify_tally()
     if not all_ok:
         raise VerificationError("wrong signature in commit")
@@ -188,12 +196,12 @@ def verify_commits_light_batch(entries, backend=None):
                 segments.append((start, 0, 0, 0, e))
                 continue
             tallied = 0
+            sign_bytes = commit.vote_sign_bytes_for(chain_id)
             for idx, cs in enumerate(commit.signatures):
                 if not cs.for_block():
                     continue
                 val = vals.validators[idx]
-                bv.add(val.pub_key, commit.vote_sign_bytes(chain_id, idx),
-                       cs.signature)
+                bv.add(val.pub_key, sign_bytes(cs), cs.signature)
                 tallied += val.voting_power
             segments.append((start, bv.count() - start, tallied,
                              vals.total_voting_power() * 2 // 3, None))

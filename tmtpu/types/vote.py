@@ -8,7 +8,7 @@ delimited proto encoding of the CanonicalVote/CanonicalProposal
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 from tmtpu.libs import protoio
 from tmtpu.types import pb
@@ -35,6 +35,47 @@ def canonicalize_vote(chain_id: str, type: int, height: int, round: int,
         timestamp=pb.Timestamp.from_unix_nanos(timestamp),
         chain_id=chain_id,
     )
+
+
+# CanonicalVote's timestamp is a ``msg!`` field: written also when empty, as
+# its tag and a zero length (2a 00).
+_EMPTY_TIMESTAMP_FIELD = pb.CanonicalVote().encode()
+
+
+def vote_sign_bytes_template(chain_id: str, type: int, height: int,
+                             round: int, block_id: BlockID
+                             ) -> Callable[[int], bytes]:
+    """The sign bytes of every vote that shares (chain_id, type, height,
+    round, block_id), as a function of the one thing left to vary, the
+    timestamp (unix nanos): byte for byte ``Vote.sign_bytes`` of such a
+    vote, without a CanonicalVote built and encoded per vote.
+
+    Fields 1-4 (up to the timestamp's tag) and field 6 (chain_id) are
+    encoded once, by the encoder above, so what proto3 leaves off the wire
+    -- height 0, round 0, a nil block id, an empty chain id -- is its
+    business and not repeated here. Per timestamp only the Timestamp body
+    is written by hand (seconds and nanos split as
+    ``pb.Timestamp.from_unix_nanos`` splits them, each left out when zero),
+    then the length prefix, which moves with it."""
+    head = canonicalize_vote(chain_id="", type=type, height=height,
+                             round=round, block_id=block_id,
+                             timestamp=0).encode()
+    prefix = head[:-1]  # ends with the empty timestamp: keep its tag
+    suffix = pb.CanonicalVote(chain_id=chain_id).encode()[
+        len(_EMPTY_TIMESTAMP_FIELD):]
+    encode_varint = protoio.encode_varint
+    encode_uvarint = protoio.encode_uvarint
+
+    def sign_bytes(timestamp: int) -> bytes:
+        seconds, nanos = divmod(timestamp, 1_000_000_000)
+        ts = b"\x08" + encode_varint(seconds) if seconds else b""
+        if nanos:
+            ts += b"\x10" + encode_uvarint(nanos)
+        # len(ts) <= 17: one byte
+        body = prefix + bytes((len(ts),)) + ts + suffix
+        return encode_uvarint(len(body)) + body
+
+    return sign_bytes
 
 
 class Vote:
