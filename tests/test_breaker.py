@@ -277,7 +277,7 @@ def test_half_open_not_advanced_by_sigcache_hits(monkeypatch):
 
     device_calls = []
 
-    def fake_batch_verify(pks, msgs, sigs):
+    def fake_batch_verify(pks, msgs, sigs, min_lanes=0):
         device_calls.append(len(pks))
         return [True] * len(pks)
 

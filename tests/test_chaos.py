@@ -112,7 +112,7 @@ def test_breaker_opens_falls_back_half_opens_and_closes(monkeypatch,
     site = tv._FAULT_ED_BATCH
     device_calls = []
 
-    def fake_batch_verify(pks, msgs, sigs):
+    def fake_batch_verify(pks, msgs, sigs, min_lanes=0):
         device_calls.append(len(pks))
         faultinject.fire(site)
         return [True] * len(pks)
@@ -179,7 +179,7 @@ def test_hung_device_returns_cpu_result_within_deadline(monkeypatch,
     monkeypatch.setenv("TMTPU_TPU_BATCH_DEADLINE", "0.2")
     hang = threading.Event()
 
-    def hung_batch_verify(pks, msgs, sigs):
+    def hung_batch_verify(pks, msgs, sigs, min_lanes=0):
         hang.wait(30.0)
         return [True] * len(pks)
 

@@ -11,7 +11,17 @@ per block (reactor.go:366), a contiguous run of fetched blocks is verified
 with ONE batched dispatch over all their commits' signatures
 (types.commit_verify.verify_commits_light_batch) — fast-sync replay is the
 BASELINE "per-block Commit batch verification" config, batched further
-across blocks.
+across blocks. A run is sized in lanes (common.run_shape: as many blocks
+as the validator set's size leaves of RUN_LANES), every run's dispatch is
+padded to that one shape, and the pool routine compiles it before it asks
+for the first block: no run, first, short or last, meets a shape the
+process has not compiled, whatever the validator set's size.
+
+Stricter than the reference, never weaker: block h is applied only if
+more than 2/3 of its validators' power signed it in block h+1's LastCommit
+and EVERY for-block signature of that commit verifies (VerifyCommitLight
+stops at 2/3); validate_block's full verify_commit of LastCommit runs on
+every block; block and state are saved before the next block is applied.
 """
 
 from __future__ import annotations
@@ -21,21 +31,20 @@ import time
 from typing import Optional
 
 from tmtpu.blocksync.common import (
-    BLOCKCHAIN_CHANNEL, BlockServingMixin, verify_block_run,
+    BLOCKCHAIN_CHANNEL, BlockServingMixin, run_shape, verify_block_run,
+    warm_run,
 )
 from tmtpu.blocksync.msgs import BlockRequestPB, BlocksyncMessagePB
 from tmtpu.blocksync.pool import BlockPool
+from tmtpu.libs import metrics, trace
 from tmtpu.p2p.conn.connection import ChannelDescriptor
 from tmtpu.p2p.switch import Peer, Reactor
-from tmtpu.types import commit_verify
-from tmtpu.types.block import Block, BlockID
-from tmtpu.types.part_set import PartSet
+from tmtpu.types.block import Block
 
 
 TRY_SYNC_INTERVAL_S = 0.01          # trySyncIntervalMS
 STATUS_UPDATE_INTERVAL_S = 10.0     # statusUpdateIntervalSeconds
 SWITCH_TO_CONSENSUS_INTERVAL_S = 1.0
-MAX_BATCH_BLOCKS = 32               # commits fused per device dispatch
 
 
 class BlocksyncReactor(BlockServingMixin, Reactor):
@@ -87,20 +96,22 @@ class BlocksyncReactor(BlockServingMixin, Reactor):
         self.pool.remove_peer(peer.node_id)
 
     def receive(self, channel_id: int, peer: Peer, msg_bytes: bytes) -> None:
-        msg = BlocksyncMessagePB.decode(msg_bytes)
-        if msg.block_request is not None:
-            self._respond_to_peer(msg.block_request.height, peer)
-        elif msg.block_response is not None:
-            block = Block.from_proto(msg.block_response.block)
-            self.pool.add_block(peer.node_id, block, len(msg_bytes))
-        elif msg.status_request is not None:
-            peer.try_send(BLOCKCHAIN_CHANNEL, self._status_msg())
-        elif msg.status_response is not None:
-            self.pool.set_peer_range(peer.node_id,
-                                     msg.status_response.base,
-                                     msg.status_response.height)
-        elif msg.no_block_response is not None:
-            pass  # reactor.go just logs it
+        # wire decode + the pool's bookkeeping, on the connection's thread
+        with trace.span("blocksync.receive", bytes=len(msg_bytes)):
+            msg = BlocksyncMessagePB.decode(msg_bytes)
+            if msg.block_request is not None:
+                self._respond_to_peer(msg.block_request.height, peer)
+            elif msg.block_response is not None:
+                block = Block.from_proto(msg.block_response.block)
+                self.pool.add_block(peer.node_id, block, len(msg_bytes))
+            elif msg.status_request is not None:
+                peer.try_send(BLOCKCHAIN_CHANNEL, self._status_msg())
+            elif msg.status_response is not None:
+                self.pool.set_peer_range(peer.node_id,
+                                         msg.status_response.base,
+                                         msg.status_response.height)
+            elif msg.no_block_response is not None:
+                pass  # reactor.go just logs it
 
     # serving + handover (status/respond/stop-peer/switch-to-consensus)
     # come from BlockServingMixin — shared with BlocksyncReactorV2
@@ -108,6 +119,9 @@ class BlocksyncReactor(BlockServingMixin, Reactor):
     # -- the sync loop (reactor.go poolRoutine) -----------------------------
 
     def _pool_routine(self, state_synced: bool = False) -> None:
+        # before the first request: a run's shape compiles for tens of
+        # seconds at first sight, and this thread is the one that waits
+        warm_run(self.state.validators, self.verify_backend)
         last_status = 0.0
         last_switch_check = 0.0
         while not self._stopped.is_set():
@@ -115,14 +129,11 @@ class BlocksyncReactor(BlockServingMixin, Reactor):
             if now - last_status > STATUS_UPDATE_INTERVAL_S:
                 last_status = now
                 self.broadcast_status_request()
-            for peer_id, height in self.pool.make_requests():
-                peer = self.switch.peers.get(peer_id) if self.switch else None
-                if peer is not None:
-                    peer.try_send(
-                        BLOCKCHAIN_CHANNEL,
-                        BlocksyncMessagePB(
-                            block_request=BlockRequestPB(height=height)
-                        ).encode())
+            # until the scheduler has nothing left to hand out: a peer
+            # that answered while the last run was applied has room for
+            # the next 20, and the pool should hold the next run whole
+            while self._send_requests():
+                pass
             if now - last_switch_check > SWITCH_TO_CONSENSUS_INTERVAL_S:
                 last_switch_check = now
                 if self.pool.is_caught_up():
@@ -131,23 +142,41 @@ class BlocksyncReactor(BlockServingMixin, Reactor):
             if not self._try_sync_batch():
                 self._stopped.wait(TRY_SYNC_INTERVAL_S)
 
+    def _send_requests(self) -> bool:
+        """One scheduling pass of the pool; True if a request went out."""
+        sent = False
+        for peer_id, height in self.pool.make_requests():
+            peer = self.switch.peers.get(peer_id) if self.switch else None
+            if peer is not None:
+                sent |= bool(peer.try_send(
+                    BLOCKCHAIN_CHANNEL,
+                    BlocksyncMessagePB(
+                        block_request=BlockRequestPB(height=height)
+                    ).encode()))
+        return sent
+
     def _try_sync_batch(self) -> bool:
         """Verify + apply a contiguous run of fetched blocks. The commits of
-        the whole run are batch-verified in one dispatch; the verified
-        prefix is applied, the first failure re-requested. Returns True if
-        any block was applied."""
-        run = self.pool.peek_run(MAX_BATCH_BLOCKS + 1)
+        the whole run are batch-verified in one dispatch, padded to the one
+        shape ``run_shape`` gives this validator set; the verified prefix
+        is applied, the first failure re-requested. Returns True if any
+        block was applied."""
+        n_blocks, lanes = run_shape(self.state.validators)
+        run = self.pool.peek_run(n_blocks + 1)
         if len(run) < 2:
             return False
-        # block h is verified by block h+1's LastCommit (reactor.go:366);
-        # the fused path needs one valset for the whole run — valset changes
-        # mid-run (rare) fall back to block-at-a-time
+        # block h is verified by block h+1's LastCommit (reactor.go:366)
+        # against ONE valset, the state's: the run stops short of the
+        # first block that names another set (rare; a first block that
+        # does is refused by validate_block below, whoever signed it)
+        vals_hash = self.state.validators.hash()
+        for i in range(1, len(run) - 1):
+            if run[i].header.validators_hash != vals_hash:
+                run = run[:i + 1]
+                break
         blocks, successors = run[:-1], run[1:]
-        vals_now = self.state.validators
-        if any(b.header.validators_hash != vals_now.hash() for b in blocks):
-            return self._try_sync_one()
         results, parts_bids = verify_block_run(
-            self.state, blocks, successors, self.verify_backend)
+            self.state, blocks, successors, self.verify_backend, lanes)
         applied = False
         for blk, nxt, err, (parts, bid) in zip(blocks, successors, results,
                                                parts_bids):
@@ -159,40 +188,26 @@ class BlocksyncReactor(BlockServingMixin, Reactor):
             applied = True
         return applied
 
-    def _try_sync_one(self) -> bool:
-        first, second = self.pool.peek_two_blocks()
-        if first is None or second is None:
-            return False
-        parts = PartSet.from_data(first.encode())
-        bid = BlockID(first.hash(), parts.total, parts.hash)
-        try:
-            self.state.validators.verify_commit_light(
-                self.state.chain_id, bid, first.header.height,
-                second.last_commit, backend=self.verify_backend)
-        except commit_verify.VerificationError as e:
-            self._handle_bad_block(first.header.height, e)
-            return False
-        return self._apply_one(first, second, parts, bid)
-
-    def _apply_one(self, block: Block, successor: Block,
-                   parts=None, bid=None) -> bool:
-        if parts is None:
-            parts = PartSet.from_data(block.encode())
-            bid = BlockID(block.hash(), parts.total, parts.hash)
-        try:
-            self.block_exec.validate_block(self.state, block)
-        except Exception as e:  # noqa: BLE001
-            self._handle_bad_block(block.header.height, e)
-            return False
-        self.pool.pop_request()
-        self.store.save_block(block, parts, successor.last_commit)
-        self.state, _ = self.block_exec.apply_block(self.state, bid, block)
+    def _apply_one(self, block: Block, successor: Block, parts, bid) -> bool:
+        with trace.span("blocksync.apply", height=block.header.height):
+            try:
+                self.block_exec.validate_block(self.state, block)
+            except Exception as e:  # noqa: BLE001
+                self._handle_bad_block(block.header.height, e)
+                return False
+            self.pool.pop_request()
+            with trace.span("blocksync.save_block"):
+                self.store.save_block(block, parts, successor.last_commit)
+            self.state, _ = self.block_exec.apply_block(self.state, bid,
+                                                        block)
         self.blocks_synced += 1
+        metrics.blocksync_blocks_applied.inc()
         return True
 
     def _handle_bad_block(self, height: int, err) -> None:
         # punish the server of the bad block and its successor's server
         # (either could have lied — reactor.go:377-390)
+        metrics.blocksync_bad_blocks.inc()
         for h in (height, height + 1):
             bad = self.pool.redo_request(h)
             if bad is not None:

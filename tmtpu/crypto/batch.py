@@ -517,6 +517,20 @@ def warm_daemon(max_lanes_per_dispatch: int
         _warm(ED25519, sizes, tally=True)
 
 
+def warm_pinned(min_lanes: int, backend: Optional[str] = None
+                ) -> List[Tuple[str, int, bool, float]]:
+    """Compile the one shape the ed25519 mask flushes of a verifier made
+    with ``new_batch_verifier(backend, min_lanes=min_lanes)`` meet,
+    whatever their length, if ``backend`` resolves to the device; a cpu
+    or sidecar process has nothing to compile and gets ``[]``. The
+    blocksync reactor calls it before it asks for the first block, so
+    that no run's first-sight trace + lower + compile lands on the sync
+    thread."""
+    if _resolve_backend(backend) != "tpu":
+        return []
+    return _warm(ED25519, [min_lanes], tally=False)
+
+
 class BatchVerifier(keys.BatchVerifier):
     """Accumulate (pubkey, msg, sig[, power]) items, then verify at once.
 
@@ -527,9 +541,16 @@ class BatchVerifier(keys.BatchVerifier):
     and only the deduped miss list reaches the backend hook
     ``_verify_pending``. Successful lanes are inserted into the cache on
     the way out. ``self.cache_stats`` carries the per-flush breakdown
-    (lanes/hits/dedup/dispatched) for callers and the timeline."""
+    (lanes/hits/dedup/dispatched) for callers and the timeline.
 
-    def __init__(self):
+    ``min_lanes`` pins the device shape of this verifier's ed25519 mask
+    flush (``verify()``): whatever the sigcache leaves of it pads as if
+    it held that many lanes, so a caller whose flushes vary in length
+    meets the one shape it warmed (``warm_pinned``). The serial and
+    sidecar backends have no shape and ignore it."""
+
+    def __init__(self, min_lanes: int = 0):
+        self.min_lanes = int(min_lanes)
         self._items: List[Tuple[PubKey, bytes, bytes, int]] = []
         self.cache_stats: Dict = {"lanes": 0, "hits": 0, "dedup": 0,
                                   "dispatched": 0}
@@ -884,11 +905,12 @@ class TPUBatchVerifier(BatchVerifier):
             else:
                 from tmtpu.tpu import verify as tv
 
+                pin = self.min_lanes
                 _dispatch(ED25519, ed_idx, _mesh_first(
-                    ED25519, len(ed_idx),
+                    ED25519, max(len(ed_idx), pin),
                     lambda: _mesh.batch_verify_mesh(
-                        ED25519, ed_pks, ed_msgs, ed_sigs),
-                    lambda: tv.batch_verify(ed_pks, ed_msgs, ed_sigs),
+                        ED25519, ed_pks, ed_msgs, ed_sigs, pin),
+                    lambda: tv.batch_verify(ed_pks, ed_msgs, ed_sigs, pin),
                 ), _apply_mask(ed_idx))
         from tmtpu.libs import timeline as _tl
 
@@ -992,15 +1014,21 @@ class SidecarBatchVerifier(BatchVerifier):
         return mask, tallied
 
 
-def new_batch_verifier(backend: Optional[str] = None) -> BatchVerifier:
+def _resolve_backend(backend: Optional[str]) -> str:
     b = backend or _default_backend
     if b == "auto":
         b = "tpu" if _tpu_available() else "cpu"
+    return b
+
+
+def new_batch_verifier(backend: Optional[str] = None,
+                       min_lanes: int = 0) -> BatchVerifier:
+    b = _resolve_backend(backend)
     if b == "sidecar":
-        return SidecarBatchVerifier()
+        return SidecarBatchVerifier(min_lanes)
     if b == "tpu":
-        return TPUBatchVerifier()
-    return CPUBatchVerifier()
+        return TPUBatchVerifier(min_lanes)
+    return CPUBatchVerifier(min_lanes)
 
 
 def batch_verify_items(items, backend: Optional[str] = None):

@@ -417,6 +417,26 @@ consensus_async_apply_overlap = DEFAULT.histogram(
              0.5, 1.0, 2.5))
 
 
+# --- the blocksync metric set (tmtpu/blocksync/) ----------------------------
+#
+# A run is the contiguous stretch of fetched blocks whose commits ride
+# one fused verify dispatch (common.verify_block_run: v0, v1, v2); in v0
+# its length is what the validator set's size leaves of common.RUN_LANES,
+# and v0 alone counts the blocks it applies and refuses.
+
+blocksync_blocks_applied = DEFAULT.counter(
+    "blocksync", "blocks_applied_total",
+    "Blocks verified, saved and applied by the fast-sync loop")
+blocksync_run_blocks = DEFAULT.histogram(
+    "blocksync", "run_blocks",
+    "Blocks whose commits one fused verify dispatch carried",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512))
+blocksync_bad_blocks = DEFAULT.counter(
+    "blocksync", "bad_blocks_total",
+    "Fetched blocks refused (commit verification or validate_block): the "
+    "block and its successor are re-requested and their servers punished")
+
+
 # --- the tx lifecycle latency metric set (libs/txlat.py) --------------------
 #
 # Written by the per-tx stamp ring: each checkpoint stamp observes the

@@ -14,7 +14,7 @@ from typing import List, Optional, Tuple
 
 from tmtpu.abci import types as abci
 from tmtpu.crypto.encoding import pubkey_from_proto
-from tmtpu.libs import faultinject
+from tmtpu.libs import faultinject, trace
 from tmtpu.state.state import State, median_time
 from tmtpu.state.store import ABCIResponses, StateStore
 from tmtpu.state.validation import validate_block
@@ -85,14 +85,16 @@ class BlockExecutor:
         every piece of block evidence is verified through the pool
         (execution.go:122 evpool.CheckEvidence). Without this a byzantine
         proposer could embed fabricated evidence framing honest validators."""
-        validate_block(state, block, verify_backend=self.verify_backend)
-        if self.evidence_pool is not None and block.evidence:
-            from tmtpu.evidence.pool import EvidenceError
+        with trace.span("state.validate_block", height=block.header.height):
+            validate_block(state, block, verify_backend=self.verify_backend)
+            if self.evidence_pool is not None and block.evidence:
+                from tmtpu.evidence.pool import EvidenceError
 
-            try:
-                self.evidence_pool.check_evidence(block.evidence)
-            except EvidenceError as e:
-                raise BlockExecutionError(f"invalid evidence: {e}") from e
+                try:
+                    self.evidence_pool.check_evidence(block.evidence)
+                except EvidenceError as e:
+                    raise BlockExecutionError(
+                        f"invalid evidence: {e}") from e
 
     def apply_block(self, state: State, block_id: BlockID, block: Block
                     ) -> Tuple[State, int]:
@@ -105,14 +107,15 @@ class BlockExecutor:
         self.validate_block(state, block)
         # ABCI-handoff stamp on the height's root trace: the instant the
         # committed block crosses into the application
-        from tmtpu.libs import trace as _trace
-
-        _trace.mark_height(block.header.height, "abci.handoff",
-                           txs=len(block.txs))
-        abci_responses = self._exec_block_on_proxy_app(state, block)
+        trace.mark_height(block.header.height, "abci.handoff",
+                          txs=len(block.txs))
+        with trace.span("state.exec_app", txs=len(block.txs)):
+            abci_responses = self._exec_block_on_proxy_app(state, block)
         # execution.go:149 — after exec, before saving
         fail.fail_point("exec.post_exec")
-        self.store.save_abci_responses(block.header.height, abci_responses)
+        with trace.span("state.save_responses"):
+            self.store.save_abci_responses(block.header.height,
+                                           abci_responses)
 
         # validate validator updates per consensus params
         val_updates = []
@@ -132,14 +135,16 @@ class BlockExecutor:
 
         fail.fail_point("exec.pre_app_commit")  # execution.go:180
         # Commit: lock mempool, flush, app Commit, update mempool
-        app_hash, retain_height = self._commit(new_state, block,
-                                               abci_responses.deliver_txs)
+        with trace.span("state.commit_app"):
+            app_hash, retain_height = self._commit(
+                new_state, block, abci_responses.deliver_txs)
         # execution.go:196 — app committed, state unsaved
         fail.fail_point("exec.post_app_commit")
         if self.evidence_pool:
             self.evidence_pool.update(new_state, block.evidence)
         new_state.app_hash = app_hash
-        self.store.save(new_state)
+        with trace.span("state.save"):
+            self.store.save(new_state)
 
         if self.event_bus:
             self._fire_events(block, block_id, abci_responses, val_updates)
@@ -151,8 +156,8 @@ class BlockExecutor:
         # apply checkpoint (async or serial executor alike): commit→apply
         # is exactly the span the async_exec overlap hides
         txlat.stamp_height(block.header.height, "apply")
-        _trace.mark_height(block.header.height, "height.apply",
-                           txs=len(block.txs))
+        trace.mark_height(block.header.height, "height.apply",
+                          txs=len(block.txs))
         return new_state, retain_height
 
     def apply_block_async(self, state: State, block_id: BlockID,

@@ -361,10 +361,12 @@ def batch_verify_tally_mesh(pks, msgs, sigs, powers
     return mask, tallied
 
 
-def batch_verify_mesh(curve: str, pks, msgs, sigs) -> np.ndarray:
+def batch_verify_mesh(curve: str, pks, msgs, sigs,
+                      min_lanes: int = 0) -> np.ndarray:
     """Mask-only lane-sharded batch verify for any supported curve —
     bit-exact twin of the single-device batch_verify/batch_verify_sr/
-    batch_verify_k1. Raises on failure."""
+    batch_verify_k1. Raises on failure. ``min_lanes`` as in
+    ``tv.batch_verify``: the flush pads as if it held that many."""
     import jax
     import jax.numpy as jnp
 
@@ -400,7 +402,7 @@ def batch_verify_mesh(curve: str, pks, msgs, sigs) -> np.ndarray:
             build = sh.sharded_verify_k1
         else:
             raise ValueError(f"unsupported mesh curve {curve!r}")
-        padded = padded_lanes(b, n)
+        padded = padded_lanes(max(b, min_lanes), n)
         # every mask-only mesh route is the lane-sharded XLA graph
         impl = "mesh-xla"
         sp.set(padded=padded, impl=impl)

@@ -388,12 +388,17 @@ def backend_label() -> str:
     return jax.devices()[0].platform
 
 
-def batch_verify(pks, msgs, sigs) -> np.ndarray:
+def batch_verify(pks, msgs, sigs, min_lanes: int = 0) -> np.ndarray:
     """ed25519 batch verification: returns bool [B] per-signature validity.
 
     Semantics are exactly per-signature Go-stdlib verify (no batch equation
     shortcuts — each lane independently checks encode([s]B+[h](-A)) == R, so
     a mixed batch yields the exact per-lane mask with no re-run).
+
+    ``min_lanes`` pads the flush as if it held at least that many lanes:
+    a caller whose flushes vary in length but must all meet one compiled
+    shape (a blocksync run, crypto/batch.py ``warm_pinned``) gives the
+    longest it makes.
     """
     B = len(sigs)
     if B == 0:
@@ -406,12 +411,11 @@ def batch_verify(pks, msgs, sigs) -> np.ndarray:
         pbr = pallas_breaker("ed25519")
         use_kernel = use_pallas_kernel() and pbr.allow()
         impl = "pallas" if use_kernel else "xla"
+        padded = _pad_to_bucket(max(B, min_lanes))
         if use_kernel:
             from tmtpu.tpu import kernel as tk
 
-            padded = max(tk.DEFAULT_TILE, _pad_to_bucket(B))
-        else:
-            padded = _pad_to_bucket(B)
+            padded = max(tk.DEFAULT_TILE, padded)
         sp.set(impl=impl, padded=padded)
         with trace.span("ed25519.pad", padded=padded):
             packed = pad_packed(packed, padded)

@@ -171,14 +171,16 @@ def verify_commit_light_trusting(vals: ValidatorSet, chain_id: str,
         raise ErrNotEnoughVotingPowerSigned(tallied, needed)
 
 
-def verify_commits_light_batch(entries, backend=None):
+def verify_commits_light_batch(entries, backend=None, min_lanes: int = 0):
     """Verify MANY blocks' commits in one batch dispatch — the fast-sync
     fused path (new vs the reference, which runs VerifyCommitLight per block
     in blockchain/v0/reactor.go:366). ``entries`` is a list of
     (vals, chain_id, block_id, height, commit); all for-block signatures
     across all entries ride a single BatchVerifier (one TPU dispatch for a
     whole run of fetched blocks), then per-entry +2/3 thresholds are checked
-    against the mask segments.
+    against the mask segments. ``min_lanes`` pins the dispatch's shape
+    (crypto/batch.py ``BatchVerifier``): the caller gives the lanes of the
+    longest run it makes, and a shorter one pads to the same shape.
 
     Returns a list the same length as ``entries``: None for a verified
     commit, or the VerificationError for that entry (so fast sync can apply
@@ -186,25 +188,26 @@ def verify_commits_light_batch(entries, backend=None):
     """
     with trace.span("commit_verify.verify_commits_light_batch",
                     commits=len(entries)):
-        bv = crypto_batch.new_batch_verifier(backend)
+        bv = crypto_batch.new_batch_verifier(backend, min_lanes=min_lanes)
         segments = []  # (start, count, tallied, needed, pre_err)
-        for vals, chain_id, block_id, height, commit in entries:
-            start = bv.count()
-            try:
-                _check_commit_basics(vals, commit, height, block_id)
-            except VerificationError as e:
-                segments.append((start, 0, 0, 0, e))
-                continue
-            tallied = 0
-            sign_bytes = commit.vote_sign_bytes_for(chain_id)
-            for idx, cs in enumerate(commit.signatures):
-                if not cs.for_block():
+        with trace.span("commit_verify.collect"):
+            for vals, chain_id, block_id, height, commit in entries:
+                start = bv.count()
+                try:
+                    _check_commit_basics(vals, commit, height, block_id)
+                except VerificationError as e:
+                    segments.append((start, 0, 0, 0, e))
                     continue
-                val = vals.validators[idx]
-                bv.add(val.pub_key, sign_bytes(cs), cs.signature)
-                tallied += val.voting_power
-            segments.append((start, bv.count() - start, tallied,
-                             vals.total_voting_power() * 2 // 3, None))
+                tallied = 0
+                sign_bytes = commit.vote_sign_bytes_for(chain_id)
+                for idx, cs in enumerate(commit.signatures):
+                    if not cs.for_block():
+                        continue
+                    val = vals.validators[idx]
+                    bv.add(val.pub_key, sign_bytes(cs), cs.signature)
+                    tallied += val.voting_power
+                segments.append((start, bv.count() - start, tallied,
+                                 vals.total_voting_power() * 2 // 3, None))
         _, mask = bv.verify()
     out = []
     for start, count, tallied, needed, pre_err in segments:
