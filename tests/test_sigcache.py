@@ -15,10 +15,16 @@ Covers the correctness corners the cache design leans on:
 - batch-level dedup: N identical in-flight lanes → one verify, N
   results, powers folded exactly once into the tally;
 - the adaptive flush scheduler is inert without device RTT samples and
-  bounded when it has them.
+  bounded when it has them;
+- the bulk forms a resolve uses (``cache_keys``, ``contains_many``,
+  ``add_many``) against a one-key-at-a-time model: same keys, answers,
+  entries in the same LRU order, totals and registry values.
 """
 
+import hashlib
 import random
+import sys
+import threading
 
 import pytest
 
@@ -51,6 +57,38 @@ def test_cache_key_injective_across_field_boundaries():
     # and the sig is part of the identity (equivocation prerequisite)
     assert sigcache.cache_key(ED, b"pk", b"m", b"s1") != \
         sigcache.cache_key(ED, b"pk", b"m", b"s2")
+
+
+def _key_by_definition(type_value, pk_bytes, msg, sig):
+    """The key's definition, spelled out field by field."""
+    h = hashlib.sha256()
+    for part in (type_value.encode(), pk_bytes, msg, sig):
+        h.update(len(part).to_bytes(4, "big"))
+        h.update(part)
+    return h.digest()
+
+
+@pytest.mark.parametrize("msg", [b"", b"m", b"long " * 14000],
+                         ids=["empty", "one-byte", "70kB"])
+def test_bulk_keys_equal_cache_key_on_every_curve(msg):
+    from tmtpu.crypto import secp256k1, sr25519
+
+    pks = [ed.gen_priv_key_from_secret(b"bulk-ed").pub_key(),
+           sr25519.gen_priv_key_from_secret(b"bulk-sr").pub_key(),
+           secp256k1.gen_priv_key().pub_key(),
+           _TwoSigPubKey(b"odd-length-ident", msg, b"a", b"b")]
+    assert {pk.type_value() for pk in pks} == {
+        "ed25519", "sr25519", "secp256k1", "equivtest"}
+    # the curves interleaved, so a header is looked up again after another
+    items = [(pk, msg, b"sig-%d" % i * (i + 1), 7)
+             for i, pk in enumerate(pks * 2)]
+    ks = sigcache.cache_keys(items)
+    assert ks == [sigcache.cache_key(pk.type_value(), pk.bytes(), m, s)
+                  for pk, m, s, _p in items]
+    assert ks == [_key_by_definition(pk.type_value(), pk.bytes(), m, s)
+                  for pk, m, s, _p in items]
+    assert all(len(k) == 32 for k in ks) and len(set(ks)) == len(ks)
+    assert sigcache.cache_keys([]) == []
 
 
 # --- basic cache behavior ----------------------------------------------------
@@ -89,6 +127,165 @@ def test_resize_shrink_evicts_lru():
     assert c.size() <= 8
     # survivors must come from the recently-used tail
     assert all(not c.contains(k) for k in ks[:16])
+
+
+# --- the bulk forms against one key at a time --------------------------------
+
+
+class _OneKeyModel:
+    """The cache as a plain loop: one shard lookup, one counter step and
+    one gauge write a key. What ``contains_many`` / ``add_many`` must
+    equal, entry for entry and count for count."""
+
+    def __init__(self, max_entries, shards):
+        from collections import OrderedDict
+
+        self.mask = shards - 1
+        self.per_shard = max_entries // shards
+        self.shards = [OrderedDict() for _ in range(shards)]
+        self.totals = {"hits": 0, "misses": 0, "inserts": 0, "evictions": 0}
+        self.entries_gauge = None
+
+    def contains(self, key):
+        shard = self.shards[key[0] & self.mask]
+        hit = key in shard
+        if hit:
+            shard.move_to_end(key)
+        self.totals["hits" if hit else "misses"] += 1
+        return hit
+
+    def add(self, key):
+        shard = self.shards[key[0] & self.mask]
+        if key not in shard:
+            self.totals["inserts"] += 1
+        shard[key] = True
+        shard.move_to_end(key)
+        while len(shard) > self.per_shard:
+            shard.popitem(last=False)
+            self.totals["evictions"] += 1
+        self.entries_gauge = sum(len(s) for s in self.shards)
+
+
+def _registry():
+    from tmtpu.libs import metrics as _m
+
+    return {name: getattr(_m, "crypto_sigcache_" + name)
+            .summary_series().get("", 0.0)
+            for name in ("hits", "misses", "inserts", "evictions",
+                         "entries")}
+
+
+def _state(cache):
+    st = cache.stats()
+    return ([list(shard) for shard in cache._shards],
+            {k: st[k] for k in ("hits", "misses", "inserts", "evictions")})
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_bulk_forms_equal_one_key_forms(seed):
+    """A seeded mix of hits, misses, in-call duplicates and inserts past
+    the per-shard cap, as flushes through the bulk forms, key by key
+    through the one-key forms, and through the plain model: the same
+    answers, the same entries in the same LRU order shard by shard, the
+    same stats() and the same five registry values after every flush."""
+    rng = random.Random(seed)
+    universe = [sigcache.cache_key(ED, b"pk%d" % i, b"m", b"s")
+                for i in range(160)]
+    bulk = sigcache.SigCache(max_entries=32, shards=4)
+    single = sigcache.SigCache(max_entries=32, shards=4)
+    model = _OneKeyModel(max_entries=32, shards=4)
+    for _flush in range(60):
+        keys = [rng.choice(universe) for _ in range(rng.randrange(0, 48))]
+        verified = [k for k in keys if rng.random() < 0.7]
+
+        r0 = _registry()
+        hits = bulk.contains_many(keys)
+        bulk.add_many(verified)
+        r1 = _registry()
+        assert hits == [single.contains(k) for k in keys]
+        for k in verified:
+            single.add(k)
+        r2 = _registry()
+        assert hits == [model.contains(k) for k in keys]
+        for k in verified:
+            model.add(k)
+
+        assert _state(bulk) == _state(single)
+        assert _state(bulk) == ([list(s) for s in model.shards],
+                                model.totals)
+        assert bulk.stats() == single.stats()
+        counters = ("hits", "misses", "inserts", "evictions")
+        assert [r1[c] - r0[c] for c in counters] == \
+            [r2[c] - r1[c] for c in counters]
+        if verified:
+            assert r1["entries"] == r2["entries"] == bulk.size() \
+                == model.entries_gauge
+    assert model.totals["evictions"] > 0 and model.totals["hits"] > 0
+    assert sum(r2[c] for c in counters) > 0
+
+
+def test_bulk_forms_on_a_disabled_cache():
+    c = sigcache.SigCache(max_entries=64, shards=2, enabled=False)
+    ks = [sigcache.cache_key(ED, b"pk", b"m", b"s%d" % i) for i in range(5)]
+    r0 = _registry()
+    c.add_many(ks)
+    assert c.contains_many(ks) == [False] * 5
+    assert c.size() == 0 and _registry() == r0
+    st = c.stats()
+    assert st["hits"] == st["misses"] == st["inserts"] == 0
+
+
+class _TrustingVerifier(crypto_batch.BatchVerifier):
+    """Accepts every lane: the resolve's bookkeeping with no crypto."""
+
+    def _verify_pending(self, items, tally):
+        return [True] * len(items), sum(it[3] for it in items)
+
+
+def test_two_threads_resolving_leave_the_serial_totals(monkeypatch):
+    """Two threads resolve interleaved flushes through one small cache:
+    the once-a-call totals lose no update. Lookups, inserts and
+    evictions (every triple is fresh, so they do not depend on the
+    interleaving) equal the serial sums, in stats() and in the registry."""
+    cache = sigcache.SigCache(max_entries=256, shards=4)
+    monkeypatch.setattr(sigcache, "DEFAULT", cache)
+    pk = ed.gen_priv_key_from_secret(b"threads").pub_key()
+    flushes, lanes = 40, 64
+    errors = []
+
+    def resolve_all(tag):
+        try:
+            for f in range(flushes):
+                bv = _TrustingVerifier()
+                for i in range(lanes):
+                    bv.add(pk, b"%s-%d-%d" % (tag, f, i), b"s" * 64, 1)
+                _ok, mask, tallied = bv.verify_tally()
+                assert mask == [True] * lanes and tallied == lanes
+        except Exception as e:  # noqa: BLE001 — reported by the test
+            errors.append(e)
+
+    r0 = _registry()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=resolve_all, args=(tag,))
+                   for tag in (b"a", b"b")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    r1 = _registry()
+    total = 2 * flushes * lanes
+    st = cache.stats()
+    assert st["hits"] + st["misses"] == total and st["hits"] == 0
+    assert st["inserts"] == total
+    assert st["evictions"] == total - cache.size() > 0
+    assert r1["hits"] + r1["misses"] - r0["hits"] - r0["misses"] == total
+    assert r1["inserts"] - r0["inserts"] == total
+    assert r1["evictions"] - r0["evictions"] == st["evictions"]
 
 
 # --- equivocation ------------------------------------------------------------
@@ -206,6 +403,48 @@ def test_mixed_hits_misses_dups_and_invalid():
     bv2.add(pk3, m3, bad)
     all_ok, mask = bv2.verify()
     assert not all_ok and mask == [False]
+
+
+def test_dup_groups_of_unequal_powers_and_an_invalid_group():
+    """Two duplicate groups in one flush, one valid with unequal powers
+    and one invalid: the backend sees one lane a group carrying the
+    group's folded power, every member gets its group's verdict, only
+    the valid group's power is tallied and only its triple is cached."""
+    pk1, m1, s1 = _ed(40)
+    pk2, m2, s2 = _ed(41)
+    pk3, m3, s3 = _ed(42)
+    pk4, m4, s4 = _ed(43)
+    bad = bytes([s3[0] ^ 0xFF]) + s3[1:]
+    assert crypto_batch.verify_one(pk4, m4, s4)
+    seen = []
+
+    class Recording(crypto_batch.CPUBatchVerifier):
+        def _verify_pending(self, items, tally):
+            seen.extend((pk.bytes(), power) for pk, _m, _s, power in items)
+            return super()._verify_pending(items, tally)
+
+    bv = Recording()
+    bv.add(pk2, m2, s2, power=2)     # group A, first seen
+    bv.add(pk3, m3, bad, power=16)   # group B (invalid), first seen
+    bv.add(pk1, m1, s1, power=1)     # alone
+    bv.add(pk2, m2, s2, power=5)     # group A
+    bv.add(pk4, m4, s4, power=64)    # hit
+    bv.add(pk3, m3, bad, power=32)   # group B
+    bv.add(pk2, m2, s2, power=11)    # group A
+    st0 = sigcache.stats()
+    all_ok, mask, tallied = bv.verify_tally()
+    st1 = sigcache.stats()
+    assert not all_ok
+    assert mask == [True, False, True, True, True, False, True]
+    assert tallied == (2 + 5 + 11) + 1 + 64
+    assert seen == [(pk2.bytes(), 18), (pk3.bytes(), 48), (pk1.bytes(), 1)]
+    assert bv.cache_stats == {"lanes": 7, "hits": 1, "dedup": 3,
+                              "dispatched": 3}
+    assert st1["hits"] - st0["hits"] == 1
+    assert st1["misses"] - st0["misses"] == 6
+    assert st1["inserts"] - st0["inserts"] == 2
+    assert sigcache.DEFAULT.check(ED, pk2.bytes(), m2, s2)
+    assert not sigcache.DEFAULT.check(ED, pk3.bytes(), m3, bad)
 
 
 def test_verify_one_caches_and_rejects():

@@ -81,6 +81,32 @@ def test_profiler_trace_holds_the_stage_spans_of_a_verify_commit():
     assert set(CALLER_LEAVES) <= ring
 
 
+def test_a_wide_resolve_opens_each_sigcache_stage_once():
+    """A 512-lane resolve (hits, misses and a duplicate among them) opens
+    ``batch.keys``, ``batch.lookup``, ``batch.fold`` and ``batch.insert``
+    exactly once each: a span a stage, never a span a lane."""
+    from tmtpu.crypto import batch as crypto_batch
+
+    lanes = [(ed.PubKeyEd25519(pk), msg, sig, power)
+             for pk, msg, sig, power in _request_lanes(511, b"wide")]
+    warm = crypto_batch.CPUBatchVerifier()
+    for lane in lanes[:100]:
+        warm.add(*lane)
+    assert warm.verify()[0]
+    bv = crypto_batch.CPUBatchVerifier()
+    for lane in lanes + lanes[-1:]:
+        bv.add(*lane)
+    trace.drain()
+    all_ok, mask, tallied = bv.verify_tally()
+    assert all_ok and mask == [True] * 512 and tallied == 512
+    assert bv.cache_stats == {"lanes": 512, "hits": 100, "dedup": 1,
+                              "dispatched": 411}
+    opened = [sp.name for sp in trace.snapshot()
+              if sp.name.startswith("batch.")]
+    assert sorted(opened) == ["batch.fold", "batch.insert", "batch.keys",
+                              "batch.lookup", "batch.resolve"]
+
+
 # -- (b) no session, no annotation; no jax, no import ------------------------
 
 

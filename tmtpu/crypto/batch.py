@@ -579,11 +579,12 @@ class BatchVerifier(keys.BatchVerifier):
         return out
 
     def _resolve_stages(self, tally: bool) -> Tuple[bool, List[bool], int]:
-        """The resolve, one span a stage (never one a lane): ``batch.keys``
-        and ``batch.lookup`` are the sigcache key and the contains + dedup
-        grouping as two passes over the lanes, ``batch.fold`` the miss
-        list with folded powers, then the backend hook (its own spans),
-        ``batch.insert`` the cache inserts and the members' mask."""
+        """The resolve, one span a stage (never one a lane) and one call
+        to the sigcache a stage (never one a lane): ``batch.keys`` the
+        flush's keys, ``batch.lookup`` the hits and the dedup grouping of
+        the misses, ``batch.fold`` the miss list with folded powers, then
+        the backend hook (its own spans), ``batch.insert`` the valid
+        lanes' inserts and the mask."""
         items = self._items
         n = len(items)
         cache = sigcache.DEFAULT
@@ -594,49 +595,47 @@ class BatchVerifier(keys.BatchVerifier):
             self.cache_stats = {"lanes": n, "hits": 0, "dedup": 0,
                                 "dispatched": n}
             return all(mask), mask, tallied
-        mask = [False] * n
-        tallied = 0
-        hits = 0
-        dedup = 0
         with trace.span("batch.keys"):
-            ks = [sigcache.cache_key(pk.type_value(), pk.bytes(), msg, sig)
-                  for pk, msg, sig, _p in items]
-        group_of: Dict[bytes, int] = {}
+            ks = sigcache.cache_keys(items)
         pending: List[int] = []       # representative index per unique miss
-        members: List[List[int]] = []  # all indices sharing that triple
+        # every index sharing a triple, by its position in ``pending`` —
+        # only for a triple the flush holds more than once
+        members: Dict[int, List[int]] = {}
         with trace.span("batch.lookup"):
+            mask = cache.contains_many(ks)
+            hits = mask.count(True)
+            tallied = sum(items[i][3] for i, hit in enumerate(mask)
+                          if hit) if hits else 0
+            group_of: Dict[bytes, int] = {}
             for i, k in enumerate(ks):
-                if cache.contains(k):
-                    mask[i] = True
-                    tallied += items[i][3]
-                    hits += 1
+                if mask[i]:
                     continue
                 pos = group_of.get(k)
                 if pos is None:
                     group_of[k] = len(pending)
                     pending.append(i)
-                    members.append([i])
                 else:
-                    members[pos].append(i)
-                    dedup += 1
+                    members.setdefault(pos, [pending[pos]]).append(i)
+        dedup = n - hits - len(pending)
         if pending:
             with trace.span("batch.fold"):
-                sub_items = []
-                for pos, i in enumerate(pending):
-                    pk, msg, sig, _p = items[i]
+                sub_items = [items[i] for i in pending]
+                for pos, group in members.items():
+                    pk, msg, sig, _p = sub_items[pos]
                     # fold dup-group powers into the unique lane so the
                     # fused device tally counts every member exactly once
-                    sub_items.append((pk, msg, sig,
-                                      sum(items[j][3]
-                                          for j in members[pos])))
+                    sub_items[pos] = (pk, msg, sig,
+                                      sum(items[j][3] for j in group))
             sub_mask, sub_tallied = self._verify_pending(sub_items, tally)
             tallied += sub_tallied
             with trace.span("batch.insert"):
-                for pos, ok in enumerate(sub_mask):
-                    if ok:
-                        cache.add(ks[pending[pos]])
-                    for j in members[pos]:
-                        mask[j] = bool(ok)
+                cache.add_many([ks[i] for i, ok in zip(pending, sub_mask)
+                                if ok])
+                for i, ok in zip(pending, sub_mask):
+                    mask[i] = bool(ok)
+                for pos, group in members.items():
+                    for j in group:
+                        mask[j] = mask[pending[pos]]
         if dedup:
             from tmtpu.libs import metrics as _m
 

@@ -13,8 +13,9 @@ lane that never exists is the cheapest lane there is.
 
 Design:
 
-- Entries are keyed by ``sha256(type ‖ len(pk) ‖ pk ‖ len(msg) ‖ msg ‖
-  len(sig) ‖ sig)`` — length-prefixed so no two distinct triples can
+- Entries are keyed by ``sha256(len(type) ‖ type ‖ len(pk) ‖ pk ‖
+  len(msg) ‖ msg ‖ len(sig) ‖ sig)``, 4-byte big-endian lengths —
+  length-prefixed so no two distinct triples can
   collide by concatenation ambiguity, and curve-typed so identical key
   bytes on two curves stay distinct entries. The SAME ``(pubkey, msg)``
   under two DIFFERENT signatures occupies two distinct entries (the
@@ -35,8 +36,18 @@ Design:
   and ``configure()`` (node wiring from ``[crypto] sigcache_*`` knobs;
   shrinking capacity evicts immediately).
 
+- A flush at a time: a batch resolve (crypto/batch.py) asks for a whole
+  flush's keys (``cache_keys``), hits (``contains_many``) and inserts
+  (``add_many``) in one call each. Per key the work is what the one-key
+  forms do — they ARE the bulk forms with one key — and it runs under
+  the key's shard lock, a shard's keys in the order given, so entries,
+  recency and evictions come out the same, shard by shard, as a
+  sequence of ``contains`` / ``add``.
+
 Every hit/miss/insert/evict lands in the
-``tendermint_crypto_sigcache_*`` metric set (libs/metrics.py) and batch
+``tendermint_crypto_sigcache_*`` metric set (libs/metrics.py) by its
+exact count, added once a call (so once a flush, not once a lane); the
+``entries`` gauge is set once, after a call's last insert. Batch
 verifies with cache activity emit ``crypto.sigcache`` timeline events
 (docs/OBSERVABILITY.md runbook).
 """
@@ -45,8 +56,8 @@ from __future__ import annotations
 
 import hashlib
 import threading
-from collections import OrderedDict
-from typing import Dict, Optional
+from collections import OrderedDict, defaultdict
+from typing import Dict, Iterable, List, Optional, Sequence
 
 DEFAULT_MAX_ENTRIES = 131072
 DEFAULT_SHARDS = 16
@@ -57,12 +68,48 @@ def cache_key(type_value: str, pk_bytes: bytes, msg: bytes,
     """The 32-byte cache key for one (curve, pubkey, msg, sig) triple.
     Length-prefixed fields make the encoding injective; the curve name
     keeps equal byte-strings on different curves apart."""
-    h = hashlib.sha256()
     t = type_value.encode()
-    for part in (t, pk_bytes, msg, sig):
-        h.update(len(part).to_bytes(4, "big"))
-        h.update(part)
-    return h.digest()
+    return hashlib.sha256(b"".join(
+        (_len4(t), t, _len4(pk_bytes), pk_bytes, _len4(msg), msg,
+         _len4(sig), sig))).digest()
+
+
+def _len4(part: bytes) -> bytes:
+    return len(part).to_bytes(4, "big")
+
+
+class _Len4Table(dict):
+    """length -> its 4-byte big-endian prefix, filled as lengths are
+    met. Made anew for each ``cache_keys`` call, so it never outgrows a
+    flush's distinct field lengths."""
+
+    def __missing__(self, n: int) -> bytes:
+        prefix = self[n] = n.to_bytes(4, "big")
+        return prefix
+
+
+def cache_keys(items: Iterable[Sequence]) -> List[bytes]:
+    """``cache_key`` for every ``(pub_key, msg, sig, ...)`` item of a
+    flush, byte for byte: one hash call a lane, the curve's header
+    (``len‖type``) built once a curve and each length prefix once a
+    length."""
+    sha256 = hashlib.sha256
+    join = b"".join
+    headers: Dict[str, bytes] = {}
+    len4 = _Len4Table()
+    out = []
+    for item in items:
+        pk, msg, sig = item[0], item[1], item[2]
+        curve = pk.type_value()
+        header = headers.get(curve)
+        if header is None:
+            t = curve.encode()
+            header = headers[curve] = _len4(t) + t
+        pkb = pk.bytes()
+        out.append(sha256(join(
+            (header, len4[len(pkb)], pkb, len4[len(msg)], msg,
+             len4[len(sig)], sig))).digest())
+    return out
 
 
 class SigCache:
@@ -91,48 +138,84 @@ class SigCache:
 
     # -- core ---------------------------------------------------------------
 
-    def _shard(self, key: bytes):
-        i = key[0] & self._shard_mask
-        return self._shards[i], self._locks[i]
+    def _by_shard(self, keys: Sequence[bytes]):
+        """``(shard, its lock, positions in keys)`` for every shard that
+        ``keys`` touch, a shard's positions in the order given: what one
+        key after another would have met there."""
+        mask = self._shard_mask
+        positions: Dict[int, List[int]] = defaultdict(list)
+        for i, key in enumerate(keys):
+            positions[key[0] & mask].append(i)
+        return [(self._shards[s], self._locks[s], at)
+                for s, at in positions.items()]
 
-    def contains(self, key: bytes) -> bool:
-        """True iff ``key`` was inserted as verified. Hits refresh LRU
-        recency. Counts a hit/miss in both stats and metrics."""
-        if not self._enabled:
-            return False
-        shard, lock = self._shard(key)
-        with lock:
-            hit = key in shard
-            if hit:
-                shard.move_to_end(key)
-        self._note(hit)
-        return hit
-
-    def add(self, key: bytes) -> None:
-        """Record one VERIFIED triple. Evicts LRU entries past the
-        per-shard cap; never blocks other shards."""
-        if not self._enabled:
-            return
-        evicted = 0
-        shard, lock = self._shard(key)
-        with lock:
-            already = key in shard
-            shard[key] = True
-            shard.move_to_end(key)
-            while len(shard) > self._per_shard:
-                shard.popitem(last=False)
-                evicted += 1
+    def contains_many(self, keys: Sequence[bytes]) -> List[bool]:
+        """Per key, True iff it was inserted as verified; a hit refreshes
+        LRU recency. Each shard's lock is taken once for all of its keys.
+        Hits and misses go to stats and metrics once, by their counts."""
+        hits = [False] * len(keys)
+        if not self._enabled or not keys:
+            return hits
+        for shard, lock, positions in self._by_shard(keys):
+            with lock:
+                for i in positions:
+                    key = keys[i]
+                    if key in shard:
+                        shard.move_to_end(key)
+                        hits[i] = True
+        n_hits = hits.count(True)
+        n_misses = len(keys) - n_hits
         from tmtpu.libs import metrics as _m
 
         with self._stats_lock:
-            if not already:
-                self._inserts += 1
-            self._evictions += evicted
-        if not already:
-            _m.crypto_sigcache_inserts.inc()
-        if evicted:
-            _m.crypto_sigcache_evictions.inc(evicted)
+            self._hits += n_hits
+            self._misses += n_misses
+        if n_hits:
+            _m.crypto_sigcache_hits.inc(n_hits)
+        if n_misses:
+            _m.crypto_sigcache_misses.inc(n_misses)
+        return hits
+
+    def add_many(self, keys: Sequence[bytes]) -> None:
+        """Record VERIFIED triples. Per key: insert (or refresh), then
+        evict LRU entries past the per-shard cap; never blocks other
+        shards. Inserts and evictions are counted exactly and added
+        once, the entries gauge set once after the last insert."""
+        if not self._enabled or not keys:
+            return
+        inserts = 0
+        evictions = 0
+        cap = self._per_shard
+        for shard, lock, positions in self._by_shard(keys):
+            with lock:
+                for i in positions:
+                    key = keys[i]
+                    if key in shard:
+                        shard.move_to_end(key)
+                        continue
+                    shard[key] = True
+                    inserts += 1
+                    while len(shard) > cap:
+                        shard.popitem(last=False)
+                        evictions += 1
+        from tmtpu.libs import metrics as _m
+
+        with self._stats_lock:
+            self._inserts += inserts
+            self._evictions += evictions
+        if inserts:
+            _m.crypto_sigcache_inserts.inc(inserts)
+        if evictions:
+            _m.crypto_sigcache_evictions.inc(evictions)
         _m.crypto_sigcache_entries.set(self.size())
+
+    def contains(self, key: bytes) -> bool:
+        """``contains_many`` of one key."""
+        return self.contains_many((key,))[0]
+
+    def add(self, key: bytes) -> None:
+        """``add_many`` of one key."""
+        self.add_many((key,))
 
     def check(self, type_value: str, pk_bytes: bytes, msg: bytes,
               sig: bytes) -> bool:
@@ -143,19 +226,6 @@ class SigCache:
                sig: bytes) -> None:
         """Convenience: key + add in one call."""
         self.add(cache_key(type_value, pk_bytes, msg, sig))
-
-    def _note(self, hit: bool) -> None:
-        from tmtpu.libs import metrics as _m
-
-        with self._stats_lock:
-            if hit:
-                self._hits += 1
-            else:
-                self._misses += 1
-        if hit:
-            _m.crypto_sigcache_hits.inc()
-        else:
-            _m.crypto_sigcache_misses.inc()
 
     # -- control ------------------------------------------------------------
 
