@@ -89,6 +89,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from tmtpu.tpu import dispatch
     from tmtpu.tpu import kernel as tk
     from tmtpu.tpu import sharding as sh
     from tmtpu.tpu import verify as tv
@@ -119,7 +120,7 @@ def main() -> int:
         for j in range(k):
             packed, host_ok = tv.prepare_batch_packed(*sets[(i + j) % 4])
             assert host_ok.all()
-            planes.append(tv.pad_packed(packed, pad1))
+            planes.append(dispatch.pad_packed(packed, pad1))
         return planes[0] if k == 1 else np.concatenate(planes, axis=1)
 
     def check(out, k: int):

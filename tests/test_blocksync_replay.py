@@ -261,15 +261,15 @@ class _Set:
 
 @pytest.mark.parametrize("n_val", [10, 175, 2000, 3072, 3073, 10000])
 def test_run_fits_the_shape_it_warms(n_val):
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     blocks, lanes = common.run_shape(_Set(n_val))
     assert blocks >= 1 and blocks * n_val <= lanes
-    shape = tv._pad_to_bucket(lanes)
+    shape = dispatch._pad_to_bucket(lanes)
     # whatever a run holds, one lane or every slot of every block, it pads
     # to the shape the warm-up's ``lanes`` copies compile
     for held in (1, n_val - n_val // 3, blocks * n_val):
-        assert tv._pad_to_bucket(max(held, lanes)) == shape
+        assert dispatch._pad_to_bucket(max(held, lanes)) == shape
     if n_val <= common.RUN_LANES:
         assert lanes == common.RUN_LANES and \
             (blocks + 1) * n_val > common.RUN_LANES
@@ -279,17 +279,20 @@ def test_a_one_block_run_pads_to_the_run_shape(node_of, monkeypatch):
     """The device backend, its compiled step replaced by one that answers
     at once (nothing of 6,144 lanes compiles in a test): the warm-up and
     every run, the one-block run first, meet the same padded width."""
+    import dataclasses
+
     import jax.numpy as jnp
 
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     widths = []
 
     def step(packed, _table):
         widths.append(int(packed.shape[1]))
         return jnp.ones(packed.shape[1], dtype=bool)
-    monkeypatch.setattr(tv, "_verify_packed_jit", step)
-    monkeypatch.setattr(tv, "use_pallas_kernel", lambda: False)
+    monkeypatch.setitem(dispatch.CURVES, "ed25519", dataclasses.replace(
+        dispatch.CURVES["ed25519"], xla=step))
+    monkeypatch.setattr(dispatch, "use_pallas_kernel", lambda: False)
     # one device, as the cell has: the tests' eight virtual ones would
     # route a 6,144-lane flush to the mesh
     monkeypatch.setenv("TMTPU_MESH_DEVICES", "1")

@@ -258,7 +258,7 @@ def test_half_open_not_advanced_by_sigcache_hits(monkeypatch):
     from tmtpu.crypto import batch as crypto_batch
     from tmtpu.crypto import ed25519 as ed
     from tmtpu.crypto import sigcache
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     br = bk.get(crypto_batch.BREAKER_NAME)
     clock = FakeClock()
@@ -277,11 +277,12 @@ def test_half_open_not_advanced_by_sigcache_hits(monkeypatch):
 
     device_calls = []
 
-    def fake_batch_verify(pks, msgs, sigs, min_lanes=0):
+    def fake_device_verify(curve, pks, msgs, sigs, powers=None,
+                           min_lanes=0):
         device_calls.append(len(pks))
-        return [True] * len(pks)
+        return [True] * len(pks), None
 
-    monkeypatch.setattr(tv, "batch_verify", fake_batch_verify)
+    monkeypatch.setattr(dispatch, "device_verify", fake_device_verify)
 
     def flush(m, s):
         bv = crypto_batch.TPUBatchVerifier()

@@ -9,10 +9,10 @@ with the whole sequence recorded in the breaker metric set and the
 per-height timeline journal. The hung-device test proves the per-batch
 deadline turns "dispatch never returns" into a CPU-verified result.
 
-The ed25519 device fn is monkeypatched with a fake that still fires the
+The device function is monkeypatched with a fake that still fires the
 real ``tpu.ed25519.batch`` site — the sr25519/secp256k1 scenarios go
-through the REAL ``batch_verify_sr`` / ``batch_verify_k1`` entry points
-(their sites fire before any jax work, so no XLA compile in tier-1).
+through the REAL ``dispatch.device_verify`` (a row's site fires before
+any jax work, so no XLA compile in tier-1).
 """
 
 import hashlib
@@ -27,7 +27,7 @@ from tmtpu.libs import breaker as _bk
 from tmtpu.libs import faultinject
 from tmtpu.libs import metrics as _m
 from tmtpu.libs import timeline as _tl
-from tmtpu.tpu import verify as tv
+from tmtpu.tpu import dispatch
 
 pytestmark = pytest.mark.chaos
 
@@ -109,15 +109,16 @@ def test_breaker_opens_falls_back_half_opens_and_closes(monkeypatch,
     _tl.DEFAULT.clear()
     _tl.record(7, "consensus.enter_new_round")
 
-    site = tv._FAULT_ED_BATCH
+    site = dispatch.CURVES["ed25519"].fault
     device_calls = []
 
-    def fake_batch_verify(pks, msgs, sigs, min_lanes=0):
+    def fake_device_verify(curve, pks, msgs, sigs, powers=None,
+                           min_lanes=0):
         device_calls.append(len(pks))
         faultinject.fire(site)
-        return [True] * len(pks)
+        return [True] * len(pks), None
 
-    monkeypatch.setattr(tv, "batch_verify", fake_batch_verify)
+    monkeypatch.setattr(dispatch, "device_verify", fake_device_verify)
     faultinject.script("tpu.ed25519.batch", faultinject.ERROR, count=2)
     fb0 = _series(_m.crypto_cpu_fallback)
 
@@ -179,11 +180,12 @@ def test_hung_device_returns_cpu_result_within_deadline(monkeypatch,
     monkeypatch.setenv("TMTPU_TPU_BATCH_DEADLINE", "0.2")
     hang = threading.Event()
 
-    def hung_batch_verify(pks, msgs, sigs, min_lanes=0):
+    def hung_device_verify(curve, pks, msgs, sigs, powers=None,
+                           min_lanes=0):
         hang.wait(30.0)
-        return [True] * len(pks)
+        return [True] * len(pks), None
 
-    monkeypatch.setattr(tv, "batch_verify", hung_batch_verify)
+    monkeypatch.setattr(dispatch, "device_verify", hung_device_verify)
     d0 = _series(_m.crypto_batch_deadline_exceeded)
     fb0 = _series(_m.crypto_cpu_fallback)
     t0 = time.monotonic()
@@ -204,7 +206,7 @@ def test_hung_device_returns_cpu_result_within_deadline(monkeypatch,
 def test_sr_and_k1_sites_inject_at_the_real_entry(breaker_env, monkeypatch):
     """No monkeypatched device fns here: scripted errors on the
     ``tpu.sr25519.batch`` / ``tpu.secp256k1.batch`` sites raise inside
-    the REAL batch_verify_sr/batch_verify_k1 (before any jax work), and
+    the REAL dispatch.device_verify (before any jax work), and
     the per-curve fallback re-verifies exactly those lanes."""
     from tmtpu.crypto import sr25519 as sr
 
@@ -230,7 +232,7 @@ def test_sr_and_k1_sites_inject_at_the_real_entry(breaker_env, monkeypatch):
 
 
 def test_k1_site_injects_at_the_real_entry(breaker_env, monkeypatch):
-    """Same scenario over the real ``batch_verify_k1`` entry (the
+    """Same scenario over the table's secp256k1 row (the
     secp256k1 curve module needs the optional `cryptography` package —
     same gate as test_replay.py)."""
     pytest.importorskip("cryptography")
@@ -281,19 +283,19 @@ def test_pallas_breaker_policy():
     """Compile/lowering rejections are deterministic → permanent trip;
     transient faults open after 2 and stay re-probeable (the old
     ``_kernel_broken`` latch never un-latched)."""
-    br = tv.pallas_breaker("chaos-test-curve")
+    br = dispatch.pallas_breaker("chaos-test-curve")
     try:
         br.reset()
-        tv.note_pallas_failure(
+        dispatch.note_pallas_failure(
             br, NotImplementedError("pallas lowering not implemented"))
         assert br.state == _bk.OPEN
         assert br.snapshot()["permanent"]
         assert not br.allow()
 
         br.reset()
-        tv.note_pallas_failure(br, RuntimeError("transient device fault"))
+        dispatch.note_pallas_failure(br, RuntimeError("transient device fault"))
         assert br.state == _bk.CLOSED  # threshold 2
-        tv.note_pallas_failure(br, RuntimeError("transient device fault"))
+        dispatch.note_pallas_failure(br, RuntimeError("transient device fault"))
         assert br.state == _bk.OPEN
         assert not br.snapshot()["permanent"]
     finally:

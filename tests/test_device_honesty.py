@@ -101,8 +101,8 @@ def test_use_pallas_kernel_and_interpret_default_surface_jax_failures(
         monkeypatch):
     import jax
 
+    from tmtpu.tpu import dispatch
     from tmtpu.tpu import kernel as tk
-    from tmtpu.tpu import verify as tv
 
     def boom():
         raise RuntimeError("no backend")
@@ -110,7 +110,7 @@ def test_use_pallas_kernel_and_interpret_default_surface_jax_failures(
     monkeypatch.delenv("TMTPU_TPU_IMPL", raising=False)
     monkeypatch.setattr(jax, "devices", boom)
     with pytest.raises(RuntimeError):
-        tv.use_pallas_kernel()
+        dispatch.use_pallas_kernel()
     with pytest.raises(RuntimeError):
         tk._default_interpret()
 
@@ -335,13 +335,13 @@ def test_sidecar_backend_name_is_the_jax_platform(monkeypatch, tmp_path):
 
 
 def test_warm_sizes_cover_every_production_bucket():
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     for top in (8, 100, 512, 2000):
         sizes = crypto_batch._warm_sizes(top)
         assert sizes[0] == crypto_batch._TPU_MIN_BATCH and sizes[-1] == top
-        warmed = {tv._pad_to_bucket(n) for n in sizes}
-        assert warmed == {tv._pad_to_bucket(n)
+        warmed = {dispatch._pad_to_bucket(n) for n in sizes}
+        assert warmed == {dispatch._pad_to_bucket(n)
                           for n in range(crypto_batch._TPU_MIN_BATCH,
                                          top + 1)}
     assert crypto_batch._warm_sizes(crypto_batch._TPU_MIN_BATCH - 1) == []
@@ -383,7 +383,7 @@ def test_warm_validator_set_reaches_the_whole_set(monkeypatch):
     """A drain can hold all of a round's votes, and verify_commit a whole
     commit: the ladder runs to the set's size (the first chip run
     compiled the 4,096 bucket inside the live 10k round)."""
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     flushed = []
     monkeypatch.setattr(
@@ -397,5 +397,5 @@ def test_warm_validator_set_reaches_the_whole_set(monkeypatch):
 
     crypto_batch.warm_validator_set(FakeSet)
     assert flushed[-1] == 10_000
-    assert {tv._pad_to_bucket(n) for n in flushed} == {
+    assert {dispatch._pad_to_bucket(n) for n in flushed} == {
         64, 128, 256, 512, 1024, 2048, 4096, 6144, 8192, 10240}

@@ -694,8 +694,7 @@ def raw_dispatch(dev):
     return _verify_jit(dev)
 
 def bucketed_dispatch(dev, n):
-    padded = _pad_to_bucket(n)
-    return _verify_jit(pad_packed(dev, padded))
+    return _verify_jit(pad_packed(dev, padded_lanes(n)))
 """})
     keys = _keys(_run(idx, "jax-hygiene"))
     assert "jax-hygiene::bucket-bypass::tmtpu/tpu/k.py::raw_dispatch" \
@@ -704,21 +703,21 @@ def bucketed_dispatch(dev, n):
 
 
 def test_jax_hygiene_unguarded_dispatch_vs_breaker(tmp_path):
-    """batch_verify* outside tmtpu/tpu/ needs breaker discipline; the
+    """device_verify outside tmtpu/tpu/ needs breaker discipline; the
     sync point behind a breaker fallback (pbr.allow() in frame) is the
     sanctioned shape and stays clean."""
     idx = _tree(tmp_path, {"tmtpu/consensus/v.py": """
 def naked(pks, msgs, sigs):
-    return batch_verify(pks, msgs, sigs)
+    return device_verify("ed25519", pks, msgs, sigs)
 
 def guarded(pks, msgs, sigs, pbr):
     if not pbr.allow():
         return [one_verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)]
-    return batch_verify(pks, msgs, sigs)
+    return device_verify("ed25519", pks, msgs, sigs)
 """})
     keys = _keys(_run(idx, "jax-hygiene"))
     assert "jax-hygiene::unguarded-dispatch::tmtpu/consensus/v.py" \
-           "::naked::batch_verify" in keys
+           "::naked::device_verify" in keys
     assert not any("::guarded::" in k for k in keys), keys
 
 

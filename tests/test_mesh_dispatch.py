@@ -26,18 +26,22 @@ from tmtpu.crypto import ed25519_ref as ref
 from tmtpu.crypto import sigcache
 from tmtpu.libs import breaker as bk
 from tmtpu.libs import metrics as _m
+from tmtpu.tpu import dispatch
 from tmtpu.tpu import mesh_dispatch as md
-from tmtpu.tpu import sharding as sh
 
 
 @pytest.fixture
 def mesh4(monkeypatch):
     monkeypatch.setenv("TMTPU_MESH_DEVICES", "4")
     monkeypatch.setenv("TMTPU_SHARD_MIN_LANES", "1")
+    # a SidecarServer started with mesh knobs writes them into the
+    # process-wide overrides: whatever a test set goes back on every exit
+    saved = dict(md._cfg)
     md.reset()
     md.breaker().reset()
     bk.get(crypto_batch.BREAKER_NAME).reset()
     yield
+    md.set_overrides(**saved)
     md.reset()
     md.breaker().reset()
     bk.get(crypto_batch.BREAKER_NAME).reset()
@@ -68,15 +72,17 @@ def test_mesh_tally_bit_exact(mesh4):
     (tier-1 proves oracle == single-device separately; the direct
     graph-vs-graph comparison is in the slow test below)."""
     pks, msgs, sigs, powers = _ed_batch(40, b"mesh-eq", bad={3, 17})
-    mask_m, tally_m = md.batch_verify_tally_mesh(pks, msgs, sigs, powers)
+    mask_m, tally_m = dispatch.device_verify("ed25519", pks, msgs, sigs,
+                                             powers)
     want = np.array([ref.verify(pk, m, s)
                      for pk, m, s in zip(pks, msgs, sigs)], dtype=bool)
     assert np.array_equal(np.asarray(mask_m), want)
     assert not mask_m[3] and not mask_m[17] and mask_m[0]
     assert tally_m == sum(p for i, p in enumerate(powers)
                           if i not in (3, 17))
-    # mask-only entry reuses the same sharded callable (zero powers)
-    mask_v = md.batch_verify_mesh("ed25519", pks, msgs, sigs)
+    # the mask flush reuses the same sharded callable (zero powers)
+    mask_v, none = dispatch.device_verify("ed25519", pks, msgs, sigs)
+    assert none is None and len(md._state["fns"]) == 1
     assert np.array_equal(np.asarray(mask_v), want)
     snap = md.snapshot()
     assert snap["devices"] == 4
@@ -92,7 +98,8 @@ def test_padding_lanes_never_enter_the_tally(mesh4):
     of the psum. 33 lanes pad to 128 on a 4-device mesh: 95 potential
     phantom contributions if the zeroing slips."""
     pks, msgs, sigs, powers = _ed_batch(33, b"mesh-pad")
-    mask, tally = md.batch_verify_tally_mesh(pks, msgs, sigs, powers)
+    mask, tally = dispatch.device_verify("ed25519", pks, msgs, sigs, powers)
+    assert md.dispatch_count() == 1
     assert len(mask) == 33 and bool(np.all(mask))
     assert tally == sum(powers)
 
@@ -141,13 +148,20 @@ def test_fallback_ladder_mesh_to_single_to_serial(mesh4, monkeypatch):
                           dtype=bool)
             return ok, sum(int(p) for p, o in zip(powers, ok) if o)
 
-        monkeypatch.setattr(sh, "batch_verify_tally", single_oracle)
+        mesh_calls = []
+
+        def flush_with(single):
+            """dispatch._flush with the mesh rung blowing up and the
+            single-device rung answered by ``single``."""
+            def fake_flush(row, pks, msgs, sigs, powers, min_lanes, mesh):
+                if mesh is not None:
+                    mesh_calls.append(1)
+                    raise RuntimeError("collective blew up")
+                return single(pks, msgs, sigs, powers)
+            monkeypatch.setattr(dispatch, "_flush", fake_flush)
 
         # rung 1: mesh dispatch raises -> single-device answers, exact
-        def mesh_boom(*a, **kw):
-            raise RuntimeError("collective blew up")
-
-        monkeypatch.setattr(md, "batch_verify_tally_mesh", mesh_boom)
+        flush_with(single_oracle)
         all_ok, mask, tallied, want = flush(b"ladder-1", bad={5})
         assert not all_ok and mask[0] and not mask[5]
         assert tallied == want
@@ -161,7 +175,7 @@ def test_fallback_ladder_mesh_to_single_to_serial(mesh4, monkeypatch):
         def single_boom(*a, **kw):
             raise RuntimeError("device fell over")
 
-        monkeypatch.setattr(sh, "batch_verify_tally", single_boom)
+        flush_with(single_boom)
         all_ok, mask, tallied, want = flush(b"ladder-2", bad={7})
         assert not all_ok and mask[0] and not mask[7]
         assert tallied == want
@@ -172,17 +186,13 @@ def test_fallback_ladder_mesh_to_single_to_serial(mesh4, monkeypatch):
         md.breaker().reset()
         md.breaker().trip_permanent("mesh declared down for rung 3")
         assert md.breaker().state == bk.OPEN
-        calls = []
-        monkeypatch.setattr(md, "batch_verify_tally_mesh",
-                            lambda *a, **kw: calls.append(1))
-        monkeypatch.setattr(
-            sh, "batch_verify_tally",
-            lambda pks, msgs, sigs, powers:
-            (np.ones(len(sigs), dtype=bool), sum(powers)))
+        del mesh_calls[:]
+        flush_with(lambda pks, msgs, sigs, powers:
+                   (np.ones(len(sigs), dtype=bool), sum(powers)))
         tpu_br.reset()
         all_ok, mask, tallied, want = flush(b"ladder-3")
         assert all_ok and tallied == want
-        assert calls == []  # breaker-open: mesh never touched
+        assert mesh_calls == []  # breaker-open: mesh never touched
     finally:
         sigcache.DEFAULT.set_enabled(True)
 
@@ -248,12 +258,17 @@ def test_mesh_exact_vs_single_device_all_curves(mesh4):
 
     from tmtpu.crypto import secp256k1 as k1
     from tmtpu.crypto import sr25519 as sr
-    from tmtpu.tpu import k1_verify as kv
-    from tmtpu.tpu import sr_verify as srv_mod
+
+    def both(curve, pks, msgs, sigs, powers=None):
+        """The same flush lane-sharded over the mesh and on one device."""
+        row = dispatch.CURVES[curve]
+        return (dispatch._flush(row, pks, msgs, sigs, powers, 0,
+                                md.get_mesh()),
+                dispatch._flush(row, pks, msgs, sigs, powers, 0, None))
 
     pks, msgs, sigs, powers = _ed_batch(40, b"mesh-sd", bad={3, 17})
-    mask_m, tally_m = md.batch_verify_tally_mesh(pks, msgs, sigs, powers)
-    mask_s, tally_s = sh.batch_verify_tally(pks, msgs, sigs, powers)
+    (mask_m, tally_m), (mask_s, tally_s) = both("ed25519", pks, msgs, sigs,
+                                                powers)
     assert np.array_equal(np.asarray(mask_m), np.asarray(mask_s))
     assert tally_m == tally_s
 
@@ -265,8 +280,7 @@ def test_mesh_exact_vs_single_device_all_curves(mesh4):
     sr_sigs[3][1] ^= 1
     sr_sigs = [bytes(s) for s in sr_sigs]
     sr_pks = [k.pub_key().bytes() for k in sr_keys]
-    mask = md.batch_verify_mesh("sr25519", sr_pks, sr_msgs, sr_sigs)
-    want = srv_mod.batch_verify_sr(sr_pks, sr_msgs, sr_sigs)
+    (mask, _), (want, _) = both("sr25519", sr_pks, sr_msgs, sr_sigs)
     assert np.array_equal(np.asarray(mask), np.asarray(want))
     assert not mask[3] and mask.sum() == n - 1
 
@@ -281,7 +295,6 @@ def test_mesh_exact_vs_single_device_all_curves(mesh4):
     k1_sigs[6][40] ^= 1
     k1_sigs = [bytes(s) for s in k1_sigs]
     k1_pks = [k.pub_key().bytes() for k in k1_keys]
-    kmask = md.batch_verify_mesh("secp256k1", k1_pks, k1_msgs, k1_sigs)
-    kwant = kv.batch_verify_k1(k1_pks, k1_msgs, k1_sigs)
+    (kmask, _), (kwant, _) = both("secp256k1", k1_pks, k1_msgs, k1_sigs)
     assert np.array_equal(np.asarray(kmask), np.asarray(kwant))
     assert not kmask[6] and kmask.sum() == n - 1

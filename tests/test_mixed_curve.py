@@ -110,7 +110,7 @@ def test_4node_net_mixed_curves_commits(monkeypatch):
 
 
 def _run_mixed_net(monkeypatch):
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     from tests.test_consensus import make_network, stop_all
 
@@ -118,7 +118,7 @@ def _run_mixed_net(monkeypatch):
     monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
     monkeypatch.setattr(crypto_batch, "_tpu_usable", True)
     # one jit shape per curve graph: every burst pads to the 8-lane bucket
-    monkeypatch.setattr(tv, "_pad_to_bucket", lambda n: 8)
+    monkeypatch.setattr(dispatch, "_pad_to_bucket", lambda n: 8)
 
     pvs = [MockPV(),
            MockPV(sr.gen_priv_key_from_secret(b"net-sr")),

@@ -97,6 +97,7 @@ def test_sharded_sr_and_k1_cpu_mesh():
 
     from tmtpu.crypto import secp256k1 as k1
     from tmtpu.crypto import sr25519 as sr
+    from tmtpu.tpu import dispatch
     from tmtpu.tpu import k1_verify as kv
     from tmtpu.tpu import sr_verify as srv
     from tmtpu.tpu import verify as tv
@@ -118,7 +119,7 @@ def test_sharded_sr_and_k1_cpu_mesh():
     step = sh.sharded_verify_sr(mesh)
     mask = np.asarray(jax.block_until_ready(
         step(jnp.asarray(packed), tv.base_table_f32())))
-    want = srv.batch_verify_sr(sr_pks, sr_msgs, sr_sigs)
+    want, _ = dispatch.device_verify("sr25519", sr_pks, sr_msgs, sr_sigs)
     assert np.array_equal(mask, np.asarray(want))
     assert not mask[3] and mask.sum() == lanes - 1
 
@@ -138,6 +139,7 @@ def test_sharded_sr_and_k1_cpu_mesh():
     kstep = sh.sharded_verify_k1(mesh)
     kmask = np.asarray(jax.block_until_ready(
         kstep(jnp.asarray(packed), kv.base_table_f32()))) & host_ok
-    kwant = kv.batch_verify_k1(k1_pks, k1_msgs, k1_sigs)
+    kwant, _ = dispatch.device_verify("secp256k1", k1_pks, k1_msgs,
+                                     k1_sigs)
     assert np.array_equal(kmask, np.asarray(kwant))
     assert not kmask[6] and kmask.sum() == lanes - 1

@@ -105,13 +105,13 @@ def test_100_validator_net_commits_through_device_batches(monkeypatch):
     with the on-device power tally. Asserts height 1 commits and that
     the flood rode wide device dispatches."""
     from tmtpu.e2e import flood_round
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     # restored by monkeypatch after flood_round.run() selects "tpu"
     monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
     # one jit shape for everything (one ~90 s XLA:CPU compile instead of
     # one per drain size); chip_smoke.py runs the production buckets
-    monkeypatch.setattr(tv, "_pad_to_bucket", lambda n: 128)
+    monkeypatch.setattr(dispatch, "_pad_to_bucket", lambda n: 128)
 
     r = flood_round.run(99, backend="tpu", timeout=600)
     assert r["precommits_in_commit"] >= 67
@@ -128,7 +128,7 @@ def test_10k_validator_live_consensus_round(monkeypatch):
     a handful of fused device dispatches — votes/dispatch >> 1 — and the
     height must commit."""
     from tmtpu.e2e import flood_round
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     n_co = 9_999
     monkeypatch.setattr(crypto_batch, "_default_backend", "tpu")
@@ -137,7 +137,7 @@ def test_10k_validator_live_consensus_round(monkeypatch):
     # happens once, in the warm-up before consensus starts — and on one
     # device: a whole-commit warm-up flush would otherwise compile the
     # 8-virtual-device mesh graph this test never dispatches to
-    monkeypatch.setattr(tv, "_pad_to_bucket", lambda n: 10_240)
+    monkeypatch.setattr(dispatch, "_pad_to_bucket", lambda n: 10_240)
     monkeypatch.setenv("TMTPU_MESH_DEVICES", "1")
 
     r = flood_round.run(n_co, backend="tpu", timeout=900)

@@ -11,6 +11,7 @@ import pytest
 from tmtpu.crypto.secp256k1 import (
     N, PrivKeySecp256k1, PubKeySecp256k1, gen_priv_key,
 )
+from tmtpu.tpu import dispatch
 from tmtpu.tpu import fe_k1 as fe
 from tmtpu.tpu import k1_verify as kv
 
@@ -149,7 +150,7 @@ def _serial(pks, msgs, sigs):
 @pytest.mark.slow
 def test_k1_batch_all_valid():
     pks, msgs, sigs = _mk(8)
-    mask = kv.batch_verify_k1(pks, msgs, sigs)
+    mask, _ = dispatch.device_verify("secp256k1", pks, msgs, sigs)
     assert mask.all()
 
 
@@ -183,7 +184,7 @@ def test_k1_batch_adversarial_lanes_match_serial():
     want = _serial(pks, msgs, sigs)
     assert want == [i not in (1, 2, 3, 4, 5, 6, 7, 8, 9, 10)
                     for i in range(12)]
-    got = kv.batch_verify_k1(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("secp256k1", pks, msgs, sigs)
     assert got.tolist() == want
 
 
@@ -225,6 +226,6 @@ def test_k1_flipped_parity_pubkey():
     flip = 2 if pks[0][0] == 3 else 3
     pks[0] = bytes([flip]) + pks[0][1:]
     want = _serial(pks, msgs, sigs)
-    got = kv.batch_verify_k1(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("secp256k1", pks, msgs, sigs)
     assert got.tolist() == want
     assert not got[0]

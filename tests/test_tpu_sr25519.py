@@ -13,6 +13,7 @@ from tmtpu.crypto.ed25519 import gen_priv_key as gen_ed
 from tmtpu.crypto.sr25519 import (
     L, PrivKeySr25519, PubKeySr25519, gen_priv_key_from_secret,
 )
+from tmtpu.tpu import dispatch
 from tmtpu.tpu import sr_verify as srv
 
 
@@ -34,7 +35,7 @@ def _serial(pks, msgs, sigs):
 @pytest.mark.slow
 def test_sr_batch_all_valid():
     pks, msgs, sigs = _mk(12)
-    mask = srv.batch_verify_sr(pks, msgs, sigs)
+    mask, _ = dispatch.device_verify("sr25519", pks, msgs, sigs)
     assert mask.all()
 
 
@@ -69,7 +70,7 @@ def test_sr_batch_adversarial_lanes_match_serial():
     want = _serial(pks, msgs, sigs)
     assert want == [i not in (1, 2, 3, 5, 6, 7, 8, 9, 10)
                     for i in range(16)]
-    got = srv.batch_verify_sr(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("sr25519", pks, msgs, sigs)
     assert got.tolist() == want
 
 
@@ -81,7 +82,7 @@ def test_sr_identity_encoding_lane():
     pks, sigs = list(pks), list(sigs)
     pks[0] = bytes(32)
     want = _serial(pks, msgs, sigs)
-    got = srv.batch_verify_sr(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("sr25519", pks, msgs, sigs)
     assert got.tolist() == want
     assert not got[0]
 
@@ -130,7 +131,7 @@ def test_sr_pallas_kernel_interpret_matches_graph():
     s2 = bytearray(sigs[2]); s2[7] ^= 0x10; sigs[2] = bytes(s2)  # bad R
     pks[5] = pks[6]  # wrong key
     args, host_ok = srv.prepare_sr_batch(pks, msgs, sigs)
-    want = srv.batch_verify_sr(pks, msgs, sigs)
+    want, _ = dispatch.device_verify("sr25519", pks, msgs, sigs)
     got = np.asarray(
         tk.sr_verify_compact_kernel(*args, tile=8, interpret=True))
     assert (got & host_ok).tolist() == want.tolist()

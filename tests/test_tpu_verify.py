@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from tmtpu.crypto import ed25519_ref as ref
+from tmtpu.tpu import dispatch
 from tmtpu.tpu import verify as tv
 
 RNG = np.random.default_rng(7)
@@ -24,7 +25,7 @@ def _mk(n, msg_len=96):
 
 def test_all_valid_batch():
     pks, msgs, sigs = _mk(5)
-    assert tv.batch_verify(pks, msgs, sigs).all()
+    assert dispatch.device_verify("ed25519", pks, msgs, sigs)[0].all()
 
 
 def test_adversarial_lanes_match_oracle():
@@ -52,7 +53,7 @@ def test_adversarial_lanes_match_oracle():
     # wrong-length handled at the python layer
     sigs[8] = sigs[8][:63]
 
-    got = tv.batch_verify(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("ed25519", pks, msgs, sigs)
     want = np.array(
         [ref.verify(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)], dtype=bool
     )
@@ -71,13 +72,13 @@ def test_low_order_and_mixed_order_points_match_oracle():
     pk = ref.point_compress(ref.IDENTITY)
     msg = b"anything"
     assert ref.verify(pk, msg, sig)  # oracle sanity
-    assert tv.batch_verify([pk], [msg], [sig])[0]
+    assert dispatch.device_verify("ed25519", [pk], [msg], [sig])[0][0]
 
 
 def test_empty_and_single():
-    assert tv.batch_verify([], [], []).shape == (0,)
+    assert dispatch.device_verify("ed25519", [], [], [])[0].shape == (0,)
     pks, msgs, sigs = _mk(1)
-    assert tv.batch_verify(pks, msgs, sigs).all()
+    assert dispatch.device_verify("ed25519", pks, msgs, sigs)[0].all()
 
 
 def test_large_random_batch_differential():
@@ -93,7 +94,7 @@ def test_large_random_batch_differential():
             msgs[i] = os.urandom(50)
         else:
             pks[i] = os.urandom(32)
-    got = tv.batch_verify(pks, msgs, sigs)
+    got, _ = dispatch.device_verify("ed25519", pks, msgs, sigs)
     want = np.array(
         [ref.verify(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)], dtype=bool
     )

@@ -188,15 +188,15 @@ def test_jsonl_export_round_trips():
 
 
 def test_batch_verify_emits_phase_spans():
-    """ISSUE acceptance: run the device batch_verify under tracing and
+    """ISSUE acceptance: run the device flush under tracing and
     assert the pipeline phases landed as nested spans — at least four
     distinct names, every duration non-negative, children inside the
     crypto.batch_verify root."""
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch
 
     pks, msgs, sigs = _mk(8)
     trace.drain()  # isolate from earlier tests' spans
-    assert tv.batch_verify(pks, msgs, sigs).all()
+    assert dispatch.device_verify("ed25519", pks, msgs, sigs)[0].all()
     spans = trace.drain()
     names = {s.name for s in spans}
     assert len(names) >= 4, names

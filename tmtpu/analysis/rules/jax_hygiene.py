@@ -7,17 +7,17 @@ batching wins behind the throughput headline:
   device→host synchronization point — ``.item()``, ``device_get``,
   ``np.asarray`` readback, ``block_until_ready``, ``float()`` of a
   computed value — reachable from a hot flush path
-  (``*BatchVerifier._verify_pending``, the mesh dispatch twins, the
-  sidecar ``Coalescer._dispatch``). Each flush needs exactly ONE
+  (``*BatchVerifier._verify_pending``, the one dispatch function
+  ``tpu/dispatch.py device_verify``, the sidecar
+  ``Coalescer._dispatch``). Each flush needs exactly ONE
   deliberate readback of the verdict mask; those sites are baselined
   with that justification, and anything else stalls the pipeline.
 - **bucket-bypass** (per-file): a call to a ``@jax.jit``-compiled
   kernel from a function that never references the shape quantizer
-  (``_pad_to_bucket`` / ``pad_args_to_bucket`` / ``padded_lanes`` /
-  ``DEFAULT_TILE``) — raw batch sizes mean one fresh multi-second XLA
-  compile per odd size (a recompile storm).
-- **unguarded-dispatch**: a call site of the public ``batch_verify*``
-  family outside ``tmtpu/tpu/`` whose enclosing function shows no
+  (``tpu/dispatch.py padded_lanes``) — raw batch sizes mean one fresh
+  multi-second XLA compile per odd size (a recompile storm).
+- **unguarded-dispatch**: a call site of the dispatch function
+  (``device_verify``) outside ``tmtpu/tpu/`` whose enclosing function shows no
   breaker/fault discipline (no ``breaker``/``allow``/``guard``/
   ``_dispatch`` wrapper, no fault-injection site) — a device failure
   there escapes the `crypto.*` breaker state machine and has no chaos
@@ -34,22 +34,19 @@ from tmtpu.analysis.findings import Finding
 from tmtpu.analysis.index import RepoIndex
 from tmtpu.analysis.registry import rule
 
+# the one function production code reaches the device through, and the
+# one shape quantizer (tmtpu/tpu/dispatch.py)
+DISPATCH_FNS = {"device_verify"}
+QUANTIZER_TOKENS = {"padded_lanes"}
 # hot flush entry points: (class-name-or-None, method/function name)
 HOT_SEEDS: Tuple[Tuple[Optional[str], str], ...] = (
     (None, "_verify_pending"),           # every *BatchVerifier flush
     ("Coalescer", "_dispatch"),          # sidecar batching loop
-    (None, "batch_verify_mesh"),         # mesh dispatch twins
-    (None, "batch_verify_tally_mesh"),
-)
+) + tuple((None, fn) for fn in sorted(DISPATCH_FNS))
 # markers only count inside the dispatch tier — a float() in some cold
 # config helper reached through a deep chain is noise, not a stall
 HOT_RELS = ("tmtpu/crypto/", "tmtpu/tpu/", "tmtpu/sidecar/")
 
-QUANTIZER_TOKENS = {"_pad_to_bucket", "pad_args_to_bucket", "padded_lanes",
-                    "pad_packed", "DEFAULT_TILE"}
-DISPATCH_FNS = {"batch_verify", "batch_verify_sr", "batch_verify_k1",
-                "batch_verify_tally", "batch_verify_mesh",
-                "batch_verify_tally_mesh"}
 GUARD_TOKENS = {"breaker", "allow", "guard", "fire", "_dispatch",
                 "note_failure", "with_fallback"}
 
@@ -88,7 +85,7 @@ def _check_host_sync(index: RepoIndex) -> List[Finding]:
     findings, seen = [], set()
     entries = []
     for cls_name, meth in HOT_SEEDS:
-        if cls_name is None and meth.startswith("batch_"):
+        if cls_name is None and meth in DISPATCH_FNS:
             for rel, fn in an._functions_by_name.get(meth, []):
                 entries.append((None, fn, rel, meth))
         else:
@@ -183,7 +180,7 @@ def _check_bucket_bypass(index: RepoIndex) -> List[Finding]:
                 findings.append(Finding(
                     "jax-hygiene", fi.rel,
                     f"{qual} dispatches jit kernel {callee}() without "
-                    f"quantizing lane shapes through _pad_to_bucket — "
+                    f"quantizing lane shapes through padded_lanes — "
                     f"every odd batch size triggers a fresh XLA compile",
                     line=line,
                     key=f"jax-hygiene::bucket-bypass::{fi.rel}::{qual}"
@@ -226,7 +223,7 @@ def _check_unguarded_dispatch(index: RepoIndex) -> List[Finding]:
 
 @rule("jax-hygiene",
       doc="no stray host-sync on hot flush paths, no jit dispatch "
-          "bypassing the _pad_to_bucket shape quantizer, no batch_verify* "
+          "bypassing the padded_lanes shape quantizer, no device_verify "
           "call outside a crypto.* breaker or fault site",
       triggers=("tmtpu/crypto", "tmtpu/tpu", "tmtpu/sidecar", "tmtpu"))
 def check(index: RepoIndex) -> List[Finding]:

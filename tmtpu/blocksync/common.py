@@ -24,7 +24,7 @@ BLOCKCHAIN_CHANNEL = 0x40
 # above 3,072), so that a small and a large validator set both flush one
 # device shape, which the reactor compiles before it asks for a block.
 # 6,144 is the bucket the old 32-block run of a 175-validator chain
-# padded to (tpu/verify.py _pad_to_bucket).
+# padded to (tpu/dispatch.py _pad_to_bucket).
 RUN_LANES = 6144
 
 

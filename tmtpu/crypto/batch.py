@@ -12,13 +12,12 @@ against (VoteSet, VerifyCommit*, light client, evidence):
 Backends:
 - ``cpu``: serial per-signature verify through the PubKey objects (OpenSSL
   under the hood) — the fallback and the small-batch fast path;
-- ``tpu``: groups items per curve into device batches — ed25519
-  (tmtpu.tpu.verify.batch_verify), sr25519
-  (tmtpu.tpu.sr_verify.batch_verify_sr), secp256k1
-  (tmtpu.tpu.k1_verify.batch_verify_k1) — so mixed-curve sets get one
-  device dispatch per curve present. Per-lane semantics are identical to
-  serial verification (no probabilistic batch equation), so the returned
-  mask is exact for mixed valid/invalid batches.
+- ``tpu``: groups items per curve into device batches — one
+  ``tmtpu.tpu.dispatch.device_verify`` per curve of its table (ed25519,
+  sr25519, secp256k1) present, so mixed-curve sets get one device
+  dispatch a curve. Per-lane semantics are identical to serial
+  verification (no probabilistic batch equation), so the returned mask
+  is exact for mixed valid/invalid batches.
 
 Backend selection: ``set_default_backend`` / config ``crypto.backend``.
 ``auto`` means the device backend only when JAX's first device is a TPU
@@ -54,6 +53,7 @@ and per-batch deadline machinery are unchanged.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time as _time_mod
@@ -431,16 +431,16 @@ def start_backend(backend: str, who: str) -> Dict:
 def _warm_sizes(max_lanes: int) -> List[int]:
     """Flush sizes that between them land in every padded shape flushes
     of up to ``max_lanes`` lanes use under the production bucket policy
-    (tmtpu.tpu.verify._pad_to_bucket): the smallest lane count of each
+    (tmtpu.tpu.dispatch._pad_to_bucket): the smallest lane count of each
     bucket, then ``max_lanes`` itself — so the widest flush is warmed
     exactly as it will route (mesh or single device)."""
-    from tmtpu.tpu import verify as tv
+    from tmtpu.tpu import dispatch as _disp
 
     sizes: List[int] = []
     n = _TPU_MIN_BATCH
     while n <= max_lanes:
         sizes.append(n)
-        n = tv._pad_to_bucket(n) + 1
+        n = _disp._pad_to_bucket(n) + 1
     if sizes and sizes[-1] != max_lanes:
         sizes.append(max_lanes)
     return sizes
@@ -543,11 +543,11 @@ class BatchVerifier(keys.BatchVerifier):
     the way out. ``self.cache_stats`` carries the per-flush breakdown
     (lanes/hits/dedup/dispatched) for callers and the timeline.
 
-    ``min_lanes`` pins the device shape of this verifier's ed25519 mask
-    flush (``verify()``): whatever the sigcache leaves of it pads as if
-    it held that many lanes, so a caller whose flushes vary in length
-    meets the one shape it warmed (``warm_pinned``). The serial and
-    sidecar backends have no shape and ignore it."""
+    ``min_lanes`` pins the device shape of this verifier's flushes:
+    whatever the sigcache leaves of one pads as if it held that many
+    lanes, so a caller whose flushes vary in length meets the one shape
+    it warmed (``warm_pinned``). The serial and sidecar backends have no
+    shape and ignore it."""
 
     def __init__(self, min_lanes: int = 0):
         self.min_lanes = int(min_lanes)
@@ -715,60 +715,59 @@ class CPUBatchVerifier(BatchVerifier):
 
 class TPUBatchVerifier(BatchVerifier):
     @staticmethod
-    def _split(items):
-        """Partition items into per-curve device-eligible lanes and CPU
-        lanes (mixed-curve valsets dispatch one device batch per curve)."""
-        ed_idx, ed_pks, ed_msgs, ed_sigs, ed_powers = [], [], [], [], []
-        sr_idx, k1_idx, cpu_idx = [], [], []
+    def _split(items, curves):
+        """Partition the lanes: per curve in ``curves`` (the dispatch
+        table) the device-eligible lanes as ``(idx, pks, msgs, sigs,
+        powers)`` — a mixed-curve valset gets one device batch a curve —
+        and the indexes of the rest, which verify serially."""
+        groups: Dict[str, Tuple[list, list, list, list, list]] = {}
+        cpu_idx: List[int] = []
+        # a flush is mostly one curve: its five lists stay in locals and
+        # the table is asked only where the key type changes
+        cur = idx = pks = msgs = sigs = powers = None
         for i, (pk, msg, sig, power) in enumerate(items):
-            if pk.type_value() == ED25519 and len(sig) == 64:
-                ed_idx.append(i)
-                ed_pks.append(pk.bytes())
-                ed_msgs.append(msg)
-                ed_sigs.append(sig)
-                ed_powers.append(power)
-            elif pk.type_value() == SR25519 and len(sig) == 64:
-                sr_idx.append(i)
-            elif pk.type_value() == SECP256K1 and len(sig) == 64:
-                k1_idx.append(i)
+            curve = pk.type_value()
+            if curve != cur:
+                cur = curve
+                idx, pks, msgs, sigs, powers = groups.setdefault(
+                    curve, ([], [], [], [], [])) if curve in curves \
+                    else (None,) * 5
+            if idx is not None and len(sig) == 64:
+                idx.append(i)
+                pks.append(pk.bytes())
+                msgs.append(msg)
+                sigs.append(sig)
+                powers.append(power)
             else:
                 cpu_idx.append(i)
-        return (ed_idx, ed_pks, ed_msgs, ed_sigs, ed_powers,
-                sr_idx, k1_idx, cpu_idx)
+        return groups, cpu_idx
 
     def _verify_pending(self, items, tally) -> Tuple[List[bool], int]:
-        """Fused verify + power tally over the deduped miss lanes:
-        ed25519 lanes get ONE device dispatch that (for ``tally``)
-        returns both the validity mask and the psum of valid lanes'
-        powers (tmtpu.tpu.sharding.verify_tally_step_compact); sr25519
-        and secp256k1 lanes get their own device dispatches (mask only —
-        powers summed on host); sub-threshold groups verify serially."""
-        import time as _time
-
+        """One device flush a curve present (``tpu/dispatch.py
+        device_verify``), each under the ``crypto.tpu`` breaker and the
+        per-batch deadline: for ``tally`` a curve with a fused step
+        returns the psum of its valid lanes' powers with the mask, the
+        others' powers are summed on the host; lanes of a key type the
+        table does not hold, and groups below ``_TPU_MIN_BATCH``, verify
+        serially."""
         from tmtpu.libs import metrics as _m
+        from tmtpu.tpu import dispatch as _disp
 
-        t0 = _time.perf_counter()
+        t0 = _time_mod.perf_counter()
         with trace.span("batch.split"):
-            (ed_idx, ed_pks, ed_msgs, ed_sigs, ed_powers,
-             sr_idx, k1_idx, cpu_idx) = self._split(items)
-        if cpu_idx:
-            _m.crypto_cpu_fallback.inc(len(cpu_idx), curve="other",
-                                       reason="unsupported")
-        if sr_idx and len(sr_idx) < _TPU_MIN_BATCH:
-            cpu_idx += sr_idx  # below dispatch threshold: serial path
-            _m.crypto_cpu_fallback.inc(len(sr_idx), curve=SR25519,
-                                       reason="small-batch")
-            sr_idx = []
-        if k1_idx and len(k1_idx) < _TPU_MIN_BATCH:
-            cpu_idx += k1_idx
-            _m.crypto_cpu_fallback.inc(len(k1_idx), curve=SECP256K1,
-                                       reason="small-batch")
-            k1_idx = []
+            groups, cpu_idx = self._split(items, _disp.CURVES)
         mask: List[bool] = [False] * len(items)
         tallied = 0
+        br = _tpu_breaker()
+        deadline = batch_deadline_s()
 
-        def _verify_serially(idx_list, reason):
+        def _serial(idx_list, curve, reason):
+            # the exact serial path: lanes the table holds no row for,
+            # and lanes whose device batch failed or was never attempted
+            # (open breaker, small batch)
             nonlocal tallied
+            _m.crypto_cpu_fallback.inc(len(idx_list), curve=curve,
+                                       reason=reason)
             with trace.span("batch.serial", lanes=len(idx_list),
                             reason=reason):
                 for i in idx_list:
@@ -777,19 +776,7 @@ class TPUBatchVerifier(BatchVerifier):
                     if mask[i]:
                         tallied += power
 
-        if cpu_idx:
-            _verify_serially(cpu_idx, "cpu-lanes")
-        br = _tpu_breaker()
-        deadline = batch_deadline_s()
-
-        def _serial(idx_list, curve, reason):
-            # CPU-serial fallback for lanes whose device batch failed
-            # (or was never attempted: open breaker / small batch)
-            _m.crypto_cpu_fallback.inc(len(idx_list), curve=curve,
-                                       reason=reason)
-            _verify_serially(idx_list, reason)
-
-        def _dispatch(curve, idx_list, thunk, apply):
+        def _dispatch(curve, idx_list, thunk):
             """One per-curve device batch under the breaker and the
             per-batch deadline. Any failure — hung dispatch past the
             deadline, device/runtime error — records against the
@@ -799,15 +786,17 @@ class TPUBatchVerifier(BatchVerifier):
             estimate (cache hits and serial fallbacks never do).
             ``batch.dispatch`` is the wait on the device path: the
             worker's ``crypto.batch_verify*`` spans are its children."""
+            nonlocal tallied
             failed = None
             with trace.span("batch.dispatch", curve=curve,
                             lanes=len(idx_list)) as sp:
                 if not br.allow():
                     failed = "breaker-open"
                 else:
-                    d0 = _time.perf_counter()
+                    d0 = _time_mod.perf_counter()
                     try:
-                        out = _bk.call_with_deadline(thunk, deadline)
+                        dev_mask, dev_sum = _bk.call_with_deadline(
+                            thunk, deadline)
                     except _bk.DeadlineExceeded as e:
                         _m.crypto_batch_deadline_exceeded.inc(curve=curve)
                         br.record_failure(e)
@@ -818,104 +807,35 @@ class TPUBatchVerifier(BatchVerifier):
                         failed = "device-error"
                     else:
                         br.record_success()
-                        SCHEDULER.note_dispatch(len(idx_list),
-                                                _time.perf_counter() - d0)
+                        SCHEDULER.note_dispatch(
+                            len(idx_list), _time_mod.perf_counter() - d0)
                 if failed:
                     sp.set(failed=failed)
             if failed:
                 _serial(idx_list, curve, failed)
                 return
             with trace.span("batch.apply"):
-                apply(out)
-
-        def _apply_mask(idx_list):
-            def apply(dev_mask):
-                nonlocal tallied
                 for j, i in enumerate(idx_list):
                     mask[i] = bool(dev_mask[j])
-                    if mask[i]:
-                        tallied += items[i][3]
-            return apply
+                if dev_sum is None:  # no fused tally step: the host sums
+                    dev_sum = sum(items[i][3] for i in idx_list if mask[i])
+                tallied += dev_sum
 
-        from tmtpu.tpu import mesh_dispatch as _mesh
-
-        def _mesh_first(curve, n_lanes, mesh_thunk, single_thunk):
-            """Thunk combinator for _dispatch: flushes past the
-            shard_min_lanes threshold try the multi-chip mesh first. A
-            mesh failure records against the ``crypto.mesh`` breaker —
-            never ``crypto.tpu``, whose single-device path may be
-            perfectly healthy — and the SAME flush falls through to the
-            single-device call inside the same deadline window, so the
-            degradation ladder is mesh → single-device → CPU-serial."""
-            def thunk():
-                if _mesh.route(curve, n_lanes):
-                    try:
-                        return mesh_thunk()
-                    except Exception as e:  # noqa: BLE001 — broken
-                        # collectives must not take down verification
-                        _mesh.note_failure(curve, n_lanes, e)
-                return single_thunk()
-            return thunk
-
-        if sr_idx:
-            from tmtpu.tpu.sr_verify import batch_verify_sr
-
-            sr_pks = [items[i][0].bytes() for i in sr_idx]
-            sr_msgs = [items[i][1] for i in sr_idx]
-            sr_sigs = [items[i][2] for i in sr_idx]
-            _dispatch(SR25519, sr_idx, _mesh_first(
-                SR25519, len(sr_idx),
-                lambda: _mesh.batch_verify_mesh(
-                    SR25519, sr_pks, sr_msgs, sr_sigs),
-                lambda: batch_verify_sr(sr_pks, sr_msgs, sr_sigs),
-            ), _apply_mask(sr_idx))
-        if k1_idx:
-            from tmtpu.tpu.k1_verify import batch_verify_k1
-
-            k1_pks = [items[i][0].bytes() for i in k1_idx]
-            k1_msgs = [items[i][1] for i in k1_idx]
-            k1_sigs = [items[i][2] for i in k1_idx]
-            _dispatch(SECP256K1, k1_idx, _mesh_first(
-                SECP256K1, len(k1_idx),
-                lambda: _mesh.batch_verify_mesh(
-                    SECP256K1, k1_pks, k1_msgs, k1_sigs),
-                lambda: batch_verify_k1(k1_pks, k1_msgs, k1_sigs),
-            ), _apply_mask(k1_idx))
-        if ed_idx:
-            if len(ed_idx) < _TPU_MIN_BATCH:
-                _serial(ed_idx, ED25519, "small-batch")
-            elif tally:
-                from tmtpu.tpu import sharding as sh
-
-                def _apply_tally(out):
-                    nonlocal tallied
-                    dev_mask, dev_sum = out
-                    for j, i in enumerate(ed_idx):
-                        mask[i] = bool(dev_mask[j])
-                    tallied += dev_sum
-
-                _dispatch(ED25519, ed_idx, _mesh_first(
-                    ED25519, len(ed_idx),
-                    lambda: _mesh.batch_verify_tally_mesh(
-                        ed_pks, ed_msgs, ed_sigs, ed_powers),
-                    lambda: sh.batch_verify_tally(
-                        ed_pks, ed_msgs, ed_sigs, ed_powers),
-                ), _apply_tally)
-            else:
-                from tmtpu.tpu import verify as tv
-
-                pin = self.min_lanes
-                _dispatch(ED25519, ed_idx, _mesh_first(
-                    ED25519, max(len(ed_idx), pin),
-                    lambda: _mesh.batch_verify_mesh(
-                        ED25519, ed_pks, ed_msgs, ed_sigs, pin),
-                    lambda: tv.batch_verify(ed_pks, ed_msgs, ed_sigs, pin),
-                ), _apply_mask(ed_idx))
+        if cpu_idx:
+            _serial(cpu_idx, "other", "unsupported")
+        for curve, (idx, pks, msgs, sigs, powers) in groups.items():
+            if len(idx) < _TPU_MIN_BATCH:
+                # below this, dispatch overhead beats the serial path
+                _serial(idx, curve, "small-batch")
+                continue
+            _dispatch(curve, idx, functools.partial(
+                _disp.device_verify, curve, pks, msgs, sigs,
+                powers if tally else None, self.min_lanes))
         from tmtpu.libs import timeline as _tl
 
         _tl.record_flush(backend="tpu", lanes=len(items),
                          ok=sum(mask),
-                         seconds=round(_time.perf_counter() - t0, 6))
+                         seconds=round(_time_mod.perf_counter() - t0, 6))
         return mask, tallied
 
 

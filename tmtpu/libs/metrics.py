@@ -609,8 +609,8 @@ validator_tracked = DEFAULT.gauge(
 
 # --- the crypto batch-verify pipeline metric set ----------------------------
 #
-# Observed at every batch call site: the per-curve device paths
-# (tmtpu/tpu/verify.py, sr_verify.py, k1_verify.py) and the CPU batch
+# Observed at every batch call site: the device dispatch
+# (tmtpu/tpu/dispatch.py, once a flush) and the CPU batch
 # verifier (tmtpu/crypto/batch.py). Labels: curve = ed25519 | sr25519 |
 # secp256k1; backend = the jax device platform ("cpu", "tpu", ...) or
 # "cpu" for the serial path; impl = pallas | xla | serial | native.

@@ -29,14 +29,12 @@ Split of labor:
 from __future__ import annotations
 
 import hashlib
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from tmtpu.crypto.secp256k1 import N
-from tmtpu.libs import faultinject, trace
 from tmtpu.tpu import fe_k1 as fe
 from tmtpu.tpu.verify import lt_le
 
@@ -421,62 +419,3 @@ def _k1_kernel_packed_jit(packed):
 
     planes, parity = split_packed_k1(packed)
     return kk.k1_verify_compact_kernel(planes[0], parity, *planes[1:])
-
-
-# chaos site on the device dispatch boundary (docs/RESILIENCE.md)
-_FAULT_K1_BATCH = faultinject.register("tpu.secp256k1.batch")
-
-
-def batch_verify_k1(pks, msgs, sigs) -> np.ndarray:
-    """secp256k1 batch verification: bool [B] per-signature validity,
-    matching serial PubKeySecp256k1.verify_signature per lane. On real
-    TPUs the fused Pallas kernel (tmtpu.tpu.k1_kernel) runs the whole
-    device half in VMEM; the plain-XLA graph remains the CPU/virtual-mesh
-    path and the fallback should Mosaic reject the kernel."""
-    from tmtpu.tpu import verify as tv
-    from tmtpu.tpu.verify import pad_packed
-
-    B = len(sigs)
-    if B == 0:
-        return np.zeros(0, dtype=bool)
-    faultinject.fire(_FAULT_K1_BATCH)
-    from tmtpu.libs import metrics as _m
-
-    t0 = time.perf_counter()
-    with trace.span("secp256k1.prepare", lanes=B):
-        packed, host_ok = prepare_k1_batch_packed(pks, msgs, sigs)
-    # breaker replaces the old module _kernel_broken latch (policy in
-    # tmtpu.tpu.verify.note_pallas_failure, same as sr_verify)
-    pbr = tv.pallas_breaker("secp256k1")
-    if tv.use_pallas_kernel() and pbr.allow():
-        from tmtpu.tpu import k1_kernel as kk
-
-        padded = max(kk.DEFAULT_TILE, tv._pad_to_bucket(B))
-        try:
-            with trace.span("secp256k1.execute", impl="pallas",
-                            lanes=B, padded=padded):
-                mask = np.asarray(_k1_kernel_packed_jit(
-                    jnp.asarray(pad_packed(packed, padded))))[:B]
-            pbr.record_success()
-            _m.observe_crypto_batch("secp256k1", tv.backend_label(),
-                                    "pallas", B, padded,
-                                    time.perf_counter() - t0)
-            return mask & host_ok
-        except Exception as e:  # noqa: BLE001
-            tv.note_pallas_failure(pbr, e)
-            import sys
-
-            print(
-                "k1_verify: Pallas kernel "
-                f"{'disabled' if pbr.state != 'closed' else 'failed'}"
-                f" (breaker {pbr.state}): {e!r}",
-                file=sys.stderr)
-    padded = tv._pad_to_bucket(B)
-    with trace.span("secp256k1.execute", impl="xla", lanes=B,
-                    padded=padded):
-        packed = pad_packed(packed, padded)
-        mask = np.asarray(
-            _k1_verify_packed_jit(jnp.asarray(packed), base_table_f32()))[:B]
-    _m.observe_crypto_batch("secp256k1", tv.backend_label(), "xla",
-                            B, padded, time.perf_counter() - t0)
-    return mask & host_ok

@@ -306,7 +306,7 @@ def _verify_pallas_jit(pk_b, r_b, s_b, h_b, tile: int, interpret: bool):
 
 def _default_interpret() -> bool:
     # compiled (Mosaic) on a TPU, interpreted anywhere else; a failing
-    # jax.devices() surfaces (same check as verify.use_pallas_kernel)
+    # jax.devices() surfaces (same check as dispatch.use_pallas_kernel)
     return jax.devices()[0].platform != "tpu"
 
 
@@ -314,7 +314,7 @@ def verify_compact_kernel(pk_b, r_b, s_b, h_b, *, tile: int = 256,
                           interpret: bool | None = None):
     """Drop-in twin of verify.verify_core_compact running as one fused
     Pallas kernel. pk_b/r_b/s_b/h_b: [32, B] uint8 device arrays (B a
-    multiple of ``tile``; verify.batch_verify pads). Returns bool [B]."""
+    multiple of ``tile``; dispatch.padded_lanes sees to it). Returns bool [B]."""
     if interpret is None:
         interpret = _default_interpret()
     return _verify_pallas_jit(pk_b, r_b, s_b, h_b, tile, interpret) != 0

@@ -1,11 +1,11 @@
 """Mesh-aware dispatch: shard production verify flushes across chips.
 
-The four sharded primitives in tpu/sharding.py are MULTICHIP-certified
-but, until this layer, nothing in the production path called them —
-``crypto/batch.py`` and the sidecar coalescer dispatched to one device.
-This module owns the process-wide device :class:`~jax.sharding.Mesh`
-and the per-curve sharded callables, and routes any flush of at least
-``crypto.shard_min_lanes`` lanes across every chip on the host:
+This module owns what is the mesh's own: the process-wide device
+:class:`~jax.sharding.Mesh`, the sharded callables built for it, the
+gate (``route``), the ``crypto.mesh`` breaker, occupancy and
+``snapshot()``. The flush itself is ``tpu/dispatch.py``'s, which sends
+any flush of at least ``crypto.shard_min_lanes`` lanes across every chip
+on the host:
 
 - ed25519 rides the fused verify+tally step with the voting-power
   reduction psum'd ON DEVICE, so the host reads back one packed mask
@@ -16,20 +16,18 @@ and the per-curve sharded callables, and routes any flush of at least
   kernel under shard_map; the metric label says which one ran
   (``mesh-pallas`` / ``mesh-xla``).
 
-Contract with the callers: every entry point here either returns the
-EXACT single-device result or raises. ``crypto.batch.TPUBatchVerifier``
-wraps each call in its own try — a mesh failure records against the
-``crypto.mesh`` breaker (never ``crypto.tpu``) and the flush falls
-through to the single-device path inside the same dispatch window, so
-the degradation ladder is mesh → single-device → CPU-serial with exact
-masks at every rung.
+Contract: a sharded flush either returns the EXACT single-device
+result or raises. ``dispatch.device_verify`` records a mesh failure
+against the ``crypto.mesh`` breaker (never ``crypto.tpu``) and the flush
+falls through to the single-device path inside the same dispatch window,
+so the degradation ladder is mesh → single-device → CPU-serial with
+exact masks at every rung.
 
-Padding: the packed bitarray output shards one uint32 word per 32
-lanes, so sharded lane counts must be a multiple of ``32 x n_devices``
-(the dryrun_multichip quantum); on top of that the padded size reuses
-``tv._pad_to_bucket`` so the jit cache sees the same handful of shapes
-the single-device path does. Pad lanes replicate lane 0's bytes but
-carry ZERO power limbs, so they can never contribute to the tally.
+Padding: the packed bitarray output shards one uint32 word per
+``WORD_LANES`` lanes, so sharded lane counts must be a multiple of
+``32 x n_devices`` (the dryrun_multichip quantum; ``dispatch.padded_lanes``
+rounds the bucket up to it). Pad lanes replicate lane 0's bytes but carry
+ZERO power limbs, so they can never contribute to the tally.
 
 jax is imported lazily — ``configure()`` runs in every node at startup,
 including CPU-only ones that must not pay backend init.
@@ -44,10 +42,7 @@ from __future__ import annotations
 
 import os
 import threading
-import time
-from typing import Dict, Optional, Tuple
-
-import numpy as np
+from typing import Dict, Optional
 
 from tmtpu.libs import breaker as _bk
 
@@ -56,9 +51,9 @@ from tmtpu.libs import breaker as _bk
 # (the single-device path may be perfectly healthy)
 MESH_BREAKER_NAME = "crypto.mesh"
 
-ED25519 = "ed25519"
-SR25519 = "sr25519"
-SECP256K1 = "secp256k1"
+# the packed bitarray a sharded step returns holds this many lanes a
+# uint32 word, and every shard must hold whole words
+WORD_LANES = 32
 
 _lock = threading.Lock()
 # defaults mirror config/config.py CryptoConfig; configure() overwrites
@@ -66,7 +61,7 @@ _cfg = {"mesh_devices": 0, "shard_min_lanes": 2048}
 _state: Dict = {
     "mesh": None,          # cached jax Mesh
     "mesh_key": None,      # (n, device ids) the cache was built for
-    "fns": {},             # (kind, mesh_key) -> jitted sharded callable
+    "fns": {},             # (builder, mesh_key) -> jitted sharded callable
     "dispatches": 0,
     "occupancy": {},       # device id -> cumulative lanes placed there
     "last": None,          # last dispatch summary (sidecar Stats)
@@ -138,7 +133,7 @@ def shard_min_lanes() -> int:
     return _cfg["shard_min_lanes"]
 
 
-def _get_mesh():
+def get_mesh():
     """The cached Mesh, rebuilt when the configured width changes.
     Raises :class:`MeshUnavailable` when fewer than 2 devices answer."""
     import jax
@@ -174,7 +169,7 @@ def device_count() -> int:
     the mesh is counted, so a host whose chips went missing shows up in
     ``crypto_mesh_fallback_total{reason="mesh-init"}``."""
     try:
-        return int(_get_mesh().devices.size)
+        return int(get_mesh().devices.size)
     except MeshUnavailable:
         return 0
     except Exception:  # noqa: BLE001 — unavailable == 0
@@ -212,19 +207,10 @@ def note_failure(curve: str, lanes: int, exc: Exception) -> None:
                                       reason="device-error")
 
 
-def padded_lanes(b: int, n_devices: int) -> int:
-    """Bucket-pad B (jit-cache stability, tv._pad_to_bucket), then round
-    up to the mesh quantum 32 x n so every shard gets whole bitarray
-    words and equal lane counts."""
-    from tmtpu.tpu import verify as tv
-
-    q = 32 * n_devices
-    base = max(b, tv._pad_to_bucket(b))
-    return ((base + q - 1) // q) * q
-
-
-def _fn(kind: str, mesh, builder):
-    key = (kind, _state["mesh_key"])
+def sharded(builder, mesh):
+    """``builder(mesh)``, a tpu/sharding.py sharded callable, built once
+    a mesh and kept until the mesh changes."""
+    key = (builder, _state["mesh_key"])
     with _lock:
         f = _state["fns"].get(key)
     if f is None:
@@ -244,12 +230,15 @@ def _shard_lanes(mask) -> Dict[int, int]:
     return out
 
 
-def _note_dispatch(curve: str, lanes: int, padded: int,
-                   shard_lanes: Dict[int, int], psum_s: float,
-                   total_s: float, impl: str) -> None:
+def note_dispatch(curve: str, lanes: int, padded: int, dev_mask,
+                  psum_s: float, total_s: float, impl: str) -> None:
+    """A sharded flush came back: occupancy as placed (``dev_mask`` is
+    the device-side mask), the mesh metric set, the timeline, and a
+    success for ``crypto.mesh``."""
     from tmtpu.libs import metrics as _m
     from tmtpu.libs import timeline as _tl
 
+    shard_lanes = _shard_lanes(dev_mask)
     n = len(shard_lanes)
     per_shard = max(shard_lanes.values())
     with _lock:
@@ -270,6 +259,7 @@ def _note_dispatch(curve: str, lanes: int, padded: int,
     _tl.record_flush(backend="mesh", curve=curve, lanes=lanes,
                      shards=n, shard_lanes=per_shard,
                      seconds=round(total_s, 6))
+    breaker().record_success()
 
 
 def dispatch_count() -> int:
@@ -292,138 +282,3 @@ def snapshot() -> Dict:
             "last": dict(_state["last"]) if _state["last"] else None,
             "breaker": breaker().state,
         }
-
-
-# --- sharded entry points ---------------------------------------------------
-
-
-def batch_verify_tally_mesh(pks, msgs, sigs, powers
-                            ) -> Tuple[np.ndarray, int]:
-    """ed25519 fused verify + tally across the host mesh: bit-exact twin
-    of sharding.batch_verify_tally with the power reduction psum'd over
-    the "sig" axis. Raises on any device/mesh failure (caller degrades
-    to single-device)."""
-    import jax
-    import jax.numpy as jnp
-
-    from tmtpu.libs import trace
-    from tmtpu.tpu import sharding as sh
-    from tmtpu.tpu import verify as tv
-
-    b = len(sigs)
-    if b == 0:
-        return np.zeros(0, dtype=bool), 0
-    mesh = _get_mesh()
-    n = int(mesh.devices.size)
-    t0 = time.perf_counter()
-    with trace.span("crypto.mesh_verify_tally", curve=ED25519,
-                    lanes=b, shards=n) as sp:
-        packed, host_ok = tv.prepare_batch_packed(pks, msgs, sigs)
-        p = np.asarray(powers, dtype=np.int64).copy()
-        p[~host_ok] = 0
-        use_kernel = tv.use_pallas_kernel()
-        padded = padded_lanes(b, n)
-        if use_kernel:
-            from tmtpu.tpu import kernel as tk
-
-            q = tk.DEFAULT_TILE * n
-            padded = ((padded + q - 1) // q) * q
-        impl = "mesh-pallas" if use_kernel else "mesh-xla"
-        sp.set(padded=padded, impl=impl)
-        # pad lanes replicate lane 0's BYTES only — their power limbs
-        # stay zero, so padding can never leak into the tally
-        power_limbs = np.zeros((sh.POWER_LIMBS, padded), dtype=np.int32)
-        power_limbs[:, :b] = sh.powers_to_limbs(p)
-        packed_h = tv.pad_packed(packed, padded)
-        if use_kernel:
-            fn = _fn("ed25519-kernel", mesh,
-                     sh.sharded_verify_tally_packed_kernel)
-            mask, power_sums, _bits = fn(jnp.asarray(packed_h),
-                                         jnp.asarray(power_limbs))
-        else:
-            fn = _fn("ed25519-xla", mesh, sh.sharded_verify_tally_packed)
-            mask, power_sums, _bits = fn(jnp.asarray(packed_h),
-                                         jnp.asarray(power_limbs),
-                                         tv.base_table_f32())
-        mask = jax.block_until_ready(mask)
-        t_mask = time.perf_counter()
-        tallied = sh.limb_sums_to_int(power_sums)   # the psum readback
-        psum_s = time.perf_counter() - t_mask
-        placed = _shard_lanes(mask)
-        mask = np.asarray(mask)[:b] & host_ok
-    total = time.perf_counter() - t0
-    _note_dispatch(ED25519, b, padded, placed, psum_s, total, impl)
-    breaker().record_success()
-    from tmtpu.libs import metrics as _m
-
-    _m.observe_crypto_batch(ED25519, tv.backend_label(), impl, b,
-                            padded, total)
-    return mask, tallied
-
-
-def batch_verify_mesh(curve: str, pks, msgs, sigs,
-                      min_lanes: int = 0) -> np.ndarray:
-    """Mask-only lane-sharded batch verify for any supported curve —
-    bit-exact twin of the single-device batch_verify/batch_verify_sr/
-    batch_verify_k1. Raises on failure. ``min_lanes`` as in
-    ``tv.batch_verify``: the flush pads as if it held that many."""
-    import jax
-    import jax.numpy as jnp
-
-    from tmtpu.libs import trace
-    from tmtpu.tpu import sharding as sh
-    from tmtpu.tpu import verify as tv
-
-    b = len(sigs)
-    if b == 0:
-        return np.zeros(0, dtype=bool)
-    mesh = _get_mesh()
-    n = int(mesh.devices.size)
-    t0 = time.perf_counter()
-    with trace.span("crypto.mesh_verify", curve=curve, lanes=b,
-                    shards=n) as sp:
-        if curve == ED25519:
-            packed, host_ok = tv.prepare_batch_packed(pks, msgs, sigs)
-            table = tv.base_table_f32()
-
-            def build(m):
-                return sh.sharded_verify_tally_packed(m)
-        elif curve == SR25519:
-            from tmtpu.tpu import sr_verify as srv
-
-            packed, host_ok = srv.prepare_sr_batch_packed(pks, msgs, sigs)
-            table = tv.base_table_f32()
-            build = sh.sharded_verify_sr
-        elif curve == SECP256K1:
-            from tmtpu.tpu import k1_verify as kv
-
-            packed, host_ok = kv.prepare_k1_batch_packed(pks, msgs, sigs)
-            table = kv.base_table_f32()
-            build = sh.sharded_verify_k1
-        else:
-            raise ValueError(f"unsupported mesh curve {curve!r}")
-        padded = padded_lanes(max(b, min_lanes), n)
-        # every mask-only mesh route is the lane-sharded XLA graph
-        impl = "mesh-xla"
-        sp.set(padded=padded, impl=impl)
-        packed_h = tv.pad_packed(packed, padded)
-        if curve == ED25519:
-            # reuse the fused tally callable with zero powers: one jit
-            # cache entry serves both verify and verify_tally flushes
-            fn = _fn("ed25519-xla", mesh, build)
-            zeros = jnp.zeros((sh.POWER_LIMBS, padded), dtype=jnp.int32)
-            mask, _sums, _bits = fn(jnp.asarray(packed_h), zeros, table)
-        else:
-            fn = _fn(curve, mesh, build)
-            mask = fn(jnp.asarray(packed_h), table)
-        mask = jax.block_until_ready(mask)
-        placed = _shard_lanes(mask)
-        mask = np.asarray(mask)[:b] & host_ok
-    total = time.perf_counter() - t0
-    _note_dispatch(curve, b, padded, placed, 0.0, total, impl)
-    breaker().record_success()
-    from tmtpu.libs import metrics as _m
-
-    _m.observe_crypto_batch(curve, tv.backend_label(), impl, b,
-                            padded, total)
-    return mask

@@ -32,22 +32,15 @@ canonical encodings and cosets, so no byte re-encoding is needed).
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from tmtpu.crypto import ed25519_ref as ref
-from tmtpu.libs import faultinject, trace
 from tmtpu.crypto import ristretto
 from tmtpu.crypto.merlin import Transcript
 from tmtpu.tpu import curve, fe
-from tmtpu.tpu.verify import (
-    base_table_f32,
-    digits_msb_device,
-    lt_le,
-)
+from tmtpu.tpu.verify import digits_msb_device, lt_le
 
 L = ref.L
 P = ref.P
@@ -253,11 +246,6 @@ def prepare_sr_batch(pks, msgs, sigs):
 
 
 @jax.jit
-def _sr_verify_compact_jit(pk_b, r_b, s_b, k_b, table):
-    return sr_verify_core_compact(pk_b, r_b, s_b, k_b, table)
-
-
-@jax.jit
 def _sr_verify_packed_jit(packed, table):
     """Packed-input twin: ONE [128, B] uint8 H2D transfer, split device-
     side (slices are free under jit)."""
@@ -272,63 +260,3 @@ def _sr_kernel_packed_jit(packed):
     from tmtpu.tpu.verify import split_packed
 
     return tk.sr_verify_compact_kernel(*split_packed(packed))
-
-
-# chaos site on the device dispatch boundary (docs/RESILIENCE.md)
-_FAULT_SR_BATCH = faultinject.register("tpu.sr25519.batch")
-
-
-def batch_verify_sr(pks, msgs, sigs) -> np.ndarray:
-    """sr25519 batch verification: bool [B] per-signature validity, exactly
-    matching serial PubKeySr25519.verify_signature per lane. On real TPUs
-    the fused Pallas kernel (tmtpu.tpu.kernel.sr_verify_compact_kernel)
-    runs the whole pipeline in VMEM like the ed25519 path; the plain-XLA
-    graph remains the CPU/virtual-mesh path and the fallback should Mosaic
-    reject the kernel."""
-    B = len(sigs)
-    if B == 0:
-        return np.zeros(0, dtype=bool)
-    faultinject.fire(_FAULT_SR_BATCH)
-    from tmtpu.libs import metrics as _m
-    from tmtpu.tpu import verify as tv
-    from tmtpu.tpu.verify import pad_packed
-
-    t0 = time.perf_counter()
-    with trace.span("sr25519.prepare", lanes=B):
-        packed, host_ok = prepare_sr_batch_packed(pks, msgs, sigs)
-    # breaker replaces the old module _kernel_broken latch: compile
-    # rejections trip permanently, transient faults re-probe after
-    # backoff (policy in tmtpu.tpu.verify.note_pallas_failure)
-    pbr = tv.pallas_breaker("sr25519")
-    if tv.use_pallas_kernel() and pbr.allow():
-        from tmtpu.tpu import kernel as tk
-
-        padded = max(tk.DEFAULT_TILE, tv._pad_to_bucket(B))
-        try:
-            with trace.span("sr25519.execute", impl="pallas",
-                            lanes=B, padded=padded):
-                mask = np.asarray(_sr_kernel_packed_jit(
-                    jnp.asarray(pad_packed(packed, padded))))[:B]
-            pbr.record_success()
-            _m.observe_crypto_batch("sr25519", tv.backend_label(), "pallas",
-                                    B, padded, time.perf_counter() - t0)
-            return mask & host_ok
-        except Exception as e:  # noqa: BLE001
-            tv.note_pallas_failure(pbr, e)
-            import sys
-
-            print(
-                "sr_verify: Pallas kernel "
-                f"{'disabled' if pbr.state != 'closed' else 'failed'}"
-                f" (breaker {pbr.state}): {e!r}",
-                file=sys.stderr)
-    # attribute lookup (not an import-time binding) so tests can pin one
-    # bucket via monkeypatch, same as the ed25519/secp256k1 paths
-    padded = tv._pad_to_bucket(B)
-    with trace.span("sr25519.execute", impl="xla", lanes=B, padded=padded):
-        packed = pad_packed(packed, padded)
-        mask = np.asarray(
-            _sr_verify_packed_jit(jnp.asarray(packed), base_table_f32()))[:B]
-    _m.observe_crypto_batch("sr25519", tv.backend_label(), "xla",
-                            B, padded, time.perf_counter() - t0)
-    return mask & host_ok

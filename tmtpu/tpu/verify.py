@@ -25,20 +25,13 @@ over signatures); see tmtpu.tpu.sharding.
 from __future__ import annotations
 
 import hashlib
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from tmtpu.crypto import ed25519_ref as ref
-from tmtpu.libs import faultinject, trace
 from tmtpu.tpu import curve, fe
-
-# chaos site on the device dispatch boundary (docs/RESILIENCE.md): an
-# injected error/latency here models a failing/hung TPU batch and must
-# surface as breaker accounting + CPU fallback in crypto/batch.py
-_FAULT_ED_BATCH = faultinject.register("tpu.ed25519.batch")
 
 L = ref.L
 WINDOW = curve.WINDOW
@@ -242,18 +235,6 @@ def split_packed(packed):
     return packed[0:32], packed[32:64], packed[64:96], packed[96:128]
 
 
-def pad_packed(packed: np.ndarray, padded: int) -> np.ndarray:
-    """numpy [rows, B] -> [rows, padded], replicating lane 0 (well-formed;
-    pad results are discarded). Row-count agnostic: ed25519/sr25519 pack
-    128 rows, secp256k1 packs 168 (k1_verify.prepare_k1_batch_packed)."""
-    B = packed.shape[1]
-    if padded == B:
-        return packed
-    return np.concatenate(
-        [packed, np.repeat(packed[:, :1], padded - B, axis=1)], axis=1
-    )
-
-
 def prepare_batch_compact(pks, msgs, sigs):
     """Compact host prep: returns ([32, B] uint8 x4 (pk, r, s, h) as jnp
     arrays, host_ok). Thin split over prepare_batch_packed for callers
@@ -276,64 +257,6 @@ def base_table_f32():
     return _BASE_TABLE_F32
 
 
-def use_pallas_kernel() -> bool:
-    """Device-graph implementation choice. The fused Pallas kernel
-    (tmtpu.tpu.kernel) is the production path on real TPUs; the plain-XLA
-    graph remains for CPU/virtual-mesh runs (tests, multichip dryrun),
-    where Mosaic isn't in play and XLA:CPU compiles the scatter form much
-    faster. Override with TMTPU_TPU_IMPL=pallas|xla."""
-    import os
-
-    impl = os.environ.get("TMTPU_TPU_IMPL", "")
-    if impl == "pallas":
-        return True
-    if impl == "xla":
-        return False
-    # a failing jax.devices() surfaces: quietly choosing the XLA graph
-    # would hide a broken runtime behind a tenfold slower, green run
-    return jax.devices()[0].platform == "tpu"
-
-
-# Shared Pallas-fallback latch policy (sr25519 + secp256k1 batch paths):
-# substrings identifying a deterministic compile/lowering rejection —
-# retrying those pays full trace+lowering cost per batch for nothing,
-# while transient runtime faults (device OOM, a preempted runtime)
-# deserve one retry before the per-module latch trips.
-_COMPILE_ERR_MARKERS = ("mosaic", "lowering", "unsupported", "unimplemented",
-                        "cannot lower", "pallas")
-
-
-def is_compile_error(e: Exception) -> bool:
-    if isinstance(e, NotImplementedError):
-        return True
-    s = f"{type(e).__name__}: {e}".lower()
-    return any(m in s for m in _COMPILE_ERR_MARKERS)
-
-
-# Pallas-fallback breakers (one per kernel family, replacing the old
-# module-level _kernel_broken latches): a compile/lowering rejection is
-# deterministic → trip permanently; transient runtime faults open after
-# 2 consecutive failures and RE-PROBE after backoff — the old latch
-# never un-latched, so one bad minute degraded the process to XLA until
-# restart. half_open_probes=1: one good batch re-trusts the kernel.
-PALLAS_BREAKER_DEFAULTS = dict(failure_threshold=2, backoff_base_s=30.0,
-                               backoff_max_s=600.0, half_open_probes=1)
-
-
-def pallas_breaker(curve_name: str):
-    from tmtpu.libs import breaker as _bk
-
-    return _bk.get(f"pallas.{curve_name}", **PALLAS_BREAKER_DEFAULTS)
-
-
-def note_pallas_failure(br, e: Exception) -> None:
-    """Shared failure policy for a Pallas kernel dispatch exception."""
-    if is_compile_error(e):
-        br.trip_permanent(f"{type(e).__name__}: {e}")
-    else:
-        br.record_failure(e)
-
-
 @jax.jit
 def _verify_compact_jit(pk_b, r_b, s_b, h_b, table):
     return verify_core_compact(pk_b, r_b, s_b, h_b, table)
@@ -349,98 +272,3 @@ def _verify_packed_kernel_jit(packed):
     from tmtpu.tpu import kernel as tk
 
     return tk.verify_compact_kernel(*split_packed(packed))
-
-
-def _pad_to_bucket(n: int) -> int:
-    """Round the batch up to a small set of sizes so jit caches stay warm
-    (recompiling per odd batch size would dwarf the verify itself).
-    The floor is 64: every consensus-sized flush (a vote burst, a commit
-    slice) shares ONE compiled shape instead of churning 8/16/32 variants
-    — the pad lanes are microseconds of device time while each extra
-    shape is a fresh multi-second XLA compile. Above that, powers of two
-    up to 4096, then multiples of 2048 (a 10k VoteSet pads to 10240
-    instead of 16384 — padding waste matters more than cache entries at
-    commit-verify scale)."""
-    if n > 4096:
-        return (n + 2047) // 2048 * 2048
-    b = 64
-    while b < n:
-        b *= 2
-    return b
-
-
-def pad_args_to_bucket(args, B: int, padded: int):
-    """Tile each lane array out to the bucket size by replicating lane 0
-    (a known-wellformed lane; pad results are discarded)."""
-    if padded == B:
-        return args
-    return tuple(
-        jnp.concatenate(
-            [a, jnp.repeat(a[..., :1], padded - B, axis=-1)], axis=-1
-        )
-        for a in args
-    )
-
-
-def backend_label() -> str:
-    """The jax device platform for metric labels ('cpu', 'tpu', ...) —
-    only consulted after a dispatch, so the backend is already up."""
-    return jax.devices()[0].platform
-
-
-def batch_verify(pks, msgs, sigs, min_lanes: int = 0) -> np.ndarray:
-    """ed25519 batch verification: returns bool [B] per-signature validity.
-
-    Semantics are exactly per-signature Go-stdlib verify (no batch equation
-    shortcuts — each lane independently checks encode([s]B+[h](-A)) == R, so
-    a mixed batch yields the exact per-lane mask with no re-run).
-
-    ``min_lanes`` pads the flush as if it held at least that many lanes:
-    a caller whose flushes vary in length but must all meet one compiled
-    shape (a blocksync run, crypto/batch.py ``warm_pinned``) gives the
-    longest it makes.
-    """
-    B = len(sigs)
-    if B == 0:
-        return np.zeros(0, dtype=bool)
-    faultinject.fire(_FAULT_ED_BATCH)
-    t0 = time.perf_counter()
-    with trace.span("crypto.batch_verify", curve="ed25519", lanes=B) as sp:
-        with trace.span("ed25519.prepare", lanes=B):
-            packed, host_ok = prepare_batch_packed(pks, msgs, sigs)
-        pbr = pallas_breaker("ed25519")
-        use_kernel = use_pallas_kernel() and pbr.allow()
-        impl = "pallas" if use_kernel else "xla"
-        padded = _pad_to_bucket(max(B, min_lanes))
-        if use_kernel:
-            from tmtpu.tpu import kernel as tk
-
-            padded = max(tk.DEFAULT_TILE, padded)
-        sp.set(impl=impl, padded=padded)
-        with trace.span("ed25519.pad", padded=padded):
-            packed = pad_packed(packed, padded)
-        with trace.span("ed25519.device_put"):
-            dev = jnp.asarray(packed)
-        with trace.span("ed25519.execute", impl=impl):
-            if use_kernel:
-                try:
-                    out = jax.block_until_ready(
-                        _verify_packed_kernel_jit(dev))
-                    pbr.record_success()
-                except Exception as e:  # noqa: BLE001 — kernel fault:
-                    # breaker decides latch-vs-retry, XLA serves THIS batch
-                    note_pallas_failure(pbr, e)
-                    impl = "xla"
-                    sp.set(impl=impl)
-                    out = jax.block_until_ready(
-                        _verify_packed_jit(dev, base_table_f32()))
-            else:
-                out = jax.block_until_ready(
-                    _verify_packed_jit(dev, base_table_f32()))
-        with trace.span("ed25519.readback"):
-            mask = np.asarray(out)[:B]
-    from tmtpu.libs import metrics as _m
-
-    _m.observe_crypto_batch("ed25519", backend_label(), impl, B, padded,
-                            time.perf_counter() - t0)
-    return mask & host_ok

@@ -124,14 +124,16 @@ def curve_measurements(lanes_sr: int, lanes_k1: int, only=None) -> dict:
     generation for the skipped curves is skipped too)."""
     from tmtpu.crypto import secp256k1 as k1
     from tmtpu.crypto import sr25519 as sr
-    from tmtpu.tpu import k1_verify as kv
-    from tmtpu.tpu import sr_verify as srv
+    from tmtpu.tpu import dispatch
+
+    def device(curve):
+        return lambda p, m, s: dispatch.device_verify(curve, p, m, s)[0]
 
     out = {}
     for name, lanes, gen, batch_fn, serial_fn in (
-        ("sr25519", lanes_sr, gen_sr, srv.batch_verify_sr,
+        ("sr25519", lanes_sr, gen_sr, device("sr25519"),
          lambda p, m, s: sr.PubKeySr25519(p).verify_signature(m, s)),
-        ("secp256k1", lanes_k1, gen_k1, kv.batch_verify_k1,
+        ("secp256k1", lanes_k1, gen_k1, device("secp256k1"),
          lambda p, m, s: k1.PubKeySecp256k1(p).verify_signature(m, s)),
         ("mixed", min(lanes_sr, lanes_k1) * 3, gen_mixed,
          _batch_verify_mixed,

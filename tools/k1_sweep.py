@@ -49,7 +49,7 @@ def main():
     from tmtpu.crypto import secp256k1 as k1
     from tmtpu.tpu import k1_kernel as kk
     from tmtpu.tpu import k1_verify as kv
-    from tmtpu.tpu.verify import pad_packed
+    from tmtpu.tpu.dispatch import pad_packed
 
     import math
 
