@@ -88,6 +88,13 @@ def _counter(name, field=None):
     return sum(v[field] if field else v for v in series.values())
 
 
+def _valset_memo():
+    return {f"{kind}.{what}": getattr(
+                metrics, f"types_valset_memo_{kind}").summary_series().get(
+                    f"what={what}", 0)
+            for kind in ("hits", "misses") for what in ("hash", "encode")}
+
+
 # -- the sound chain -----------------------------------------------------------
 
 def test_replay_ends_where_the_reference_does(node_of):
@@ -125,6 +132,7 @@ def test_every_span_and_counter_once_a_block(node_of):
     runs0 = _counter("blocksync_run_blocks", "count")
     in_runs0 = _counter("blocksync_run_blocks", "sum")
     bad0 = _counter("blocksync_bad_blocks")
+    memo0 = _valset_memo()
     node.peer("a", chain)
     node.reactor.on_start()
     node.wait(lambda: node.height == 40, "the replay")
@@ -148,6 +156,13 @@ def test_every_span_and_counter_once_a_block(node_of):
     # the pool held every run whole: 512 blocks of 12 validators fit the
     # run's lanes, so the 41 blocks the peer served at once were one run
     assert runs == 1
+    # the sets' kept bytes: a block, validate_block's two hashes twice and
+    # three of save's four encodings (the new next_validators is the
+    # fourth); a run, the reactor's hash; the chain's first sight of
+    # validators and of next_validators are the only hashes computed
+    memo = {k: v - memo0[k] for k, v in _valset_memo().items()}
+    assert memo == {"hits.hash": 4 * 40 + runs - 2, "misses.hash": 2,
+                    "hits.encode": 3 * 40, "misses.encode": 40}
 
 
 # -- the faults ------------------------------------------------------------------

@@ -13,6 +13,7 @@
 import pytest
 
 from tmtpu.state.state import median_time
+from tmtpu.state.validation import BlockValidationError, validate_block
 from tmtpu.types.block import Block, BlockID, Commit, CommitSig, \
     BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_ABSENT
 from tmtpu.types.priv_validator import MockPV
@@ -21,6 +22,7 @@ from tmtpu.types.vote import PRECOMMIT, ErrVoteConflictingVotes, Vote
 from tmtpu.types.vote_set import VoteSet
 
 from tests.test_types import CHAIN_ID, mk_valset, mk_vote
+from tests.test_valset_memo import _hits, _plain_hash, chain  # noqa: F401
 
 
 # --- intra-batch duplicates --------------------------------------------------
@@ -160,3 +162,33 @@ def test_validate_basic_checks_evidence_hash():
     # header.evidence_hash still binds [ev], but the list is empty
     with pytest.raises(ValueError, match="EvidenceHash"):
         blk2.validate_basic()
+
+
+# --- the header's set hashes against a set that keeps its hash ---------------
+
+
+@pytest.mark.parametrize("which,message", [
+    ("validators", "wrong Block.Header.ValidatorsHash"),
+    ("next_validators", "wrong Block.Header.NextValidatorsHash")])
+def test_validate_block_with_a_warm_memo_refuses_another_set(
+        chain, which, message):
+    for i in range(3):
+        chain.apply([b"w%d=v" % i])
+    block, _ = chain.make_block([b"x=y"])
+    h0 = _hits("hash")
+    validate_block(chain.state, block, verify_backend="cpu")
+    assert _hits("hash") - h0 == 2         # both answered from the memo
+    # a header naming a set that differs in one power
+    other = getattr(chain.state, which).copy()
+    other.validators[1].voting_power += 1
+    field = "validators_hash" if which == "validators" \
+        else "next_validators_hash"
+    setattr(block.header, field, _plain_hash(other))
+    block.fill_header()
+    with pytest.raises(BlockValidationError, match=message):
+        validate_block(chain.state, block, verify_backend="cpu")
+    # and a state whose set moved under a header that names the old one
+    block, _ = chain.make_block([b"x=y"])
+    getattr(chain.state, which).validators[1].voting_power += 1
+    with pytest.raises(BlockValidationError, match=message):
+        validate_block(chain.state, block, verify_backend="cpu")

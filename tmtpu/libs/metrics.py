@@ -682,6 +682,18 @@ crypto_flush_gather_waits = DEFAULT.counter(
     "Consensus receive-loop waits taken to gather a fuller verify "
     "batch (adaptive flush scheduling)")
 
+# The same idea one layer up (types/validator.py): a ValidatorSet keeps its
+# Merkle hash and its protobuf encoding while the content they cover stands.
+types_valset_memo_hits = DEFAULT.counter(
+    "types", "valset_memo_hits_total",
+    "ValidatorSet.hash() / encode() calls answered with kept bytes "
+    "(the content they were computed from is unchanged)",
+    labels=("what",))
+types_valset_memo_misses = DEFAULT.counter(
+    "types", "valset_memo_misses_total",
+    "ValidatorSet.hash() / encode() calls that computed their bytes",
+    labels=("what",))
+
 crypto_device_probe_attempts = DEFAULT.counter(
     "crypto", "device_probe_attempts_total",
     "jax device-backend probe attempts")

@@ -111,6 +111,11 @@ class _StatePB(pb.ProtoMessage):
 
 
 def _state_to_pb(s: State) -> _StatePB:
+    """The message to write, not one to read back: fields 6-8 hold the
+    sets themselves, of which the encoder asks only ``encode()``, so a set
+    whose content this process has encoded before hands over kept bytes
+    (``validators`` and ``last_validators`` are copies of the last block's
+    ``next_validators`` and ``validators``)."""
     from tmtpu.version import BlockProtocol, TMCoreSemVer
 
     return _StatePB(
@@ -123,10 +128,9 @@ def _state_to_pb(s: State) -> _StatePB:
         last_block_height=s.last_block_height,
         last_block_id=s.last_block_id.to_proto(),
         last_block_time=pb.Timestamp.from_unix_nanos(s.last_block_time),
-        next_validators=s.next_validators.to_proto()
-        if s.next_validators else None,
-        validators=s.validators.to_proto() if s.validators else None,
-        last_validators=s.last_validators.to_proto()
+        next_validators=s.next_validators,
+        validators=s.validators,
+        last_validators=s.last_validators
         if s.last_validators and s.last_validators.size() else None,
         last_height_validators_changed=s.last_height_validators_changed,
         consensus_params=s.consensus_params.to_proto(),
@@ -197,7 +201,7 @@ class StateStore:
         self.db.set(_k_state(), _state_to_pb(state).encode())
 
     def _save_validators(self, height: int, vals: ValidatorSet) -> None:
-        self.db.set(_k_validators(height), vals.to_proto().encode())
+        self.db.set(_k_validators(height), vals.encode())
 
     def _save_params(self, height: int, params: ConsensusParams) -> None:
         self.db.set(_k_params(height), params.to_proto().encode())

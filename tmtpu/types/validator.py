@@ -20,6 +20,7 @@ from typing import List, Optional, Tuple
 from tmtpu.crypto.encoding import pubkey_from_proto, pubkey_to_proto
 from tmtpu.crypto.keys import PubKey
 from tmtpu.crypto.merkle import hash_from_byte_slices
+from tmtpu.libs import metrics as _m
 from tmtpu.types import pb
 
 MAX_TOTAL_VOTING_POWER = (1 << 63) // 8  # types/validator_set.go:17
@@ -110,6 +111,11 @@ class ValidatorSet:
         self.validators: List[Validator] = []
         self.proposer: Optional[Validator] = None
         self._total_voting_power = 0
+        # "hash" / "encode" -> (content, bytes) of the last such call: the
+        # bytes are handed back only while the content read at the call
+        # equals the content they were computed from (_kept), so no
+        # mutator has anything to clear
+        self._memo: dict = {}
         if validators:
             self._update_with_change_set(
                 [v.copy() for v in validators], allow_deletes=False
@@ -159,6 +165,9 @@ class ValidatorSet:
         vs.validators = [v.copy() for v in self.validators]
         vs.proposer = self.proposer.copy() if self.proposer else None
         vs._total_voting_power = self._total_voting_power
+        # the copies share each PubKey and address object, so the copy's
+        # content check is identity comparisons until it is mutated
+        vs._memo = dict(self._memo)
         return vs
 
     def validate_basic(self) -> None:
@@ -317,8 +326,39 @@ class ValidatorSet:
 
     # -- hashing / proto ----------------------------------------------------
 
+    def _kept(self, what: str, content, compute) -> bytes:
+        """``compute()``, or the bytes it gave when ``content`` was last
+        what it is now."""
+        memo = self._memo.get(what)
+        if memo is not None and memo[0] == content:
+            _m.types_valset_memo_hits.inc(what=what)
+            return memo[1]
+        _m.types_valset_memo_misses.inc(what=what)
+        value = compute()
+        self._memo[what] = (content, value)
+        return value
+
     def hash(self) -> bytes:
-        return hash_from_byte_slices([v.bytes() for v in self.validators])
+        """Merkle root over the SimpleValidator leaves (validator_set.go:347),
+        kept while the (public key, voting power) sequence it covers is
+        what it was: priorities do not enter it."""
+        return self._kept(
+            "hash", [(v.pub_key, v.voting_power) for v in self.validators],
+            lambda: hash_from_byte_slices(
+                [v.bytes() for v in self.validators]))
+
+    def encode(self) -> bytes:
+        """``to_proto().encode()``, kept while everything those bytes hold
+        is what it was: each validator's address, key, power and priority,
+        the proposer's, the total."""
+        p = self.proposer
+        return self._kept("encode", (
+            self.total_voting_power(),
+            (p.address, p.pub_key, p.voting_power, p.proposer_priority)
+            if p else None,
+            [(v.address, v.pub_key, v.voting_power, v.proposer_priority)
+             for v in self.validators],
+        ), lambda: self.to_proto().encode())
 
     def to_proto(self) -> pb.ValidatorSet:
         return pb.ValidatorSet(
