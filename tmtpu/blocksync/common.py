@@ -19,10 +19,11 @@ from tmtpu.types.part_set import PartSet
 
 BLOCKCHAIN_CHANNEL = 0x40
 
-# The v0 reactor sizes a run in lanes, not blocks: as many blocks as fit
-# this many commit-signature slots (35 at 175 validators, 3 at 2,000, one
-# above 3,072), so that a small and a large validator set both flush one
-# device shape, which the reactor compiles before it asks for a block.
+# The v0 reactor and the sequential light client (light/client.py) size a
+# run in lanes, not blocks: as many blocks as fit this many commit-signature
+# slots (35 at 175 validators, 3 at 2,000, one above 3,072), so that a small
+# and a large validator set both flush one device shape, which the caller
+# compiles (``warm_run``) before it asks for a block.
 # 6,144 is the bucket the old 32-block run of a 175-validator chain
 # padded to (tpu/dispatch.py _pad_to_bucket).
 RUN_LANES = 6144

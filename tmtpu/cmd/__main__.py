@@ -721,26 +721,30 @@ def cmd_light(args) -> int:
     (commands/light.go)."""
     import threading
 
-    from tmtpu.light.client import Client, TrustOptions
+    from tmtpu.crypto import batch as crypto_batch
+    from tmtpu.light.client import TrustOptions, open_client
     from tmtpu.light.provider import HTTPProvider
     from tmtpu.light.proxy import LightProxy
-    from tmtpu.light.store import LightStore
-    from tmtpu.libs.db import SQLiteDB
 
     primary = args.primary.rstrip("/")
     witnesses = [w for w in (args.witnesses or "").split(",") if w]
     home = os.path.expanduser(args.home)
-    os.makedirs(os.path.join(home, "data"), exist_ok=True)
-    store = LightStore(SQLiteDB(os.path.join(home, "data", "light.sqlite")))
-    lc = Client(
-        args.chain_id,
+    # commit checks share crypto/batch.py: the home's [crypto] knobs and
+    # crypto_backend apply, and a device backend gets its compile cache
+    cfg = _load_config(home)
+    crypto_batch.configure(cfg.crypto)
+    crypto_batch.set_default_backend(cfg.base.crypto_backend)
+    if cfg.base.crypto_backend == "sidecar":
+        crypto_batch.configure_sidecar(cfg.sidecar, home=home)
+    crypto_batch.start_backend(cfg.base.crypto_backend, "light")
+    lc = open_client(
+        home, args.chain_id,
         TrustOptions(period_ns=int(args.trusting_period * 1e9),
                      height=args.trusted_height,
                      hash=bytes.fromhex(args.trusted_hash)),
         HTTPProvider(args.chain_id, primary),
-        witnesses=[HTTPProvider(args.chain_id, w) for w in witnesses],
-        store=store,
-    )
+        [HTTPProvider(args.chain_id, w) for w in witnesses],
+        sequential=args.sequential)
     proxy = LightProxy(lc, primary, laddr=args.laddr)
     proxy.start()
     print(f"light proxy for {args.chain_id} listening on {proxy.laddr} "
@@ -1005,6 +1009,9 @@ def main(argv=None) -> int:
     sp.add_argument("--trusting-period", type=float,
                     default=7 * 24 * 3600.0, help="seconds")
     sp.add_argument("--laddr", default="tcp://127.0.0.1:8888")
+    sp.add_argument("--sequential", action="store_true",
+                    help="verify all headers sequentially as opposed to "
+                         "using skipping verification")
     sp.set_defaults(fn=cmd_light)
 
     sp = sub.add_parser("wal2json", help="decode a WAL to JSON lines")

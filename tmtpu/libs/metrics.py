@@ -864,6 +864,29 @@ sidecar_client_up = DEFAULT.gauge(
     "sidecar", "client_up",
     "1 when this process holds a live sidecar connection, else 0")
 
+# --- the light client's metric set (tmtpu/light/client.py) ------------------
+#
+# A session is one verify_light_block call; in sequential mode a run is
+# the stretch of fetched headers whose commits ride one fused verify
+# dispatch, sized in lanes as blocksync's is (blocksync/common.run_shape).
+
+light_blocks_verified = DEFAULT.counter(
+    "light", "blocks_verified_total",
+    "Light blocks verified and saved to the trusted store")
+light_sessions = DEFAULT.counter(
+    "light", "sessions_total",
+    "verify_light_block calls that reached verification (sequential, "
+    "skipping or backwards), whatever their outcome")
+light_run_blocks = DEFAULT.histogram(
+    "light", "run_blocks",
+    "Headers whose commits one fused verify dispatch of a sequential "
+    "session carried",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512))
+light_provider_calls = DEFAULT.counter(
+    "light", "provider_calls_total",
+    "Light blocks asked of a provider, by its role",
+    labels=("role",))
+
 # --- the light-client serving-tier metric set (tmtpu/lightserve/) -----------
 #
 # Server set: written by the lightserve daemon (lightserve/server.py

@@ -8,19 +8,33 @@ divergence.
 
 TPU-first deviation: sequential verification uses
 verifier.verify_adjacent_run — the whole fetched run's commits verify in
-ONE fused batch dispatch instead of the reference's per-hop loop.
+ONE fused batch dispatch instead of the reference's per-hop loop. A run
+is sized in lanes as blocksync's is (blocksync/common.run_shape: as many
+headers as the validator set's size leaves of RUN_LANES), every run's
+dispatch is padded to that one shape, and a sequential client compiles it
+when it is built: no run, first, short or last, meets a shape the process
+has not compiled.
+
+Stricter than the reference, never weaker: a header is trusted only after
+the adjacent checks of light/verifier.go:93, more than 2/3 of its own
+set's power signed it and EVERY for-block signature of its commit verifies
+(VerifyCommitLight stops at 2/3); a session that fails stores nothing.
 """
 
 from __future__ import annotations
 
+import os
 import time
 from typing import List, Optional, Tuple
 
+from tmtpu.blocksync.common import run_shape, warm_run
+from tmtpu.libs import metrics, trace
 from tmtpu.light import provider as prov
 from tmtpu.light import verifier
 from tmtpu.light.store import LightStore
 from tmtpu.light.verifier import (
-    DEFAULT_TRUST_LEVEL, ErrNewValSetCantBeTrusted, LightError,
+    DEFAULT_TRUST_LEVEL, ErrNewValSetCantBeTrusted, ErrVerificationFailed,
+    LightError,
 )
 from tmtpu.types.evidence import LightClientAttackEvidence
 from tmtpu.types.light_block import LightBlock
@@ -90,11 +104,14 @@ class Client:
         self.max_clock_drift_ns = max_clock_drift_ns
         self.pruning_size = pruning_size
         self.backend = backend
-        self.provider_calls = 0  # instrumentation for tests/benchmarks
         self._latest_trusted: Optional[LightBlock] = None
         self._restore_trusted()
         if self._latest_trusted is None:
             self._initialize()
+        if mode == SEQUENTIAL:
+            # the one shape every run of this set flushes, before the
+            # first run's fetch (a no-op off the device backend)
+            warm_run(self._latest_trusted.validator_set, backend)
 
     # -- setup --------------------------------------------------------------
 
@@ -125,7 +142,7 @@ class Client:
         """client.go:1131 — all witnesses must agree on the first header."""
         for w in self.witnesses:
             try:
-                wb = w.light_block(lb.height())
+                wb = self._fetch(w, lb.height())
             except prov.ProviderError:
                 continue
             if wb.header.hash() != lb.header.hash():
@@ -169,6 +186,11 @@ class Client:
     def verify_light_block(self, lb: LightBlock, now_ns: int) -> LightBlock:
         """client.go:558 verifyLightBlock — route to sequential, skipping,
         or backwards verification."""
+        metrics.light_sessions.inc()
+        with trace.span("light.session", height=lb.height()):
+            return self._verify_light_block(lb, now_ns)
+
+    def _verify_light_block(self, lb: LightBlock, now_ns: int) -> LightBlock:
         lb.validate_basic(self.chain_id)
         if self._latest_trusted is None:
             raise LightError("no trusted state")
@@ -188,44 +210,50 @@ class Client:
             raise verifier.ErrOldHeaderExpired(
                 base.header.time + self.trust_options.period_ns, now_ns)
         if self.mode == SEQUENTIAL:
-            trace = self._verify_sequential(base, lb, now_ns)
+            verified = self._verify_sequential(base, lb, now_ns)
         else:
-            trace = self._verify_skipping_against_primary(base, lb, now_ns)
-        self._detect_divergence(trace, now_ns)
-        for b in trace[1:]:
+            verified = self._verify_skipping_against_primary(base, lb,
+                                                             now_ns)
+        with trace.span("light.detect", witnesses=len(self.witnesses)):
+            self._detect_divergence(verified, now_ns)
+        for b in verified[1:]:
             self._update_trusted(b)
         return lb
 
     # -- sequential (client.go:613), fused ----------------------------------
 
-    _RUN_CHUNK = 64  # adjacent headers verified per fused dispatch
-
     def _verify_sequential(self, trusted: LightBlock, target: LightBlock,
                            now_ns: int) -> List[LightBlock]:
-        trace = [trusted]
+        verified = [trusted]
         cur = trusted
         while cur.height() < target.height():
-            hi = min(cur.height() + self._RUN_CHUNK, target.height())
-            run = []
-            for h in range(cur.height() + 1, hi + 1):
-                run.append(target if h == target.height()
-                           else self._from_primary(h))
+            n_blocks, lanes = run_shape(cur.validator_set)
+            hi = min(cur.height() + n_blocks, target.height())
+            run = [target if h == target.height() else self._from_primary(h)
+                   for h in range(cur.height() + 1, hi + 1)]
+            metrics.light_run_blocks.observe(len(run))
             n_ok = verifier.verify_adjacent_run(
                 cur, run, self.trust_options.period_ns, now_ns,
-                self.max_clock_drift_ns, backend=self.backend)
+                self.max_clock_drift_ns, backend=self.backend,
+                min_lanes=lanes)
             if n_ok < len(run):
                 # pinpoint the failing hop for a precise error
                 bad = run[n_ok]
                 prev = run[n_ok - 1] if n_ok > 0 else cur
-                verifier.verify_adjacent(
-                    prev.signed_header, bad.signed_header, bad.validator_set,
-                    self.trust_options.period_ns, now_ns,
-                    self.max_clock_drift_ns, backend=self.backend)
+                try:
+                    verifier.verify_adjacent(
+                        prev.signed_header, bad.signed_header,
+                        bad.validator_set, self.trust_options.period_ns,
+                        now_ns, self.max_clock_drift_ns,
+                        backend=self.backend)
+                except LightError as e:
+                    raise ErrVerificationFailed(prev.height(), bad.height(),
+                                                e) from e
                 raise LightError(   # fused and precise paths disagree
                     f"run verification failed at height {bad.height()}")
-            trace.extend(run)
+            verified.extend(run)
             cur = run[-1]
-        return trace
+        return verified
 
     # -- skipping / bisection (client.go:706) --------------------------------
 
@@ -295,7 +323,7 @@ class Client:
         evidence: List[LightClientAttackEvidence] = []
         for wi, w in enumerate(self.witnesses):
             try:
-                wb = w.light_block(last.height())
+                wb = self._fetch(w, last.height())
             except prov.ProviderError:
                 continue
             if wb.header.hash() == last.header.hash():
@@ -351,25 +379,44 @@ class Client:
     # -- internals -----------------------------------------------------------
 
     def _update_trusted(self, lb: LightBlock, prune: bool = True) -> None:
-        self.store.save_light_block(lb)
-        if self._latest_trusted is None or \
-                lb.height() > self._latest_trusted.height():
-            self._latest_trusted = lb
-        if prune and self.pruning_size and \
-                self.store.size() > self.pruning_size:
-            self.store.prune(self.pruning_size)
+        with trace.span("light.store", height=lb.height()):
+            self.store.save_light_block(lb)
+            if self._latest_trusted is None or \
+                    lb.height() > self._latest_trusted.height():
+                self._latest_trusted = lb
+            if prune and self.pruning_size and \
+                    self.store.size() > self.pruning_size:
+                self.store.prune(self.pruning_size)
+        metrics.light_blocks_verified.inc()
 
     def _from_primary(self, height: Optional[int]) -> LightBlock:
         return self._fetch(self.primary, height)
 
     def _fetch(self, source: prov.Provider,
                height: Optional[int]) -> LightBlock:
-        self.provider_calls += 1
-        lb = source.light_block(height)
+        metrics.light_provider_calls.inc(
+            role="primary" if source is self.primary else "witness")
+        with trace.span("light.fetch"):
+            lb = source.light_block(height)
         if height is not None and lb.height() != height:
             raise prov.ErrBadLightBlock(
                 f"expected height {height}, got {lb.height()}")
         return lb
+
+
+def open_client(home: str, chain_id: str, trust_options: TrustOptions,
+                primary: prov.Provider, witnesses: List[prov.Provider],
+                sequential: bool = False) -> Client:
+    """The client ``tmtpu light`` runs (commands/light.go): its trusted
+    store on SQLite under ``<home>/data/light.sqlite``, sequential
+    verification when the operator asked for it and skipping otherwise,
+    everything else at the defaults. The providers are the caller's."""
+    from tmtpu.libs.db import SQLiteDB
+
+    os.makedirs(os.path.join(home, "data"), exist_ok=True)
+    store = LightStore(SQLiteDB(os.path.join(home, "data", "light.sqlite")))
+    return Client(chain_id, trust_options, primary, witnesses=witnesses,
+                  store=store, mode=SEQUENTIAL if sequential else SKIPPING)
 
 
 def _new_attack_evidence(conflicted: LightBlock, trusted: LightBlock,

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
+from tmtpu.libs import trace
 from tmtpu.types import commit_verify
 from tmtpu.types.light_block import LightBlock, SignedHeader
 from tmtpu.types.validator import ValidatorSet
@@ -46,6 +47,18 @@ class ErrNewValSetCantBeTrusted(LightError):
 
     def __init__(self, reason):
         super().__init__(f"cant trust new val set: {reason}")
+        self.reason = reason
+
+
+class ErrVerificationFailed(LightError):
+    """light/errors.go ErrVerificationFailed — the hop ``from_height`` →
+    ``to_height`` was refused for ``reason`` (the hop's own error)."""
+
+    def __init__(self, from_height: int, to_height: int, reason):
+        super().__init__(f"verify from #{from_height} to #{to_height} "
+                         f"failed: {reason}")
+        self.from_height = from_height
+        self.to_height = to_height
         self.reason = reason
 
 
@@ -189,40 +202,45 @@ def verify_backwards(untrusted: SignedHeader, trusted: SignedHeader) -> None:
 def verify_adjacent_run(trusted: LightBlock, run: List[LightBlock],
                         trusting_period_ns: int, now_ns: int,
                         max_clock_drift_ns: int,
-                        backend: Optional[str] = None) -> int:
+                        backend: Optional[str] = None,
+                        min_lanes: int = 0) -> int:
     """Verify a run of ADJACENT light blocks after ``trusted`` with a single
     fused signature dispatch (new vs the reference's per-hop loop in
-    light/client.go:613 verifySequential). Returns the number of verified
-    blocks from the front of the run; structural failure or a bad commit at
-    position i leaves 0..i-1 verified, matching what a caller can commit.
+    light/client.go:613 verifySequential), padded as if it held
+    ``min_lanes`` signatures (0: by its own length). Returns the number of
+    verified blocks from the front of the run; structural failure or a bad
+    commit at position i leaves 0..i-1 verified, matching what a caller can
+    commit.
     """
     if not run:
         return 0
     prev = trusted
     entries = []
-    checked = 0
-    for lb in run:
-        try:
-            if lb.height() != prev.height() + 1:
-                raise LightError("headers must be adjacent in height")
-            if header_expired(prev.signed_header, trusting_period_ns, now_ns):
-                raise ErrOldHeaderExpired(
-                    prev.header.time + trusting_period_ns, now_ns)
-            _verify_new_header_and_vals(
-                lb.signed_header, lb.validator_set, prev.signed_header,
-                now_ns, max_clock_drift_ns)
-            if lb.header.validators_hash != \
-                    prev.header.next_validators_hash:
-                raise LightError("next validators hash mismatch")
-        except (LightError, ValueError):
-            break
-        entries.append((lb.validator_set, prev.header.chain_id,
-                        lb.commit.block_id, lb.height(), lb.commit))
-        prev = lb
-        checked += 1
+    with trace.span("light.check", blocks=len(run)):
+        for lb in run:
+            try:
+                if lb.height() != prev.height() + 1:
+                    raise LightError("headers must be adjacent in height")
+                if header_expired(prev.signed_header, trusting_period_ns,
+                                  now_ns):
+                    raise ErrOldHeaderExpired(
+                        prev.header.time + trusting_period_ns, now_ns)
+                _verify_new_header_and_vals(
+                    lb.signed_header, lb.validator_set, prev.signed_header,
+                    now_ns, max_clock_drift_ns)
+                if lb.header.validators_hash != \
+                        prev.header.next_validators_hash:
+                    raise LightError("next validators hash mismatch")
+            except (LightError, ValueError):
+                break
+            entries.append((lb.validator_set, prev.header.chain_id,
+                            lb.commit.block_id, lb.height(), lb.commit))
+            prev = lb
     if not entries:
         return 0
-    errs = commit_verify.verify_commits_light_batch(entries, backend=backend)
+    with trace.span("light.verify_run", blocks=len(entries)):
+        errs = commit_verify.verify_commits_light_batch(
+            entries, backend=backend, min_lanes=min_lanes)
     ok = 0
     for e in errs:
         if e is not None:
