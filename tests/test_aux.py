@@ -164,6 +164,24 @@ def test_abci_cli_one_shots(tmp_path, capsys):
         srv.stop()
 
 
+_L = 2**252 + 27742317777372353535851937790883648493
+
+
+def _native_prep_rows(pk, r, s, msgs, **kw):
+    """native.prep_ed25519 read back lane-major: (pk, r, s, h rows [B, 32],
+    s_ok, the SHA-512 that ran)."""
+    from tmtpu import native
+
+    B = pk.shape[0]
+    plane = np.full((128, B + 3), 0xA5, dtype=np.uint8)
+    sig = np.ascontiguousarray(np.concatenate([r, s], axis=1))
+    s_ok, sha = native.prep_ed25519(pk, sig, msgs, plane, **kw)
+    assert (plane[:, B:] == 0xA5).all()  # columns B.. are the caller's
+    rows = [np.ascontiguousarray(plane[32 * k:32 * k + 32, :B].T)
+            for k in range(4)]
+    return rows, s_ok, sha
+
+
 def test_native_hostprep_differential():
     import hashlib
 
@@ -176,19 +194,83 @@ def test_native_hostprep_differential():
     pk = rng.integers(0, 256, (B, 32), dtype=np.uint8)
     r = rng.integers(0, 256, (B, 32), dtype=np.uint8)
     s = rng.integers(0, 256, (B, 32), dtype=np.uint8)
-    L = 2**252 + 27742317777372353535851937790883648493
+    L = _L
     # adversarial s lanes: L-1, L, L+1, 2^256-1, 0
     for j, v in enumerate([L - 1, L, L + 1, 2**256 - 1, 0]):
         s[j] = np.frombuffer(v.to_bytes(32, "little"), dtype=np.uint8)
     msgs = [rng.integers(0, 256, int(n), dtype=np.uint8).tobytes()
             for n in rng.integers(0, 400, B)]
     msgs[0] = b""  # empty message edge
-    h, sok = native.prep_ed25519(pk, r, s, msgs)
+    (pk_o, r_o, s_o, h), sok, _sha = _native_prep_rows(pk, r, s, msgs)
+    assert np.array_equal(pk_o, pk) and np.array_equal(r_o, r)
     for i in range(B):
         d = hashlib.sha512(r[i].tobytes() + pk[i].tobytes() + msgs[i])
         want = (int.from_bytes(d.digest(), "little") % L)
         assert h[i].tobytes() == want.to_bytes(32, "little"), i
         assert sok[i] == (int.from_bytes(s[i].tobytes(), "little") < L), i
+        # a lane the host refuses carries s = 0 to the device
+        assert s_o[i].tobytes() == (s[i].tobytes() if sok[i]
+                                    else bytes(32)), i
+
+
+@pytest.mark.parametrize("nthreads", [1, 3])
+@pytest.mark.parametrize("sha", ["libcrypto", "portable"])
+def test_native_hostprep_sha_block_edges(sha, nthreads):
+    """R||A is 64 bytes, so these message lengths straddle SHA-512's
+    padding edge (111/112 bytes in the last block) and its 128-byte
+    blocks; every SHA-512 the build has gives hashlib's digest, in one
+    thread and cut over several."""
+    import hashlib
+
+    from tmtpu import native
+
+    if sha not in native.sha_impls():
+        pytest.skip(f"no {sha} SHA-512 on this host")
+    lens = [0, 1, 15, 16, 47, 48, 63, 64, 175, 176, 1000]
+    rng = np.random.default_rng(33)
+    # three lanes a length, and enough lanes that three threads get a tile
+    lens = lens * 3 + [110] * 160
+    B = len(lens)
+    pk = rng.integers(0, 256, (B, 32), dtype=np.uint8)
+    r = rng.integers(0, 256, (B, 32), dtype=np.uint8)
+    s = rng.integers(0, 256, (B, 32), dtype=np.uint8)
+    msgs = [rng.integers(0, 256, n, dtype=np.uint8).tobytes() for n in lens]
+    (_pk, _r, _s, h), sok, ran = _native_prep_rows(
+        pk, r, s, msgs, nthreads=nthreads, sha=sha)
+    assert ran == sha
+    for i in range(B):
+        d = hashlib.sha512(r[i].tobytes() + pk[i].tobytes() + msgs[i])
+        want = int.from_bytes(d.digest(), "little") % _L
+        assert h[i].tobytes() == want.to_bytes(32, "little"), (i, lens[i])
+        assert sok[i] == (int.from_bytes(s[i].tobytes(), "little") < _L), i
+
+
+def test_native_hostprep_refuses_bad_buffers():
+    """Sizes, dtype and contiguity are checked before a pointer goes to C."""
+    from tmtpu import native
+
+    if native.load() is None:
+        pytest.skip("no C toolchain")
+    pk = np.zeros((4, 32), dtype=np.uint8)
+    sig = np.zeros((4, 64), dtype=np.uint8)
+    msgs = [b"m"] * 4
+    ok = np.zeros((128, 4), dtype=np.uint8)
+    for bad_pk, bad_sig, bad_plane in (
+        (pk[:, :31], sig, ok),
+        (pk, sig[:3], ok),
+        (pk, sig, np.zeros((128, 3), dtype=np.uint8)),
+        (pk, sig, np.zeros((64, 4), dtype=np.uint8)),
+        (pk, sig, np.zeros((128, 8), dtype=np.uint8)[:, ::2]),
+        (pk.astype(np.int8), sig, ok),
+    ):
+        with pytest.raises(ValueError):
+            native.prep_ed25519(bad_pk, bad_sig, msgs, bad_plane)
+    with pytest.raises(ValueError):
+        native.prep_ed25519(pk, sig, msgs, ok, sha="md5")
+    # a lane whose len() is not its byte count would shift every offset
+    with pytest.raises(ValueError):
+        native.prep_ed25519(pk, sig, [memoryview(np.zeros(2, np.uint16))] * 4,
+                            ok)
 
 
 def test_step_transitions_observe_durations():

@@ -93,15 +93,143 @@ def test_mesh_tally_bit_exact(mesh4):
 
 
 def test_padding_lanes_never_enter_the_tally(mesh4):
-    """pad_packed replicates lane 0's BYTES into the pad lanes, so they
-    VERIFY true on device — only their zeroed power limbs keep them out
-    of the psum. 33 lanes pad to 128 on a 4-device mesh: 95 potential
+    """A row's prepare replicates lane 0's BYTES into the pad lanes, so
+    they VERIFY true on device — only their zeroed power limbs keep them
+    out of the psum. 33 lanes pad to 128 on a 4-device mesh: 95 potential
     phantom contributions if the zeroing slips."""
     pks, msgs, sigs, powers = _ed_batch(33, b"mesh-pad")
     mask, tally = dispatch.device_verify("ed25519", pks, msgs, sigs, powers)
     assert md.dispatch_count() == 1
     assert len(mask) == 33 and bool(np.all(mask))
     assert tally == sum(powers)
+
+
+def _prepare_then_pad(pks, msgs, sigs, padded):
+    """Host prep as it was until PR 33, the reference: bytes() a lane, the
+    lengths a lane, hashlib and Python ints for h, four transposed copies,
+    then pad_packed's concatenate."""
+    import hashlib
+
+    B = len(sigs)
+    pks_b = [bytes(p) for p in pks]
+    sigs_b = [bytes(s) for s in sigs]
+    len_ok = np.array([len(pks_b[i]) == 32 and len(sigs_b[i]) == 64
+                       for i in range(B)], dtype=bool)
+    pks_b = [p if ok else bytes(32) for p, ok in zip(pks_b, len_ok)]
+    sigs_b = [s if ok else bytes(64) for s, ok in zip(sigs_b, len_ok)]
+    host_ok = len_ok & np.array(
+        [int.from_bytes(s[32:], "little") < ref.L for s in sigs_b])
+    packed = np.zeros((128, B), dtype=np.uint8)
+    for i, (p, s, m) in enumerate(zip(pks_b, sigs_b, msgs)):
+        h = int.from_bytes(hashlib.sha512(s[:32] + p + bytes(m)).digest(),
+                           "little") % ref.L
+        lane = p + s[:32] + (s[32:] if host_ok[i] else bytes(32)) \
+            + h.to_bytes(32, "little")
+        packed[:, i] = np.frombuffer(lane, dtype=np.uint8)
+        y = int.from_bytes(p, "little") & ((1 << 255) - 1)
+        host_ok[i] &= y < ref.P
+    return dispatch.pad_packed(packed, padded), host_ok
+
+
+def _lanes_with_every_host_refusal(B, seed, as_type):
+    """B signed lanes; where B allows, one each of: a short key, a long
+    signature, s = L, s = 2^256 - 1, A.y = p (non-canonical) and A.y = p
+    with the sign bit set. ``as_type`` wraps every lane's three fields."""
+    pks, msgs, sigs, _ = _ed_batch(B, b"prep-%d" % seed)
+    msgs = [m * (1 + i % 5) for i, m in enumerate(msgs)]
+    msgs[0] = b""
+    bad_y = ref.P.to_bytes(32, "little")
+    edits = [
+        lambda i: pks.__setitem__(i, pks[i][:31]),
+        lambda i: sigs.__setitem__(i, sigs[i] + b"\x00"),
+        lambda i: sigs.__setitem__(
+            i, sigs[i][:32] + ref.L.to_bytes(32, "little")),
+        lambda i: sigs.__setitem__(i, sigs[i][:32] + b"\xff" * 32),
+        lambda i: pks.__setitem__(i, bad_y),
+        lambda i: pks.__setitem__(i, bad_y[:31] + bytes([bad_y[31] | 0x80])),
+    ]
+    # lane 0 stays good where it can: it is the lane the pad replicates
+    for k, edit in enumerate(edits):
+        if B > 1 and 1 + 2 * k < B:
+            edit(1 + 2 * k)
+    if B == 1:
+        edits[seed % len(edits)](0)
+    return ([as_type(p) for p in pks], [as_type(m) for m in msgs],
+            [as_type(s) for s in sigs])
+
+
+@pytest.mark.parametrize("as_type", [bytes, bytearray, memoryview],
+                         ids=["bytes", "bytearray", "memoryview"])
+@pytest.mark.parametrize("bucket", [False, True], ids=["at-B", "at-bucket"])
+@pytest.mark.parametrize("B", [1, 63, 64, 167, 300])
+def test_prepare_at_padded_equals_prepare_then_pad(B, bucket, as_type,
+                                                   monkeypatch):
+    """The operand a flush now builds in one pass, at the padded width,
+    is byte for byte the one it used to build and then pad — through the
+    native library and through the numpy/hashlib path — and refuses the
+    same lanes."""
+    from tmtpu.tpu import verify as tv
+
+    padded = dispatch.padded_lanes(B + 1 if bucket and B == 64 else B) \
+        if bucket else B
+    pks, msgs, sigs = _lanes_with_every_host_refusal(B, B, as_type)
+    want, want_ok = _prepare_then_pad(pks, msgs, sigs, padded)
+    assert B == 1 or not want_ok.all()
+    for no_native in ("", "1"):
+        monkeypatch.setenv("TMTPU_NO_NATIVE", no_native)
+        got, got_ok = tv.prepare_batch_packed(pks, msgs, sigs, padded)
+        assert got.dtype == np.uint8 and got.shape == (128, padded)
+        assert np.array_equal(got_ok, want_ok)
+        assert np.array_equal(got, want)
+    # no width given: the lanes alone, as every other caller takes them
+    got, got_ok = tv.prepare_batch_packed(pks, msgs, sigs)
+    assert np.array_equal(got, want[:, :B]) and np.array_equal(got_ok,
+                                                               want_ok)
+
+
+@pytest.mark.parametrize("curve", list(dispatch.CURVES))
+def test_pad_lanes_replicate_lane_0_with_zero_power_limbs(curve,
+                                                          monkeypatch):
+    """For every row of CURVES a flush hands its step the operand at the
+    padded width with lanes >= B replicating lane 0, and — where it carries
+    power limbs — zero limbs there and under every host-refused lane."""
+    import dataclasses
+
+    from tmtpu.tpu import sharding as sh
+
+    row = dispatch.CURVES[curve]
+    B, seen = 40, {}
+    rng = np.random.default_rng(7)
+    klen = 33 if curve == "secp256k1" else 32
+    pks = [rng.integers(0, 256, klen, dtype=np.uint8).tobytes()
+           for _ in range(B)]
+    sigs = [rng.integers(0, 256, 64, dtype=np.uint8).tobytes()
+            for _ in range(B)]
+    sigs[3] = sigs[3][:63]  # one lane the host refuses on every curve
+    msgs = [b"pad-lane msg %d" % i for i in range(B)]
+    powers = [1000 + i for i in range(B)]
+
+    def step(packed, limbs, *table):
+        seen["packed"], seen["limbs"] = np.asarray(packed), np.asarray(limbs)
+        return (np.ones(packed.shape[1], dtype=bool),
+                np.zeros(sh.POWER_LIMBS, dtype=np.int32), None)
+
+    fake = dataclasses.replace(row, tally_kernel=step, tally_xla=step,
+                               table=lambda: None)
+    monkeypatch.setenv("TMTPU_TPU_IMPL", "xla")
+    mask, _tally = dispatch._flush(fake, pks, msgs, sigs, powers, 0, None)
+    alone, host_ok = row.prepare(pks, msgs, sigs)
+    padded = dispatch.padded_lanes(B)
+    packed, limbs = seen["packed"], seen["limbs"]
+    assert padded > B and packed.shape == (alone.shape[0], padded)
+    assert np.array_equal(packed[:, :B], alone)
+    assert np.array_equal(packed[:, B:],
+                          np.repeat(alone[:, :1], padded - B, axis=1))
+    assert limbs.shape == (sh.POWER_LIMBS, padded) and not limbs[:, B:].any()
+    assert not host_ok[3] and not mask[3]
+    assert np.array_equal(
+        limbs[:, :B],
+        sh.powers_to_limbs([p if ok else 0 for p, ok in zip(powers, host_ok)]))
 
 
 def test_route_threshold_and_mesh_off(mesh4, monkeypatch):

@@ -14,6 +14,53 @@ def test_power_limbs_roundtrip():
     assert sh.limb_sums_to_int(sums) == sum(powers)
 
 
+def _powers_to_limbs_loop(powers) -> np.ndarray:
+    """The double loop powers_to_limbs was until PR 33: the reference."""
+    out = np.zeros((sh.POWER_LIMBS, len(powers)), dtype=np.int32)
+    for i, p in enumerate(powers):
+        v = int(p)
+        for j in range(sh.POWER_LIMBS):
+            out[j, i] = v & ((1 << sh.POWER_RADIX) - 1)
+            v >>= sh.POWER_RADIX
+        assert v == 0, "voting power exceeds 65 bits"
+    return out
+
+
+_EDGE_POWERS = [0, 1, 2**13 - 1, 2**13, 2**62, 2**63 - 1]
+
+
+@pytest.mark.parametrize("powers", [
+    *([v] for v in _EDGE_POWERS),
+    _EDGE_POWERS,
+    np.asarray(_EDGE_POWERS, dtype=np.int64),
+    np.random.default_rng(33).integers(0, 2**53, 9500, dtype=np.int64),
+    [],
+], ids=[*(f"one-{v:#x}" for v in _EDGE_POWERS), "list", "int64-array",
+        "9500-drawn", "empty"])
+def test_power_limbs_equal_the_loop(powers):
+    limbs = sh.powers_to_limbs(powers)
+    assert limbs.dtype == np.int32
+    assert limbs.shape == (sh.POWER_LIMBS, len(powers))
+    assert np.array_equal(limbs, _powers_to_limbs_loop(powers))
+    # the column sums (what the device reduces) give back the total
+    sums = limbs.astype(np.int64).sum(axis=1)
+    assert sh.limb_sums_to_int(sums) == sum(int(p) for p in powers)
+    # written in place into the first lanes of a wider operand
+    wide = np.zeros((sh.POWER_LIMBS, len(powers) + 7), dtype=np.int32)
+    sh.powers_to_limbs(powers, out=wide[:, :len(powers)])
+    assert np.array_equal(wide[:, :len(powers)], limbs)
+    assert not wide[:, len(powers):].any()
+
+
+@pytest.mark.parametrize("powers", [
+    [5, -1], np.asarray([-2**63], dtype=np.int64), [2**65], [1, 2**66],
+    np.asarray([2**63], dtype=np.uint64),
+], ids=["negative", "int64-min", "65-bits", "66-bits", "uint64-top-bit"])
+def test_power_limbs_refuse_what_no_int64_power_is(powers):
+    with pytest.raises(ValueError):
+        sh.powers_to_limbs(powers)
+
+
 def test_dryrun_multichip_8():
     pytest.importorskip("cryptography")  # dryrun's vote-gen oracle
     import __graft_entry__ as ge

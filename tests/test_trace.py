@@ -51,6 +51,25 @@ def test_span_set_attrs_mid_region():
     assert tr.snapshot()[0].attrs == {"lanes": 42, "impl": "xla"}
 
 
+def test_annotate_reaches_the_innermost_open_span_only():
+    """A callee's way to say something about the stage its caller times:
+    the innermost span open on this thread takes the attrs; with none
+    open, or tracing off, nothing happens."""
+    tr = trace.Tracer()
+    tr.annotate(lost=1)  # no span open
+    with tr.span("outer"):
+        with tr.span("inner"):
+            tr.annotate(impl="native", sha="libcrypto")
+        tr.annotate(after=True)
+    by_name = {s.name: s.attrs for s in tr.snapshot()}
+    assert by_name == {"inner": {"impl": "native", "sha": "libcrypto"},
+                       "outer": {"after": True}}
+    tr.set_enabled(False)
+    with tr.span("ghost"):
+        tr.annotate(a=1)
+    assert len(tr.snapshot()) == 2
+
+
 def test_span_error_flag_propagates():
     tr = trace.Tracer()
     try:
@@ -206,6 +225,14 @@ def test_batch_verify_emits_phase_spans():
     by_id = {s.span_id: s for s in spans}
     root = next(s for s in spans if s.name == "crypto.batch_verify")
     assert root.attrs["lanes"] == 8
+    # the prepare says which host prep and which SHA-512 fed the device
+    from tmtpu import native
+
+    prep = next(s for s in spans if s.name == "ed25519.prepare")
+    how = {"impl": "native", "sha": native.sha_impls()[0]} \
+        if native.load() is not None else \
+        {"impl": "python", "sha": "hashlib"}
+    assert prep.attrs == {"lanes": 8, **how}
     for s in spans:
         assert s.duration_s >= 0.0
         if s.parent_id is not None and s.parent_id in by_id:

@@ -343,6 +343,14 @@ class Tracer:
                 tot[0] += 1
                 tot[1] += sp.end_s - sp.start_s
 
+    def annotate(self, **attrs) -> None:
+        """Attach attrs to the innermost span open on this thread (none
+        open: nothing happens) — for a callee that learns something about
+        the stage its caller is timing, e.g. which implementation ran."""
+        stack = self._stack()
+        if stack:
+            stack[-1].set(**attrs)
+
     def traced(self, name: Optional[str] = None):
         """Decorator form: the whole call body becomes one span."""
 
@@ -582,6 +590,10 @@ DEFAULT = Tracer()
 
 def span(name: str, **attrs):
     return DEFAULT.span(name, **attrs)
+
+
+def annotate(**attrs) -> None:
+    DEFAULT.annotate(**attrs)
 
 
 def traced(name: Optional[str] = None):

@@ -1,10 +1,15 @@
 """Native (C) host-side helpers for the TPU crypto pipeline.
 
-``hostprep`` — batched SHA-512 challenge hashing + mod-L reduction + the
-canonical-s check, the host half of ed25519 batch verification (the device
-half is tmtpu/tpu/kernel.py). Reference semantics:
-crypto/ed25519/ed25519.go:148-155 (h = SHA-512(R||A||M)) and scMinimal
-(s < L); spec oracle tmtpu/crypto/ed25519_ref.py.
+``hostprep`` — the host half of ed25519 batch verification (the device
+half is tmtpu/tpu/kernel.py) in one C call a flush: SHA-512 challenge
+hashing + mod-L reduction + the canonical-s check over the flush's keys,
+signatures and messages as three contiguous buffers, every lane written
+straight into the byte planes of the padded device operand
+(``prep_ed25519``). Reference semantics: crypto/ed25519/ed25519.go:148-155
+(h = SHA-512(R||A||M)) and scMinimal (s < L); spec oracle
+tmtpu/crypto/ed25519_ref.py. The SHA-512 is the system libcrypto's where
+the library finds one at run time (about twice the portable C, which
+stays as the path without it: ``sha_impls``).
 
 The library is built lazily with the system C compiler (cc -O2 -shared
 -pthread) into this directory and loaded over ctypes; when no toolchain is
@@ -102,15 +107,18 @@ def _load_and_bind():
         lib.tmtpu_prep_ed25519.argtypes = [
             ctypes.c_size_t,
             ctypes.c_void_p,  # pks  n*32
-            ctypes.c_void_p,  # rs   n*32
-            ctypes.c_void_p,  # ss   n*32
+            ctypes.c_void_p,  # sigs n*64
             ctypes.c_void_p,  # msgs concatenated
             ctypes.c_void_p,  # moff n+1 uint64
-            ctypes.c_void_p,  # h_out n*32
+            ctypes.c_void_p,  # plane 128*stride
+            ctypes.c_size_t,  # stride
             ctypes.c_void_p,  # s_ok  n
             ctypes.c_int,     # nthreads
+            ctypes.c_int,     # portable_sha
         ]
-        lib.tmtpu_prep_ed25519.restype = None
+        lib.tmtpu_prep_ed25519.restype = ctypes.c_int
+        lib.tmtpu_sha512_libcrypto.argtypes = []
+        lib.tmtpu_sha512_libcrypto.restype = ctypes.c_int
         lib.tmtpu_sr_challenges.argtypes = [
             ctypes.c_size_t,
             ctypes.c_void_p,  # pks  n*32
@@ -149,41 +157,69 @@ def _load_and_bind():
 
 def _pack_msgs(msgs, B):
     """(offsets [B+1] uint64, concatenated uint8 buffer) for a message list
-    — the shared wire layout both batch entry points hand to C."""
+    — the shared wire layout both batch entry points hand to C. One
+    ``len`` pass and one join, whatever bytes-like type the lanes are."""
     moff = np.zeros(B + 1, dtype=np.uint64)
-    lens = np.fromiter((len(m) for m in msgs), dtype=np.uint64, count=B)
-    np.cumsum(lens, out=moff[1:])
-    blob = b"".join(bytes(m) for m in msgs)
+    np.cumsum(np.fromiter(map(len, msgs), dtype=np.uint64, count=B),
+              out=moff[1:])
+    blob = b"".join(msgs)
+    if len(blob) != int(moff[B]):  # C indexes the blob by these offsets
+        raise ValueError("a message's len() is not its size in bytes")
     msgs_buf = np.frombuffer(blob, dtype=np.uint8) if blob else \
         np.zeros(1, dtype=np.uint8)
     return moff, msgs_buf
 
 
-def prep_ed25519(pk_arr: np.ndarray, r_arr: np.ndarray, s_arr: np.ndarray,
-                 msgs, nthreads: int | None = None):
-    """Batched h = SHA-512(R||A||M) mod L and s < L.
+SHA_IMPLS = ("libcrypto", "portable")
 
-    pk_arr/r_arr/s_arr: [B, 32] uint8 C-contiguous; msgs: list of bytes.
-    Returns (h_arr [B, 32] uint8, s_ok bool [B]) or None when the native
-    library is unavailable.
+
+def sha_impls():
+    """The SHA-512 implementations ``prep_ed25519`` can run on this host,
+    the one it takes by default first; () without the library."""
+    lib = load()
+    if lib is None:
+        return ()
+    return SHA_IMPLS if lib.tmtpu_sha512_libcrypto() else SHA_IMPLS[1:]
+
+
+def prep_ed25519(pk_arr: np.ndarray, sig_arr: np.ndarray, msgs,
+                 plane: np.ndarray, nthreads: int | None = None,
+                 sha: str | None = None):
+    """One flush's lanes into its device operand: for lane i, column i of
+    ``plane`` gets pk (rows 0-31), R (32-63), s (64-95) and
+    h = SHA-512(R||A||M) mod L (96-127); a lane whose s >= L gets s = 0.
+
+    pk_arr [B, 32], sig_arr [B, 64] (R||s) uint8 C-contiguous; msgs: B
+    bytes-like objects; plane: uint8 [128, W >= B] C-contiguous, columns
+    B.. are left alone. ``sha`` names one of ``sha_impls()`` (tests; the
+    default is the first). Returns (s_ok bool [B], the SHA-512 that ran),
+    or None when the native library is unavailable.
     """
     lib = load()
     if lib is None:
         return None
     B = pk_arr.shape[0]
+    for arr, shape in ((pk_arr, (B, 32)), (sig_arr, (B, 64))):
+        if arr.shape != shape or arr.dtype != np.uint8 \
+                or not arr.flags.c_contiguous:
+            raise ValueError(f"want C-contiguous uint8 {shape}")
+    if plane.ndim != 2 or plane.shape[0] != 128 or plane.shape[1] < B \
+            or plane.dtype != np.uint8 or not plane.flags.c_contiguous \
+            or not plane.flags.writeable:
+        raise ValueError("want a writeable C-contiguous uint8 [128, >=B]")
+    if sha is not None and sha not in sha_impls():
+        raise ValueError(f"no {sha!r} SHA-512 on this host")
     if nthreads is None:
         nthreads = min(8, os.cpu_count() or 1)
     moff, msgs_buf = _pack_msgs(msgs, B)
-    h_out = np.empty((B, 32), dtype=np.uint8)
     s_ok = np.empty(B, dtype=np.uint8)
-    lib.tmtpu_prep_ed25519(
-        B,
-        pk_arr.ctypes.data, r_arr.ctypes.data, s_arr.ctypes.data,
+    used = lib.tmtpu_prep_ed25519(
+        B, pk_arr.ctypes.data, sig_arr.ctypes.data,
         msgs_buf.ctypes.data, moff.ctypes.data,
-        h_out.ctypes.data, s_ok.ctypes.data,
-        int(nthreads),
+        plane.ctypes.data, plane.shape[1], s_ok.ctypes.data,
+        int(nthreads), int(sha == "portable"),
     )
-    return h_out, s_ok.astype(bool)
+    return s_ok.view(bool), SHA_IMPLS[0 if used else 1]
 
 
 def sr_challenges(pk_arr: np.ndarray, r_arr: np.ndarray, msgs,

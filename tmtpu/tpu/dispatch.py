@@ -1,12 +1,13 @@
 """The device dispatch, written once: one table, one function.
 
 A flush is the same seven steps on every curve, for the mask and for the
-fused verify+tally step, on one chip and on the mesh: host prep → the
-padded shape → Pallas or the XLA graph → pad → one transfer → execute →
-read back and ``observe_crypto_batch``. ``CURVES`` has one row a curve
-naming what differs (host prep, the jitted steps, the kernel's tile
-floor, the sharded builders, the chaos site); ``device_verify`` is the
-only way production code reaches the device, called from one place
+fused verify+tally step, on one chip and on the mesh: Pallas or the XLA
+graph → the padded shape → host prep, straight into the operand at that
+width → a fused flush's power limbs at that width → one transfer →
+execute → read back and ``observe_crypto_batch``. ``CURVES`` has one row
+a curve naming what differs (host prep, the jitted steps, the kernel's
+tile floor, the sharded builders, the chaos site); ``device_verify`` is
+the only way production code reaches the device, called from one place
 (``crypto/batch.py TPUBatchVerifier._verify_pending``, under the
 ``crypto.tpu`` breaker and the per-batch deadline).
 
@@ -56,7 +57,8 @@ class Curve:
     steps)."""
     name: str                # metric label, span prefix, pallas.<name> breaker
     fault: str               # chaos site on the dispatch boundary
-    prepare: Callable        # (pks, msgs, sigs) -> (packed uint8 [rows, B], host_ok)
+    prepare: Callable        # (pks, msgs, sigs, padded) -> (packed uint8
+    #                          [rows, padded], lanes B.. = lane 0; host_ok [B])
     kernel: Callable         # Pallas mask step: packed -> mask
     tile: int                # the kernel's tile: the floor of its padded shapes
     xla: Callable            # XLA mask step: (packed, table) -> mask
@@ -193,7 +195,9 @@ def padded_lanes(lanes: int, tile: int = 0, n_devices: int = 1) -> int:
 def pad_packed(packed: np.ndarray, padded: int) -> np.ndarray:
     """numpy [rows, B] -> [rows, padded], replicating lane 0 (well-formed;
     pad results are discarded). Row-count agnostic: ed25519/sr25519 pack
-    128 rows, secp256k1 packs 168."""
+    128 rows, secp256k1 packs 168. A flush does not copy through here (a
+    row's ``prepare`` writes at the padded width); tools and tests that
+    hold an unpadded plane do."""
     B = packed.shape[1]
     if padded == B:
         return packed
@@ -246,10 +250,13 @@ def device_verify(curve: str, pks, msgs, sigs, powers=None,
 def _flush(row: Curve, pks, msgs, sigs, powers, min_lanes: int, mesh
            ) -> Tuple[np.ndarray, Optional[int]]:
     """The seven steps, on one device (``mesh`` None) or lane-sharded
-    over ``mesh``. On the mesh every mask route is the lane-sharded XLA
-    graph and only the fused tally runs the kernel (under shard_map, the
-    power reduction one psum); nothing there is retried on another
-    implementation — a failure is the caller's to take single-device."""
+    over ``mesh``. The shape is settled before host prep (it depends on
+    the lane count, the row's tile and the implementation, none of which
+    host prep changes), so the operands are built once, at that width. On
+    the mesh every mask route is the lane-sharded XLA graph and only the
+    fused tally runs the kernel (under shard_map, the power reduction one
+    psum); nothing there is retried on another implementation — a failure
+    is the caller's to take single-device."""
     B = len(sigs)
     fused = powers is not None and row.tally_kernel is not None
     n = int(mesh.devices.size) if mesh is not None else 1
@@ -259,11 +266,6 @@ def _flush(row: Curve, pks, msgs, sigs, powers, min_lanes: int, mesh
     t0 = time.perf_counter()
     with trace.span(name + ("_tally" if fused else ""), curve=row.name,
                     lanes=B, **attrs) as sp:
-        with trace.span(f"{row.name}.prepare", lanes=B):
-            packed, host_ok = row.prepare(pks, msgs, sigs)
-        if fused:
-            p = np.asarray(powers, dtype=np.int64).copy()
-            p[~host_ok] = 0
         if mesh is None:
             pbr = pallas_breaker(row.name)
             use_kernel = use_pallas_kernel() and pbr.allow()
@@ -280,13 +282,16 @@ def _flush(row: Curve, pks, msgs, sigs, powers, min_lanes: int, mesh
         padded = padded_lanes(max(B, min_lanes),
                               row.tile if use_kernel else 0, n)
         sp.set(impl=impl, padded=padded)
+        with trace.span(f"{row.name}.prepare", lanes=B):
+            packed, host_ok = row.prepare(pks, msgs, sigs, padded)
         with trace.span(f"{row.name}.pad", padded=padded):
             if fused:
                 # pad lanes replicate lane 0's BYTES only — their power
                 # limbs stay zero, so padding can never leak into the tally
                 limbs = np.zeros((sh.POWER_LIMBS, padded), dtype=np.int32)
-                limbs[:, :B] = sh.powers_to_limbs(p)
-            packed = pad_packed(packed, padded)
+                sh.powers_to_limbs(
+                    np.where(host_ok, np.asarray(powers, dtype=np.int64), 0),
+                    out=limbs[:, :B])
         with trace.span(f"{row.name}.device_put"):
             args = (jnp.asarray(packed),)  # ONE transfer
         with trace.span(f"{row.name}.execute", impl=impl):

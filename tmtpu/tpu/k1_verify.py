@@ -36,7 +36,7 @@ import numpy as np
 
 from tmtpu.crypto.secp256k1 import N
 from tmtpu.tpu import fe_k1 as fe
-from tmtpu.tpu.verify import lt_le
+from tmtpu.tpu.verify import lanes_as_arrays, lt_le
 
 P = fe.P_INT
 B3 = 21  # 3*b for y^2 = x^3 + 7
@@ -284,8 +284,6 @@ _P_BE = np.frombuffer(int.to_bytes(P, 32, "big"), dtype=np.uint8)
 _N_BE = np.frombuffer(int.to_bytes(N, 32, "big"), dtype=np.uint8)
 _HALF_N1_BE = np.frombuffer(
     int.to_bytes(N // 2 + 1, 32, "big"), dtype=np.uint8)
-_ZERO33 = bytes(33)
-_ZERO64 = bytes(64)
 _DUMMY_SCALAR = int.to_bytes(1, 32, "big")
 
 
@@ -295,23 +293,13 @@ def _lt_be(arr: np.ndarray, bound_be: np.ndarray) -> np.ndarray:
     return lt_le(arr[:, ::-1], bound_be[::-1].copy())
 
 
-def prepare_k1_batch_packed(pks, msgs, sigs):
-    """Host prep, packed form: (numpy [168, B] uint8, host_ok). Host
-    rejects wrong lengths, bad SEC1 prefixes, r/s out of [1, n-1], and
-    non-low-S (s > n/2) — matching the serial path's checks before any
-    curve math."""
+def prepare_k1_batch_packed(pks, msgs, sigs, padded: int = 0):
+    """Host prep, packed form: (numpy [168, max(B, padded)] uint8, lanes
+    B.. replicating lane 0, and host_ok [B]). Host rejects wrong lengths,
+    bad SEC1 prefixes, r/s out of [1, n-1], and non-low-S (s > n/2) —
+    matching the serial path's checks before any curve math."""
     B = len(sigs)
-    pks_b = [bytes(p) for p in pks]
-    sigs_b = [bytes(s) for s in sigs]
-    len_ok = np.fromiter(
-        (len(pks_b[i]) == 33 and len(sigs_b[i]) == 64 for i in range(B)),
-        dtype=bool, count=B,
-    )
-    if not len_ok.all():
-        pks_b = [p if ok else _ZERO33 for p, ok in zip(pks_b, len_ok)]
-        sigs_b = [s if ok else _ZERO64 for s, ok in zip(sigs_b, len_ok)]
-    sig_arr = np.frombuffer(b"".join(sigs_b), dtype=np.uint8).reshape(B, 64)
-    pk_arr = np.frombuffer(b"".join(pks_b), dtype=np.uint8).reshape(B, 33)
+    len_ok, pk_arr, sig_arr = lanes_as_arrays(pks, sigs, 33)
     r_arr = sig_arr[:, :32].copy()
     s_arr = sig_arr[:, 32:]
     prefix = pk_arr[:, 0]
@@ -368,10 +356,11 @@ def prepare_k1_batch_packed(pks, msgs, sigs):
     # ONE [168, B] host plane: 5 byte planes + the parity row (+7 zero
     # rows to an 8-multiple) — single H2D transfer, split on device
     # (see verify.prepare_batch_packed)
-    packed = np.concatenate(
-        [np.ascontiguousarray(a.T)
-         for a in (pkx, u1_arr, u2_arr, r_arr, rpn_arr)]
-        + [parity[None, :], np.zeros((7, B), dtype=np.uint8)], axis=0)
+    packed = np.zeros((168, max(B, padded)), dtype=np.uint8)
+    for row, arr in enumerate((pkx, u1_arr, u2_arr, r_arr, rpn_arr)):
+        packed[32 * row:32 * row + 32, :B] = arr.T
+    packed[160, :B] = parity
+    packed[:, B:] = packed[:, :1]
     return packed, host_ok
 
 

@@ -30,15 +30,21 @@ POWER_RADIX = 13
 POWER_LIMBS = 5  # 5 * 13 = 65 bits >= int64
 
 
-def powers_to_limbs(powers) -> np.ndarray:
-    """int64-ish array/list [B] -> [5, B] int32 radix-2^13 limbs."""
-    out = np.zeros((POWER_LIMBS, len(powers)), dtype=np.int32)
-    for i, p in enumerate(powers):
-        v = int(p)
-        for j in range(POWER_LIMBS):
-            out[j, i] = v & ((1 << POWER_RADIX) - 1)
-            v >>= POWER_RADIX
-        assert v == 0, "voting power exceeds 65 bits"
+def powers_to_limbs(powers, out=None) -> np.ndarray:
+    """int64 powers, array or list [B] -> [5, B] int32 radix-2^13 limbs,
+    as five shifts and masks over the whole array; written into ``out``
+    (int32 [5, B], e.g. the first B lanes of a wider operand) when given.
+    A negative power, or one no int64 holds, is refused."""
+    try:
+        p = np.asarray(powers, dtype=np.int64)
+    except OverflowError:
+        raise ValueError("voting power exceeds 63 bits") from None
+    if p.size and p.min() < 0:
+        raise ValueError("negative voting power")
+    if out is None:
+        out = np.empty((POWER_LIMBS, p.shape[0]), dtype=np.int32)
+    for j in range(POWER_LIMBS):
+        out[j] = (p >> (POWER_RADIX * j)) & ((1 << POWER_RADIX) - 1)
     return out
 
 

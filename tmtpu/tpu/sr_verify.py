@@ -40,7 +40,7 @@ from tmtpu.crypto import ed25519_ref as ref
 from tmtpu.crypto import ristretto
 from tmtpu.crypto.merlin import Transcript
 from tmtpu.tpu import curve, fe
-from tmtpu.tpu.verify import digits_msb_device, lt_le
+from tmtpu.tpu.verify import digits_msb_device, lanes_as_arrays, lt_le
 
 L = ref.L
 P = ref.P
@@ -143,8 +143,6 @@ def sr_verify_core_compact(pk_b, r_b, s_b, k_b, base_table):
 
 _P_LE = np.frombuffer(int.to_bytes(P, 32, "little"), dtype=np.uint8)
 _L_LE = np.frombuffer(int.to_bytes(L, 32, "little"), dtype=np.uint8)
-_ZERO32 = bytes(32)
-_ZERO64 = bytes(64)
 
 
 def _native_challenges(pk_arr, r_arr, msgs):
@@ -175,27 +173,17 @@ def _challenge_k(pk: bytes, msg: bytes, r_bytes: bytes) -> bytes:
     return k.to_bytes(32, "little")
 
 
-def prepare_sr_batch_packed(pks, msgs, sigs):
-    """Host prep, packed form: (numpy [128, B] uint8 — pk/r/s/k stacked,
-    host_ok). Callers device_put the single plane.
+def prepare_sr_batch_packed(pks, msgs, sigs, padded: int = 0):
+    """Host prep, packed form: (numpy [128, max(B, padded)] uint8 —
+    pk/r/s/k stacked, lanes B.. replicating lane 0 — and host_ok [B]).
+    Callers device_put the single plane.
 
     Host-rejected lanes (wrong length, missing schnorrkel marker bit,
     s >= L, non-canonical A or R encoding) get well-formed dummy inputs and
     are masked out via host_ok."""
     B = len(sigs)
-    pks_b = [bytes(p) for p in pks]
-    sigs_b = [bytes(s) for s in sigs]
-    len_ok = np.fromiter(
-        (len(pks_b[i]) == 32 and len(sigs_b[i]) == 64 for i in range(B)),
-        dtype=bool, count=B,
-    )
-    if not len_ok.all():
-        pks_b = [p if ok else _ZERO32 for p, ok in zip(pks_b, len_ok)]
-        sigs_b = [s if ok else _ZERO64 for s, ok in zip(sigs_b, len_ok)]
-    sig_arr = np.frombuffer(b"".join(sigs_b), dtype=np.uint8).reshape(B, 64)
-    pk_arr = np.frombuffer(
-        b"".join(pks_b), dtype=np.uint8
-    ).reshape(B, 32).copy()  # frombuffer views are read-only; lanes get zeroed
+    len_ok, pk_arr, sig_arr = lanes_as_arrays(pks, sigs, 32)
+    pk_arr = pk_arr.copy()  # the views are read-only; lanes get zeroed
     r_arr = sig_arr[:, :32].copy()
     s_arr = sig_arr[:, 32:].copy()
     marker_ok = (s_arr[:, 31] & 0x80) != 0
@@ -229,10 +217,10 @@ def prepare_sr_batch_packed(pks, msgs, sigs):
     # ONE [128, B] host plane (pk/r/s/k stacked): callers device_put it as
     # a single transfer, same reason the ed25519 path packs
     # (verify.prepare_batch_packed)
-    packed = np.concatenate([
-        np.ascontiguousarray(pk_arr.T), np.ascontiguousarray(r_arr.T),
-        np.ascontiguousarray(s_arr.T), np.ascontiguousarray(k_arr.T),
-    ], axis=0)
+    packed = np.empty((128, max(B, padded)), dtype=np.uint8)
+    for row, arr in enumerate((pk_arr, r_arr, s_arr, k_arr)):
+        packed[32 * row:32 * row + 32, :B] = arr.T
+    packed[:, B:] = packed[:, :1]
     return packed, host_ok
 
 

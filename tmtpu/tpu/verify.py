@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tmtpu.crypto import ed25519_ref as ref
+from tmtpu.libs import trace
 from tmtpu.tpu import curve, fe
 
 L = ref.L
@@ -128,14 +129,14 @@ def verify_core_compact(pk_b, r_b, s_b, h_b, base_table):
 
 
 _L_LE = np.frombuffer(int.to_bytes(L, 32, "little"), dtype=np.uint8)
-_ZERO32 = bytes(32)
 _ZERO64 = bytes(64)
 
 
-def _native_prep(pk_arr, r_arr, s_arr, msgs):
-    """Batched SHA-512 + mod-L + s<L via the C hostprep library
-    (tmtpu/native); None when no toolchain is available (callers fall back
-    to the numpy/hashlib path below). Disable with TMTPU_NO_NATIVE=1."""
+def _native_prep(pk_arr, sig_arr, msgs, plane):
+    """One C call that writes the flush's lanes into ``plane`` (tmtpu/native
+    ``prep_ed25519``: SHA-512 + mod-L + s<L + the four byte planes); None
+    when no toolchain is available (callers fall back to the numpy/hashlib
+    path below). Disable with TMTPU_NO_NATIVE=1."""
     import os
 
     if os.environ.get("TMTPU_NO_NATIVE"):
@@ -144,7 +145,7 @@ def _native_prep(pk_arr, r_arr, s_arr, msgs):
         from tmtpu import native
     except Exception:
         return None
-    return native.prep_ed25519(pk_arr, r_arr, s_arr, msgs)
+    return native.prep_ed25519(pk_arr, sig_arr, msgs, plane)
 
 
 def lt_le(arr: np.ndarray, bound_le: np.ndarray) -> np.ndarray:
@@ -163,8 +164,29 @@ def _s_below_l(s_arr: np.ndarray) -> np.ndarray:
     return lt_le(s_arr, _L_LE)
 
 
-def prepare_batch_packed(pks, msgs, sigs):
-    """Host prep, packed form: returns (numpy [128, B] uint8, host_ok).
+def lanes_as_arrays(pks, sigs, key_len: int):
+    """A flush's keys and signatures as two contiguous buffers: (len_ok
+    bool [B], pk_arr [B, key_len], sig_arr [B, 64]), read-only uint8 views
+    of one join each. Lanes are any bytes-like objects; lengths are read in
+    one C-level pass a list (no frame a lane), and only where one is off
+    are that lane's key and signature swapped for zeros, lane by lane."""
+    B = len(sigs)
+    len_ok = (np.fromiter(map(len, pks), dtype=np.int64, count=B) == key_len) \
+        & (np.fromiter(map(len, sigs), dtype=np.int64, count=B) == 64)
+    if not len_ok.all():
+        zero_pk = bytes(key_len)
+        pks = [p if ok else zero_pk for p, ok in zip(pks, len_ok)]
+        sigs = [s if ok else _ZERO64 for s, ok in zip(sigs, len_ok)]
+    return (len_ok,
+            np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(B, key_len),
+            np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(B, 64))
+
+
+def prepare_batch_packed(pks, msgs, sigs, padded: int = 0):
+    """Host prep, packed form: returns (numpy [128, W] uint8, host_ok [B]),
+    W = max(B, padded): the operand at the width the device step takes,
+    lanes B.. replicating lane 0 (well-formed; their results are
+    discarded), built in one pass with no Python statement a lane.
 
     The four 32-byte planes (pk, r, s, h) are stacked into ONE array so
     the host->device hop is a single transfer: at 128 B/lane a flush is
@@ -173,6 +195,11 @@ def prepare_batch_packed(pks, msgs, sigs):
     step one argument to shard. Output is pure numpy: callers decide
     when the device_put happens (and can overlap it with compute).
 
+    The flow: ``lanes_as_arrays`` (a ``len`` pass and a join each for keys
+    and signatures), the same for the messages, then one C call that hashes every
+    lane and writes its four fields into the plane's columns (without
+    the native library: hashlib a lane, four transposed copies).
+
     Host-side checks (the ones the device never sees): wrong lengths,
     non-canonical s (>= L), non-canonical A.y (>= p); violating lanes get
     dummy-but-wellformed inputs and are masked via host_ok. No limb/digit
@@ -180,53 +207,42 @@ def prepare_batch_packed(pks, msgs, sigs):
     host does only byte shuffling plus SHA-512 challenge hashing and the
     mod-L reduction."""
     B = len(sigs)
-    pks_b = [bytes(p) for p in pks]
-    sigs_b = [bytes(s) for s in sigs]
-    len_ok = np.fromiter(
-        (len(pks_b[i]) == 32 and len(sigs_b[i]) == 64 for i in range(B)),
-        dtype=bool, count=B,
-    )
-    if not len_ok.all():
-        pks_b = [p if ok else _ZERO32 for p, ok in zip(pks_b, len_ok)]
-        sigs_b = [s if ok else _ZERO64 for s, ok in zip(sigs_b, len_ok)]
-    sig_arr = np.frombuffer(b"".join(sigs_b), dtype=np.uint8).reshape(B, 64)
-    pk_arr = np.frombuffer(b"".join(pks_b), dtype=np.uint8).reshape(B, 32)
-    r_arr = sig_arr[:, :32].copy()
-    s_arr = sig_arr[:, 32:].copy()
-    native = _native_prep(pk_arr, r_arr, s_arr, msgs)
+    len_ok, pk_arr, sig_arr = lanes_as_arrays(pks, sigs, 32)
+    packed = np.empty((128, max(B, padded)), dtype=np.uint8)
+    native = _native_prep(pk_arr, sig_arr, msgs, packed)
     if native is not None:
-        h_arr, s_ok = native
-        host_ok = len_ok & s_ok
+        s_ok, sha = native
+        trace.annotate(impl="native", sha=sha)
     else:
-        host_ok = len_ok & _s_below_l(s_arr)
+        trace.annotate(impl="python", sha="hashlib")
+        s_ok = _s_below_l(sig_arr[:, 32:])
         h_arr = np.frombuffer(
             b"".join(
                 int.to_bytes(
                     int.from_bytes(
-                        hashlib.sha512(s[:32] + p + bytes(m)).digest(),
+                        hashlib.sha512(r.tobytes() + p.tobytes()
+                                       + bytes(m)).digest(),
                         "little",
                     ) % L,
                     32, "little",
                 )
-                for s, p, m in zip(sigs_b, pks_b, msgs)
+                for r, p, m in zip(sig_arr[:, :32], pk_arr, msgs)
             ),
             dtype=np.uint8,
         ).reshape(B, 32)
-    if not host_ok.all():
-        s_arr[~host_ok] = 0
-    # canonicality of A.y (device packs the masked bytes; the check is host's)
-    masked = pk_arr.copy()
-    masked[:, 31] &= 0x7F
-    host_ok &= ~(
-        (masked[:, 0] >= 0xED)
-        & np.all(masked[:, 1:31] == 0xFF, axis=1)
-        & (masked[:, 31] == 0x7F)
-    )
-    packed = np.empty((128, B), dtype=np.uint8)
-    packed[0:32] = pk_arr.T
-    packed[32:64] = r_arr.T
-    packed[64:96] = s_arr.T
-    packed[96:128] = h_arr.T
+        packed[0:32, :B] = pk_arr.T
+        packed[32:96, :B] = sig_arr.T
+        packed[96:128, :B] = h_arr.T
+        if not s_ok.all():
+            packed[64:96, :B][:, ~s_ok] = 0
+    host_ok = len_ok & s_ok
+    # canonicality of A.y (device packs the masked bytes; the check is
+    # host's): y >= p is 2^255 - 19 + d, d < 19 — looked for among the one
+    # lane in 128 whose top byte allows it
+    top = np.flatnonzero((pk_arr[:, 31] & 0x7F) == 0x7F)
+    host_ok[top[(pk_arr[top, 0] >= 0xED)
+                & (pk_arr[top, 1:31] == 0xFF).all(axis=1)]] = False
+    packed[:, B:] = packed[:, :1]
     return packed, host_ok
 
 
