@@ -362,8 +362,8 @@ def test_warm_validator_set_flushes_below_the_sigcache(monkeypatch):
         Validator(ed.gen_priv_key_from_secret(b"w%d" % i).pub_key(), 1)
         for i in range(70)])
     out = crypto_batch.warm_validator_set(vals)
-    assert flushed == [("ed25519", 8, True), ("ed25519", 65, True),
-                       ("ed25519", 70, True)]
+    # a set under a drain's worth: every vote flush is pinned to the set
+    assert flushed == [("ed25519", 70, True)]
     assert [(c, n, t) for c, n, t, _s in out] == flushed
     # a set too small to ever reach the device warms nothing
     flushed.clear()
@@ -380,9 +380,11 @@ def test_warm_validator_set_flushes_below_the_sigcache(monkeypatch):
 
 
 def test_warm_validator_set_reaches_the_whole_set(monkeypatch):
-    """A drain can hold all of a round's votes, and verify_commit a whole
-    commit: the ladder runs to the set's size (the first chip run
-    compiled the 4,096 bucket inside the live 10k round)."""
+    """A vote flush is pinned to a drain's worth or to the whole set
+    (vote_flush_lanes), and verify_commit flushes a whole commit: those
+    two shapes are warmed and no other (the first chip run compiled the
+    4,096 bucket inside the live 10k round; a ladder of every bucket then
+    cost 11 compiles, 190 s)."""
     from tmtpu.tpu import dispatch
 
     flushed = []
@@ -396,6 +398,8 @@ def test_warm_validator_set_reaches_the_whole_set(monkeypatch):
             b"one").pub_key()})()] * 10_000
 
     crypto_batch.warm_validator_set(FakeSet)
-    assert flushed[-1] == 10_000
-    assert {dispatch._pad_to_bucket(n) for n in flushed} == {
-        64, 128, 256, 512, 1024, 2048, 4096, 6144, 8192, 10240}
+    assert flushed == [crypto_batch.DRAIN_LANES, 10_000]
+    assert [dispatch._pad_to_bucket(n) for n in flushed] == [1024, 10240]
+    # whatever a flush holds, its pin is one of the two
+    assert {dispatch._pad_to_bucket(crypto_batch.vote_flush_lanes(10_000, n))
+            for n in range(1, 10_001)} == {1024, 10240}

@@ -115,7 +115,7 @@ def test_100_validator_net_commits_through_device_batches(monkeypatch):
 
     r = flood_round.run(99, backend="tpu", timeout=600)
     assert r["precommits_in_commit"] >= 67
-    assert [w[1] for w in r["warmed"]] == [8, 100]   # one 128 shape
+    assert [w[1] for w in r["warmed"]] == [100]      # the one 128 shape
     assert r["lanes_dispatched"] >= 99
     assert r["lanes_dispatched"] / r["dispatches"] >= 16, r
 

@@ -306,6 +306,16 @@ class ConsensusReactor(Reactor):
         return ctx
 
     def receive(self, channel_id: int, peer: Peer, msg_bytes: bytes) -> None:
+        # decode and PeerState under the span; the hand-over to the state
+        # machine after it, because a full queue blocks there and that wait
+        # is the consensus thread's work, not this one's
+        with _trace.span("consensus.receive"):
+            enqueue = self._receive(channel_id, peer, msg_bytes)
+        if enqueue is not None:
+            enqueue[0](*enqueue[1:])
+
+    def _receive(self, channel_id: int, peer: Peer, msg_bytes: bytes):
+        """-> what to hand the state machine, as (method, *args), or None."""
         m = cm.ConsensusMessagePB.decode(msg_bytes)
         ps: Optional[PeerState] = peer.get("consensus_peer_state")
         if ps is None:
@@ -363,9 +373,9 @@ class ConsensusReactor(Reactor):
                 if ctx is not None:
                     _trace.mark("gossip.proposal_rx", ctx=ctx,
                                 height=prop.height, peer=peer.node_id)
-                self.cs.add_proposal(prop, peer.node_id)
                 with ps.lock:
                     ps.proposal = True
+                return self.cs.add_proposal, prop, peer.node_id
             elif kind == "block_part":
                 bp = m.block_part
                 part = Part.from_proto(bp.part)
@@ -375,8 +385,8 @@ class ConsensusReactor(Reactor):
                                 height=bp.height, index=part.index,
                                 peer=peer.node_id)
                 ps.set_has_part(bp.height, part.index, part.proof.total)
-                self.cs.add_block_part(bp.height, bp.round, part,
-                                       peer.node_id)
+                return (self.cs.add_block_part, bp.height, bp.round, part,
+                        peer.node_id)
         elif channel_id == VOTE_CHANNEL:
             if self.wait_sync:
                 return
@@ -391,7 +401,7 @@ class ConsensusReactor(Reactor):
                 n = vals.size() if vals else 0
                 ps.set_has_vote(vote.height, vote.round, vote.type,
                                 vote.validator_index, n)
-                self.cs.add_vote_msg(vote, peer.node_id)
+                return self.cs.add_vote_msg, vote, peer.node_id
         elif channel_id == VOTE_SET_BITS_CHANNEL:
             if kind == "vote_set_bits":
                 vb = m.vote_set_bits

@@ -32,6 +32,8 @@ from tmtpu.consensus.types import (
 from tmtpu.consensus.wal import (
     EndHeightPB, EventRoundStatePB, MsgInfoPB, TimeoutInfoPB, WAL,
 )
+from tmtpu.crypto import batch as _crypto_batch
+from tmtpu.libs import metrics as _m
 from tmtpu.libs import timeline, trace, txlat
 from tmtpu.libs import valstats as _valstats
 from tmtpu.libs.service import BaseService
@@ -128,7 +130,10 @@ class ConsensusState(BaseService):
         self.rs = RoundState()
         self.state = None  # sm.State, set by update_to_state
 
-        self.peer_msg_queue: "queue.Queue[MsgInfo]" = queue.Queue(maxsize=1000)
+        # the bound is also the most one drain takes, and so what sizes a
+        # vote flush's device shape (crypto/batch.py vote_flush_lanes)
+        self.peer_msg_queue: "queue.Queue[MsgInfo]" = queue.Queue(
+            maxsize=_crypto_batch.DRAIN_LANES)
         self.internal_msg_queue: "queue.Queue[MsgInfo]" = queue.Queue(maxsize=1000)
         self._timeout_queue: "queue.Queue[TimeoutInfo]" = queue.Queue()
         self.ticker = TimeoutTicker(self._timeout_queue.put)
@@ -322,8 +327,10 @@ class ConsensusState(BaseService):
                     f"updateToState expected height {self.rs.height}, "
                     f"state at {state.last_block_height}"
                 )
-            validators = state.next_validators.copy() \
-                if state.last_block_height else state.validators.copy()
+            # the set of the height being entered (state.go:1683
+            # `validators := state.Validators`): ``update_state`` has
+            # already moved next_validators into validators
+            validators = state.validators.copy()
 
             last_precommits = None
             if self.rs.commit_round > -1 and self.rs.votes is not None:
@@ -393,8 +400,9 @@ class ConsensusState(BaseService):
                     # trace id — None (unsampled) is a no-op
                     with trace.activate(
                             trace.height_context(self.rs.height)):
-                        for mi in msgs:
-                            self._wal_write_msg(mi)
+                        with trace.span("consensus.wal", msgs=len(msgs)):
+                            for mi in msgs:
+                                self._wal_write_msg(mi)
                         self._handle_msgs(msgs)
                         for ti in timeouts:
                             if self.wal is not None:
@@ -414,41 +422,48 @@ class ConsensusState(BaseService):
                 return
 
     def _drain_messages(self):
-        """Block for one message/timeout, then drain everything pending —
-        the TPU batching window."""
+        """Block for one message/timeout, then drain what is pending — the
+        TPU batching window. Of the peers' messages a drain takes at most
+        the queue's bound (the rest is the next drain's), so that a vote
+        flush has a known widest size (crypto/batch.py vote_flush_lanes)."""
         msgs: List[MsgInfo] = []
         timeouts: List[TimeoutInfo] = []
-        # block on the first item from either queue
-        got = False
-        while not got:
+        cap = self.peer_msg_queue.maxsize
+
+        def first(wait_s: float) -> Optional[bool]:
+            """One look at the three queues, blocking ``wait_s`` on the
+            peers': True = got one, None = stop."""
             try:
-                ti = self._timeout_queue.get_nowait()
-                timeouts.append(ti)
-                got = True
-                break
+                timeouts.append(self._timeout_queue.get_nowait())
+                return True
             except queue.Empty:
                 pass
-            try:
-                mi = self.internal_msg_queue.get_nowait()
+            for q, wait in ((self.internal_msg_queue, 0.0),
+                            (self.peer_msg_queue, wait_s)):
+                try:
+                    mi = q.get(timeout=wait) if wait else q.get_nowait()
+                except queue.Empty:
+                    continue
                 if mi is None:
                     return None
                 msgs.append(mi)
-                got = True
-                break
-            except queue.Empty:
-                pass
-            try:
-                mi = self.peer_msg_queue.get(timeout=0.02)
-                if mi is None:
+                return True
+            return False
+
+        # the loop asking for its next message: no time at all while the
+        # queues hold one, else the commit wait or a peer's silence
+        with trace.span("consensus.idle"):
+            got = first(0.0)
+            while got is False:
+                got = first(0.02)
+                if got is False and self._quit.is_set():
                     return None
-                msgs.append(mi)
-                got = True
-            except queue.Empty:
-                if self._quit.is_set():
-                    return None
+        if got is None:
+            return None
         # drain the rest without blocking
-        for q in (self.internal_msg_queue, self.peer_msg_queue):
-            while True:
+        for q, most in ((self.internal_msg_queue, None),
+                        (self.peer_msg_queue, cap)):
+            while most is None or len(msgs) < most:
                 try:
                     mi = q.get_nowait()
                 except queue.Empty:
@@ -462,19 +477,15 @@ class ConsensusState(BaseService):
         # instead of two sparse ones. Inert (0.0 wait) until real device
         # RTT samples exist, so CPU-only nodes keep the legacy window;
         # never delays a timeout.
-        if not timeouts:
+        if not timeouts and len(msgs) < cap:
             n_votes = sum(1 for mi in msgs
                           if isinstance(mi.msg, VoteMessage))
             if n_votes:
-                from tmtpu.crypto import batch as _crypto_batch
-
                 wait = _crypto_batch.SCHEDULER.gather_wait_s(n_votes)
                 if wait > 0:
-                    from tmtpu.libs import metrics as _m
-
                     _m.crypto_flush_gather_waits.inc()
                     deadline = time.monotonic() + wait
-                    while True:
+                    while len(msgs) < cap:
                         left = deadline - time.monotonic()
                         if left <= 0:
                             break
@@ -1003,7 +1014,8 @@ class ConsensusState(BaseService):
 
     def _set_proposal_safe(self, proposal: Proposal) -> None:
         try:
-            self._set_proposal(proposal)
+            with trace.span("consensus.proposal", height=proposal.height):
+                self._set_proposal(proposal)
         except VoteError:
             pass
 
@@ -1019,8 +1031,6 @@ class ConsensusState(BaseService):
                  proposal.pol_round >= proposal.round):
             raise VoteError("error invalid proposal POL round")
         proposer = rs.validators.get_proposer()
-        from tmtpu.crypto import batch as _crypto_batch
-
         if not _crypto_batch.verify_one(
                 proposer.pub_key,
                 proposal.sign_bytes(self.state.chain_id), proposal.signature):
@@ -1070,14 +1080,16 @@ class ConsensusState(BaseService):
             if len(self._pending_parts) < 128:
                 self._pending_parts[(msg.height, msg.part.index)] = msg
             return
-        try:
-            added = rs.proposal_block_parts.add_part(msg.part)
-        except ValueError:
-            return
-        if not added or not rs.proposal_block_parts.is_complete():
-            return
-        data = rs.proposal_block_parts.assemble()
-        rs.proposal_block = Block.decode(data)
+        # parts -> block: the proof of each part, then the whole decoded
+        with trace.span("consensus.proposal", part=msg.part.index):
+            try:
+                added = rs.proposal_block_parts.add_part(msg.part)
+            except ValueError:
+                return
+            if not added or not rs.proposal_block_parts.is_complete():
+                return
+            data = rs.proposal_block_parts.assemble()
+            rs.proposal_block = Block.decode(data)
         # proposal checkpoint for every tx in the block — proposer and
         # followers both complete their parts through this path; the
         # noted hashes also serve the later height-keyed stamps
@@ -1205,26 +1217,31 @@ class ConsensusState(BaseService):
         return max(now, min_vote_time)
 
     def _try_add_votes(self, votes: List[Tuple[Vote, str]]) -> None:
-        """tryAddVote (:1947) over a batch — one BatchVerifier dispatch."""
+        """tryAddVote (:1947) over a batch — one BatchVerifier dispatch a
+        vote set."""
         rs = self.rs
         # late precommits for the previous height extend LastCommit
         current, last = [], []
         for v, peer in votes:
             if v.height + 1 == rs.height and v.type == PRECOMMIT:
-                last.append((v, peer))
+                last.append(v)
             elif v.height == rs.height:
                 current.append((v, peer))
             # other heights: ignore (reactor handles catchup)
-        if last and rs.step == STEP_NEW_HEIGHT and rs.last_commit is not None:
-            for v, _peer in last:
-                try:
-                    rs.last_commit.add_vote(v)
-                    if self.event_bus:
-                        self.event_bus.publish_vote(v)
-                except VoteError:
-                    pass
-            if self.config.skip_timeout_commit and rs.last_commit.has_all():
-                self._schedule_round0()
+        if len(current) + len(last) < len(votes):
+            _m.consensus_votes_dropped.inc(
+                len(votes) - len(current) - len(last), reason="height")
+        if last:
+            # state.go addVote's branch for vote.Height+1 == cs.Height:
+            # only while the commit wait lasts, all of a drain's in one flush
+            if rs.step == STEP_NEW_HEIGHT and rs.last_commit is not None:
+                self._add_group(rs.last_commit.add_votes, last,
+                                "late_precommit")
+                if self.config.skip_timeout_commit and \
+                        rs.last_commit.has_all():
+                    self._schedule_round0()
+            else:
+                _m.consensus_votes_dropped.inc(len(last), reason="late")
         if not current:
             return
         # group by peer so the per-peer catchup-round budget in
@@ -1233,24 +1250,43 @@ class ConsensusState(BaseService):
         for v, peer in current:
             by_peer.setdefault(peer, []).append(v)
         for peer, group in by_peer.items():
-            try:
-                added_mask = rs.votes.add_votes(group, peer_id=peer)
-            except ErrVoteConflictingVotes as e:
-                # equivocation -> evidence pool (state.go:1971); the batch
-                # was still processed — keep the per-vote added flags
-                if self.evidence_pool is not None:
-                    try:
-                        self.evidence_pool.report_conflicting_votes(
-                            e.vote_a, e.vote_b)
-                    except Exception:
-                        pass
-                added_mask = e.results or [False] * len(group)
-            except VoteError:
-                added_mask = [False] * len(group)
-            for v, added in zip(group, added_mask):
-                if added and self.event_bus:
-                    self.event_bus.publish_vote(v)
+            self._add_group(
+                lambda g, _p=peer: rs.votes.add_votes(g, peer_id=_p), group)
         self._check_vote_transitions()
+
+    def _add_group(self, add_votes, group: List[Vote],
+                   counted_as: str = "") -> None:
+        """One ``add_votes`` call and what tryAddVote does with its
+        outcome: a conflicting pair goes to the evidence pool
+        (state.go:1971; the batch was still processed, the per-vote added
+        flags kept), each added vote is published and counted."""
+        try:
+            added_mask = add_votes(group)
+        except ErrVoteConflictingVotes as e:
+            if self.evidence_pool is not None:
+                try:
+                    self.evidence_pool.report_conflicting_votes(
+                        e.vote_a, e.vote_b)
+                except Exception:
+                    pass
+            added_mask = e.results or [False] * len(group)
+        except VoteError:
+            added_mask = [False] * len(group)
+        n_added = {}
+        with trace.span("consensus.publish", votes=len(group)):
+            for v, added in zip(group, added_mask):
+                if not added:
+                    continue
+                kind = counted_as or \
+                    ("prevote" if v.type == PREVOTE else "precommit")
+                n_added[kind] = n_added.get(kind, 0) + 1
+                if self.event_bus:
+                    self.event_bus.publish_vote(v)
+        for kind, n in n_added.items():
+            _m.consensus_votes_added.inc(n, type=kind)
+        refused = len(group) - sum(n_added.values())
+        if refused:
+            _m.consensus_votes_dropped.inc(refused, reason="refused")
 
     def _check_vote_transitions(self) -> None:
         """The post-addVote step logic (state.go:2054-2160), run once per

@@ -72,6 +72,11 @@ SECP256K1 = "secp256k1"
 # small-validator integration tests can force the device path)
 _TPU_MIN_BATCH = int(os.environ.get("TMTPU_TPU_MIN_BATCH", "8"))
 
+# the consensus receive loop's peer queue holds this many messages and one
+# drain takes no more (consensus/state.py): the widest flush a vote set
+# meets short of a whole commit
+DRAIN_LANES = 1000
+
 _default_backend = os.environ.get("TMTPU_CRYPTO_BACKEND", "auto")
 _probe_lock = threading.Lock()
 # memo of the last ANSWERED device probe: True = JAX's first device is a
@@ -446,12 +451,27 @@ def _warm_sizes(max_lanes: int) -> List[int]:
     return sizes
 
 
+def vote_flush_lanes(validators: int, lanes: int) -> int:
+    """The ``min_lanes`` of a vote set's flush of ``lanes`` lanes, for a
+    set of ``validators``: a drain's worth (``DRAIN_LANES``, or the set
+    if that is smaller) where the flush fits it, else the whole set — so
+    whatever a drain held, a vote flush meets one of two device shapes,
+    those ``warm_validator_set`` compiles (``run_shape``'s reasoning, for
+    votes). 0 for a set under ``_TPU_MIN_BATCH``: nothing is pinned or
+    warmed, and its flushes verify serially as small batches."""
+    if validators < _TPU_MIN_BATCH:
+        return 0
+    drain = min(validators, DRAIN_LANES)
+    return drain if lanes <= drain else max(validators, lanes)
+
+
 def _warm(curve: str, sizes: List[int], tally: bool
           ) -> List[Tuple[str, int, bool, float]]:
     """One flush per size of a self-signed ``curve`` lane replicated,
     sent through the same per-curve dispatch (breaker, deadline, mesh
     routing) as production and below the sigcache, so it compiles
-    exactly what production will run. Returns
+    exactly what production will run: a verifier pinned to ``n`` lanes
+    meets the shape this flush of ``n`` lanes does. Returns
     ``[(curve, lanes, tally, seconds)]``; a device failure is the
     breakers' to count (the lanes re-verify serially), never fatal."""
     from tmtpu.crypto import ed25519 as _ed
@@ -475,24 +495,23 @@ def _warm(curve: str, sizes: List[int], tally: bool
 
 
 def warm_validator_set(val_set) -> List[Tuple[str, int, bool, float]]:
-    """Compile, before consensus starts, every device shape this
-    validator set's vote flushes can use, so no first-sight trace +
-    lower + compile (tens of seconds per shape) lands on the consensus
-    thread inside ``batch_deadline``. Per curve with at least
-    ``_TPU_MIN_BATCH`` validators: every shape up to the whole set —
-    one drain of the receive loop can hold anything from a handful of
-    votes to all of a round's (it keeps taking while a fast relay keeps
-    filling the queue), and verify_commit flushes a whole commit.
-    ed25519 warms the fused verify+tally step VoteSet uses; the other
-    curves their mask step."""
-    counts: Dict[str, int] = {}
-    for v in val_set.validators:
-        c = v.pub_key.type_value()
-        counts[c] = counts.get(c, 0) + 1
+    """Compile, before consensus starts, the device shapes this validator
+    set's vote flushes meet (``vote_flush_lanes``: a drain's worth and
+    the whole set, which is also a whole commit's through
+    ``verify_commit``), so no first-sight trace + lower + compile (tens
+    of seconds per shape) lands on the consensus thread inside
+    ``batch_deadline``. A vote flush splits by curve under its one pin,
+    so every curve of the set is warmed at both; ed25519 warms the fused
+    verify+tally step VoteSet uses, the other curves their mask step. A
+    set under ``_TPU_MIN_BATCH`` pins nothing and warms nothing."""
+    n = len(val_set.validators)
+    if not vote_flush_lanes(n, 1):
+        return []
+    sizes = sorted({vote_flush_lanes(n, 1), vote_flush_lanes(n, n)})
+    curves = {v.pub_key.type_value() for v in val_set.validators}
     out = []
-    for curve, n in sorted(counts.items()):
-        if curve in (ED25519, SR25519, SECP256K1):
-            out += _warm(curve, _warm_sizes(n), tally=curve == ED25519)
+    for curve in sorted(curves & {ED25519, SR25519, SECP256K1}):
+        out += _warm(curve, sizes, tally=curve == ED25519)
     return out
 
 
@@ -546,8 +565,10 @@ class BatchVerifier(keys.BatchVerifier):
     ``min_lanes`` pins the device shape of this verifier's flushes:
     whatever the sigcache leaves of one pads as if it held that many
     lanes, so a caller whose flushes vary in length meets the one shape
-    it warmed (``warm_pinned``). The serial and sidecar backends have no
-    shape and ignore it."""
+    it warmed (``warm_pinned``, ``warm_validator_set``) — and, having
+    compiled it, takes the device however few lanes are left: the
+    small-batch exit is for flushes nobody pinned. The serial and sidecar
+    backends have no shape and ignore it."""
 
     def __init__(self, min_lanes: int = 0):
         self.min_lanes = int(min_lanes)
@@ -748,8 +769,8 @@ class TPUBatchVerifier(BatchVerifier):
         per-batch deadline: for ``tally`` a curve with a fused step
         returns the psum of its valid lanes' powers with the mask, the
         others' powers are summed on the host; lanes of a key type the
-        table does not hold, and groups below ``_TPU_MIN_BATCH``, verify
-        serially."""
+        table does not hold, and groups below ``_TPU_MIN_BATCH`` of a
+        verifier without a pinned shape, verify serially."""
         from tmtpu.libs import metrics as _m
         from tmtpu.tpu import dispatch as _disp
 
@@ -824,7 +845,7 @@ class TPUBatchVerifier(BatchVerifier):
         if cpu_idx:
             _serial(cpu_idx, "other", "unsupported")
         for curve, (idx, pks, msgs, sigs, powers) in groups.items():
-            if len(idx) < _TPU_MIN_BATCH:
+            if len(idx) < _TPU_MIN_BATCH and not self.min_lanes:
                 # below this, dispatch overhead beats the serial path
                 _serial(idx, curve, "small-batch")
                 continue

@@ -319,6 +319,25 @@ consensus_invalid_votes = DEFAULT.counter(
     "consensus", "invalid_votes_total",
     "Gossiped votes rejected at signature verification — the admission "
     "filter doing its job under byzantine garbage-signature spam")
+# Vote ingestion (consensus/state.py _try_add_votes over
+# types/vote_set.py add_votes): what a peer's votes came to, and how wide
+# the vote sets' verify flushes were. ``late_precommit`` is a precommit
+# of the height just committed, added to LastCommit during the commit wait.
+consensus_votes_added = DEFAULT.counter(
+    "consensus", "votes_added_total",
+    "Votes added to a vote set, signature verified",
+    labels=("type",))
+consensus_votes_dropped = DEFAULT.counter(
+    "consensus", "votes_dropped_total",
+    "Votes handed to the state machine and not added: height (neither "
+    "this height's nor a late precommit), late (a late precommit after "
+    "round 0 began, as state.go drops it), refused (the vote set did not "
+    "add it: invalid signature, duplicate, conflict, wrong index)",
+    labels=("reason",))
+consensus_vote_flush_lanes = DEFAULT.histogram(
+    "consensus", "vote_flush_lanes",
+    "Votes one VoteSet.add_votes call handed to its batch verifier",
+    buckets=(1, 8, 64, 256, 512, 1024, 2048, 4096, 8192, 16384))
 # Per-step latency breakdown (consensus/metrics.go StepDurationSeconds
 # in later reference releases: ONE histogram with a step label): time
 # spent in each round step, observed on every step transition by

@@ -7,6 +7,7 @@ verify_commit (one TPU dispatch per block).
 
 from __future__ import annotations
 
+from tmtpu.crypto import batch as crypto_batch
 from tmtpu.state.state import State, STATE_VERSION, median_time
 from tmtpu.types import commit_verify  # noqa: F401 (binds ValidatorSet methods)
 from tmtpu.types.block import Block
@@ -58,9 +59,15 @@ def validate_block(state: State, block: Block, verify_backend=None) -> None:
                 len(block.last_commit.signatures) != state.last_validators.size():
             raise BlockValidationError("wrong LastCommit signature count")
         try:
+            # pinned to the whole set's shape, which the node compiled at
+            # start (warm_validator_set): a LastCommit this process has not
+            # verified whole — late precommits dropped after round 0 began,
+            # a restart — then meets that shape, however few lanes are left
+            n = state.last_validators.size()
             state.last_validators.verify_commit(
                 state.chain_id, state.last_block_id,
                 h.height - 1, block.last_commit, backend=verify_backend,
+                min_lanes=crypto_batch.vote_flush_lanes(n, n),
             )
         except commit_verify.VerificationError as e:
             raise BlockValidationError(str(e)) from e

@@ -73,13 +73,16 @@ def _check_commit_basics(vals: ValidatorSet, commit: Commit, height: int,
 
 def verify_commit(vals: ValidatorSet, chain_id: str, block_id: BlockID,
                   height: int, commit: Commit,
-                  backend: Optional[str] = None) -> None:
+                  backend: Optional[str] = None, min_lanes: int = 0) -> None:
     """validator_set.go:667 — all signatures must be valid; tallied power of
-    BlockIDFlagCommit votes must exceed 2/3 of total."""
+    BlockIDFlagCommit votes must exceed 2/3 of total. ``min_lanes`` pins
+    the flush's device shape (a node's ``validate_block`` gives the set's
+    own, which it warmed: whatever the sigcache leaves of a LastCommit
+    then meets a compiled shape)."""
     _check_commit_basics(vals, commit, height, block_id)
     with trace.span("commit_verify.verify_commit", height=height,
                     sigs=len(commit.signatures)):
-        bv = crypto_batch.new_batch_verifier(backend)
+        bv = crypto_batch.new_batch_verifier(backend, min_lanes=min_lanes)
         with trace.span("commit_verify.collect"):
             sign_bytes = commit.vote_sign_bytes_for(chain_id)
             for idx, cs in enumerate(commit.signatures):
@@ -224,8 +227,10 @@ def verify_commits_light_batch(entries, backend=None, min_lanes: int = 0):
 
 # Bind as methods.
 ValidatorSet.verify_commit = (
-    lambda self, chain_id, block_id, height, commit, backend=None:
-    verify_commit(self, chain_id, block_id, height, commit, backend)
+    lambda self, chain_id, block_id, height, commit, backend=None,
+    min_lanes=0:
+    verify_commit(self, chain_id, block_id, height, commit, backend,
+                  min_lanes)
 )
 ValidatorSet.verify_commit_light = (
     lambda self, chain_id, block_id, height, commit, backend=None:

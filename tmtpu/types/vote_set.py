@@ -145,63 +145,73 @@ class VoteSet:
             results = [False] * len(votes)
             first_err: Optional[Exception] = None
             conflict: Optional[ErrVoteConflictingVotes] = None
-            for i, vote in enumerate(votes):
-                try:
-                    val, existing = self._pre_validate(vote)
-                except VoteError as e:
-                    if first_err is None:
-                        first_err = e
-                    continue
-                if val is None:
-                    continue  # benign duplicate; results[i] stays False
-                prepared.append((i, vote, val, existing))
+            bv = None
+            with trace.span("vote_set.collect"):
+                for i, vote in enumerate(votes):
+                    try:
+                        val, existing = self._pre_validate(vote)
+                    except VoteError as e:
+                        if first_err is None:
+                            first_err = e
+                        continue
+                    if val is None:
+                        continue  # benign duplicate; results[i] stays False
+                    prepared.append((i, vote, val, existing))
+                if prepared:
+                    # pinned to a drain's worth or the whole set, so that
+                    # whatever the drain held the flush meets a shape
+                    # warm_validator_set compiled
+                    bv = crypto_batch.new_batch_verifier(
+                        self.verify_backend,
+                        min_lanes=crypto_batch.vote_flush_lanes(
+                            self.size(), len(prepared)))
+                    # Fused tally: when every prepared vote is a fresh add
+                    # from a distinct validator (the normal round: no
+                    # conflicts, no replays), voting powers ride the batch
+                    # and the device returns Σ power over the VALID lanes —
+                    # the on-device replacement for vote_set.go:233-304's
+                    # per-vote host sum. A mixed/conflicting batch rides
+                    # the same step with zero powers (one compiled step a
+                    # shape, never a second one at first sight of an
+                    # equivocation) and is tallied per vote on the host.
+                    fused = (
+                        all(existing is None for *_r, existing in prepared)
+                        and len({v.validator_index for _, v, *_r in prepared})
+                        == len(prepared)
+                    )
+                    for _, vote, val, _ in prepared:
+                        bv.add(val.pub_key, vote.sign_bytes(self.chain_id),
+                               vote.signature,
+                               power=val.voting_power if fused else 0)
 
             if prepared:
-                bv = crypto_batch.new_batch_verifier(self.verify_backend)
-                # Fused-tally fast path: when every prepared vote is a fresh
-                # add from a distinct validator (the normal round: no
-                # conflicts, no replays), voting powers ride the batch and
-                # the device returns Σ power over the VALID lanes — the
-                # on-device replacement for vote_set.go:233-304's per-vote
-                # host sum. Mixed/conflicting batches fall back to per-vote
-                # bookkeeping off the plain mask.
-                fused = (
-                    all(existing is None for *_r, existing in prepared)
-                    and len({v.validator_index for _, v, *_r in prepared})
-                    == len(prepared)
-                )
-                for _, vote, val, _ in prepared:
-                    bv.add(val.pub_key, vote.sign_bytes(self.chain_id),
-                           vote.signature,
-                           power=val.voting_power if fused else 0)
-                if fused:
-                    _, mask, dev_sum = bv.verify_tally()
-                else:
-                    _, mask = bv.verify()
+                _metrics.consensus_vote_flush_lanes.observe(len(prepared))
+                _, mask, dev_sum = bv.verify_tally()
                 applied_power = 0
-                for (i, vote, val, existing), ok in zip(prepared, mask):
-                    if not ok:
-                        _metrics.consensus_invalid_votes.inc()
-                        err = VoteError(
-                            f"invalid signature from {vote.validator_address.hex()}"
+                with trace.span("vote_set.apply"):
+                    for (i, vote, val, existing), ok in zip(prepared, mask):
+                        if not ok:
+                            _metrics.consensus_invalid_votes.inc()
+                            err = VoteError(
+                                "invalid signature from "
+                                f"{vote.validator_address.hex()}")
+                            if first_err is None:
+                                first_err = err
+                            continue
+                        added, conflicting = self._add_verified(
+                            vote, val, defer_sum=fused
                         )
-                        if first_err is None:
-                            first_err = err
-                        continue
-                    added, conflicting = self._add_verified(
-                        vote, val, defer_sum=fused
-                    )
-                    if added and fused:
-                        applied_power += val.voting_power
-                    results[i] = added
-                    if conflicting is not None:
-                        # equivocation flag BEFORE the single-raise
-                        # fold: every conflicting pair is ledgered even
-                        # when several land in one batch
-                        _valstats.on_equivocation(vote)
-                        if conflict is None:
-                            conflict = ErrVoteConflictingVotes(
-                                conflicting, vote)
+                        if added and fused:
+                            applied_power += val.voting_power
+                        results[i] = added
+                        if conflicting is not None:
+                            # equivocation flag BEFORE the single-raise
+                            # fold: every conflicting pair is ledgered even
+                            # when several land in one batch
+                            _valstats.on_equivocation(vote)
+                            if conflict is None:
+                                conflict = ErrVoteConflictingVotes(
+                                    conflicting, vote)
                 if fused:
                     # every valid lane was a fresh add, so the device sum IS
                     # the _sum delta; a divergence from the host bookkeeping

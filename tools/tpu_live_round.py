@@ -1,10 +1,14 @@
 """One LIVE 10k-validator consensus round on the chip: proposal->commit
 wall time with the device doing every batched verify dispatch.
 
-A thin caller of tmtpu/e2e/flood_round.py (one running validator + 9,999
-MockPV co-signers flooding ~20k votes through the consensus receive
-loop's batch-drain window), which chip_smoke.py and
-tests/test_tpu_integration.py share. ``--mixed`` splits the co-signers
+A thin caller of tmtpu/e2e/flood_round.py ``run``: one height of that
+module's many-height scripted network (one running validator, built as
+node/node.py builds it, + 9,999 MockPV co-signers whose ~20k votes one
+relay peer hands to ``ConsensusReactor.receive``), with the validator
+given the power to propose the height itself. chip_smoke.py and
+tests/test_tpu_integration.py share it; height after height with the
+proposals coming from peers is the benchmark cell
+``valset10k.live-rounds`` (benchmarks/drivers/live_rounds.py). ``--mixed`` splits the co-signers
 round-robin across ed25519 / sr25519 / secp256k1 (reference max-valset
 constant: types/vote_set.go:14-19; mixed-curve valsets are the BASELINE
 "Curves" row), so one commit's verify traffic dispatches to all three
