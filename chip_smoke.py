@@ -493,9 +493,16 @@ def live_round_phase(n_validators: int, seed: int, fails: Failures,
         f"round_lanes={r['lanes_dispatched']} "
         f"round_in_dispatch={r['dispatch_s']:.2f}s")
     say(f"  live round total (warm-up + round): {meter.line()}")
-    if 3 * r["precommits_in_commit"] <= 2 * r["validators"]:
+    # by power, as the protocol counts: the live validator's own is 40, so
+    # fewer than 2/3 of the signatures can close a small set's commit
+    vals = r["vals"]
+    power = sum(v.voting_power for v, s in
+                zip(vals.validators, r["commit"].signatures)
+                if not s.is_absent())
+    if 3 * power <= 2 * vals.total_voting_power():
         fails.fail(f"live round: commit holds only "
-                   f"{r['precommits_in_commit']} precommits")
+                   f"{r['precommits_in_commit']} precommits, {power} of "
+                   f"{vals.total_voting_power()} in power")
     if n_co >= 64 and r["lanes_dispatched"] < 1.5 * n_co:
         # all prevotes plus the 2/3 of precommits that closed the commit
         # must have ridden batched dispatches

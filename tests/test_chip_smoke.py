@@ -110,7 +110,9 @@ def test_part_a_phases_at_tiny_size_and_the_gate(closed_breakers):
     assert narrow["ed25519.mask.16"]["mask"] == \
         out["ed25519.mask.48"]["mask"][:16]
     r = chip_smoke.live_round_phase(40, 3, fails, meter, backend="cpu")
-    assert r["validators"] == 40 and r["precommits_in_commit"] >= 27
+    # the live validator holds 40 of the set's 79 in power: its own
+    # precommit and 13 more pass 2/3, however many the drain then held
+    assert r["validators"] == 40 and r["precommits_in_commit"] >= 14
     chip_smoke.gate(fails, meter, expect_device=False)
     assert fails == []
     # the same observations fail the real gate: nothing ran on a TPU,

@@ -20,7 +20,7 @@ import queue
 import threading
 import time
 import traceback
-from typing import List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from tmtpu.config.config import ConsensusConfig
 from tmtpu.consensus.ticker import TimeoutInfo, TimeoutTicker
@@ -31,6 +31,7 @@ from tmtpu.consensus.types import (
 )
 from tmtpu.consensus.wal import (
     EndHeightPB, EventRoundStatePB, MsgInfoPB, TimeoutInfoPB, WAL,
+    vote_record_template,
 )
 from tmtpu.crypto import batch as _crypto_batch
 from tmtpu.libs import metrics as _m
@@ -400,9 +401,9 @@ class ConsensusState(BaseService):
                     # trace id — None (unsampled) is a no-op
                     with trace.activate(
                             trace.height_context(self.rs.height)):
-                        with trace.span("consensus.wal", msgs=len(msgs)):
-                            for mi in msgs:
-                                self._wal_write_msg(mi)
+                        with trace.span("consensus.wal",
+                                        msgs=len(msgs)) as sp:
+                            sp.set(template=self._wal_write_msgs(msgs))
                         self._handle_msgs(msgs)
                         for ti in timeouts:
                             if self.wal is not None:
@@ -503,26 +504,59 @@ class ConsensusState(BaseService):
                 break
         return msgs, timeouts
 
-    def _wal_write_msg(self, mi: MsgInfo) -> None:
-        if self.wal is None or self.replay_mode:
-            return
-        m = mi.msg
-        if isinstance(m, ProposalMessage):
-            info = MsgInfoPB(peer_id=mi.peer_id,
-                             proposal=m.proposal.to_proto())
-        elif isinstance(m, BlockPartMessage):
-            info = MsgInfoPB(peer_id=mi.peer_id, block_part_height=m.height,
-                             block_part_round=m.round,
-                             block_part=m.part.to_proto())
-        elif isinstance(m, VoteMessage):
-            info = MsgInfoPB(peer_id=mi.peer_id, vote=m.vote.to_proto())
-        else:
-            return
-        if mi.peer_id == "":
-            # own messages are fsync'd before processing (state.go:763)
-            self.wal.write_sync(self.wal.make(msg_info=info))
-        else:
-            self.wal.write(self.wal.make(msg_info=info))
+    def _wal_write_msgs(self, msgs: List[MsgInfo]) -> int:
+        """A drain's records, every one handed to the file before
+        ``_handle_msgs`` sees any of the drain's messages; returns how many
+        came from a template. The votes that share (peer, type, height,
+        round, block id) are encoded from one template (wal.py
+        ``vote_record_template``), every other kind by the reflective
+        encoder; each record has its own time. The records go to the file
+        a run at a time (``WAL.write_records``), and a run ends at every
+        message of the node's own, which is fsync'd before it is processed
+        (state.go:763)."""
+        wal = self.wal
+        if wal is None or self.replay_mode:
+            return 0
+        templates: Dict[tuple, Callable[[int, Vote], bytes]] = {}
+        run: List[bytes] = []
+        n_template = n_reflective = 0
+        for mi in msgs:
+            m = mi.msg
+            if isinstance(m, VoteMessage):
+                v = m.vote
+                bid = v.block_id
+                key = (mi.peer_id, v.type, v.height, v.round,
+                       bid.hash, bid.parts_total, bid.parts_hash)
+                payload = templates.get(key)
+                if payload is None:
+                    payload = templates[key] = vote_record_template(
+                        mi.peer_id, v.type, v.height, v.round, bid)
+                run.append(payload(time.time_ns(), v))
+                n_template += 1
+            else:
+                if isinstance(m, ProposalMessage):
+                    info = MsgInfoPB(peer_id=mi.peer_id,
+                                     proposal=m.proposal.to_proto())
+                elif isinstance(m, BlockPartMessage):
+                    info = MsgInfoPB(peer_id=mi.peer_id,
+                                     block_part_height=m.height,
+                                     block_part_round=m.round,
+                                     block_part=m.part.to_proto())
+                else:
+                    continue
+                run.append(wal.make(msg_info=info).encode())
+                n_reflective += 1
+            if mi.peer_id == "":
+                wal.write_records(run)
+                wal.flush_and_sync()
+                run = []
+        if run:
+            wal.write_records(run)
+        if n_template:
+            _m.consensus_wal_records.inc(n_template, path="template")
+        if n_reflective:
+            _m.consensus_wal_records.inc(n_reflective, path="reflective")
+        return n_template
 
     def _handle_msgs(self, msgs: List[MsgInfo]) -> None:
         """Group votes for batch verification; other messages in order."""
@@ -684,11 +718,11 @@ class ConsensusState(BaseService):
         # WAL-then-process inline: we are already inside the receive loop
         # (the reference round-trips via internalMsgQueue; same ordering)
         mi = MsgInfo(ProposalMessage(proposal), "")
-        self._wal_write_msg(mi)
+        self._wal_write_msgs([mi])
         self._set_proposal_safe(proposal)
         for i in range(parts.total):
             bpm = BlockPartMessage(height, round, parts.get_part(i))
-            self._wal_write_msg(MsgInfo(bpm, ""))
+            self._wal_write_msgs([MsgInfo(bpm, "")])
             self._add_proposal_block_part(bpm, "")
         if self.on_own_proposal is not None:
             self.on_own_proposal(proposal, parts)
@@ -1167,7 +1201,7 @@ class ConsensusState(BaseService):
                                              parts), ""))).start()
             return
         mi = MsgInfo(VoteMessage(vote), "")
-        self._wal_write_msg(mi)
+        self._wal_write_msgs([mi])
         self._try_add_votes([(vote, "")])
         if self.on_own_vote is not None:
             self.on_own_vote(vote)

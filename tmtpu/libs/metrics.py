@@ -338,6 +338,18 @@ consensus_vote_flush_lanes = DEFAULT.histogram(
     "consensus", "vote_flush_lanes",
     "Votes one VoteSet.add_votes call handed to its batch verifier",
     buckets=(1, 8, 64, 256, 512, 1024, 2048, 4096, 8192, 16384))
+# The WAL records of the receive loop (consensus/state.py _wal_write_msgs,
+# consensus/wal.py): a drain's votes are encoded from a per-group template,
+# everything else by the reflective encoder; moved once a drain by the
+# drain's exact counts. An append is one file write, of one record or a run.
+consensus_wal_records = DEFAULT.counter(
+    "consensus", "wal_records_total",
+    "WAL records written, by how the payload was encoded: template (a "
+    "drain's vote records) or reflective (every other kind)",
+    labels=("path",))
+consensus_wal_appends = DEFAULT.counter(
+    "consensus", "wal_appends_total",
+    "File writes the WAL made: one a run of records")
 # Per-step latency breakdown (consensus/metrics.go StepDurationSeconds
 # in later reference releases: ONE histogram with a step label): time
 # spent in each round step, observed on every step transition by
