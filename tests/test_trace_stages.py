@@ -149,6 +149,72 @@ def test_no_annotation_is_constructed_without_a_profiler_session(
                                                  "loud"]
 
 
+def test_a_collection_in_a_profiler_session_is_an_annotation(monkeypatch):
+    """The collector's hook gives a collection of generation 1 or 2 the
+    annotation any span has, entered when it starts and left when it
+    stops; a young one, and any without a session, gets none."""
+    import gc
+
+    import jax
+
+    class Annotation(_CountingAnnotation):
+        open_now = 0
+        names = []
+
+        def __enter__(self):
+            type(self).open_now += 1
+            type(self).names.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            type(self).open_now -= 1
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        Annotation.enabled = False
+        gc.collect(2)
+        assert Annotation.names == []
+        Annotation.enabled = True
+        with trace.span("gc.parent"):
+            gc.collect(0)
+            gc.collect(2)
+    finally:
+        Annotation.enabled = False
+        if was:
+            gc.enable()
+    assert Annotation.names == ["gc.parent", "gc.collect"]
+    assert Annotation.open_now == 0
+
+
+def test_profiler_trace_holds_a_collection_under_its_name():
+    """In a real session (XLA:CPU here) the reduced trace has the
+    ``gc.collect`` span, inside the span it interrupted."""
+    import gc
+
+    import jax.numpy as jnp
+
+    from benchmarks.lib import devtrace
+
+    jnp.arange(8).sum().block_until_ready()
+    tracer = devtrace.Tracer(emulated=True)
+    tracer.start()
+    try:
+        with trace.span("gc.parent"):
+            junk = [[i] for i in range(50_000)]
+            gc.collect(2)
+            del junk
+        jnp.arange(8).sum().block_until_ready()
+    finally:
+        red = tracer.stop()
+    assert red is not None
+    spans = red["spans"]
+    assert spans["gc.collect"][1] >= 1
+    assert 0 < spans["gc.collect"][0] <= spans["gc.parent"][0]
+
+
 def test_span_never_imports_jax(tmp_path):
     """The benchmark's node runs with a poisoned ``jax`` on its path (a
     sidecar node must never open the chip): spans there must not try."""

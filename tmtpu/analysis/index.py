@@ -48,7 +48,8 @@ FAULT_ENSURE_RE = re.compile(
     r"(?:faultinject\.ensure|fail\.fail_point|(?<![.\w])fail_point)"
     r"\(\s*[\"']([^\"']+)[\"']")
 _METRIC_DEF_RE = re.compile(
-    r"DEFAULT\.(?:counter|gauge|histogram)\(\s*[\"'](\w+)[\"'],"
+    r"DEFAULT\.(?:counter|gauge|histogram|summary_metric)"
+    r"\(\s*[\"'](\w+)[\"'],"
     r"\s*[\"'](\w+)[\"']", re.S)
 _TIMELINE_CONST_RE = re.compile(r"EVENT_\w+\s*=\s*[\"']([\w.]+)[\"']")
 _TIMELINE_RECORD_RE = re.compile(
@@ -283,7 +284,8 @@ class RepoIndex:
                         continue
                     fn = node.value.func
                     if not (isinstance(fn, ast.Attribute) and
-                            fn.attr in ("counter", "gauge", "histogram")):
+                            fn.attr in ("counter", "gauge", "histogram",
+                                        "summary_metric")):
                         continue
                     args = node.value.args
                     if len(args) < 2 or not all(

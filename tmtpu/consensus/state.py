@@ -49,11 +49,14 @@ from tmtpu.types.vote_set import VoteSet
 
 
 class MsgInfo:
-    __slots__ = ("msg", "peer_id")
+    # enq_s: perf_counter at the put on the peers' queue (0.0: not put
+    # there — an internal message, or a test's own)
+    __slots__ = ("msg", "peer_id", "enq_s")
 
     def __init__(self, msg, peer_id: str = ""):
         self.msg = msg
         self.peer_id = peer_id
+        self.enq_s = 0.0
 
 
 class ProposalMessage:
@@ -305,16 +308,27 @@ class ConsensusState(BaseService):
 
     # -- inbound ------------------------------------------------------------
 
+    def _put_peer_msg(self, mi: MsgInfo) -> None:
+        """The reactor's hand-over. A full queue blocks the peer's thread
+        until the consensus thread has drained, and only that wait is
+        timed: the put that finds room pays for one clock read."""
+        mi.enq_s = t = time.perf_counter()
+        try:
+            self.peer_msg_queue.put_nowait(mi)
+        except queue.Full:
+            self.peer_msg_queue.put(mi)
+            _m.consensus_peer_queue_blocked.observe(time.perf_counter() - t)
+
     def add_proposal(self, proposal: Proposal, peer_id: str = "") -> None:
-        self.peer_msg_queue.put(MsgInfo(ProposalMessage(proposal), peer_id))
+        self._put_peer_msg(MsgInfo(ProposalMessage(proposal), peer_id))
 
     def add_block_part(self, height: int, round: int, part: Part,
                        peer_id: str = "") -> None:
-        self.peer_msg_queue.put(
+        self._put_peer_msg(
             MsgInfo(BlockPartMessage(height, round, part), peer_id))
 
     def add_vote_msg(self, vote: Vote, peer_id: str = "") -> None:
-        self.peer_msg_queue.put(MsgInfo(VoteMessage(vote), peer_id))
+        self._put_peer_msg(MsgInfo(VoteMessage(vote), peer_id))
 
     # ------------------------------------------------- state initialization
 
@@ -502,6 +516,11 @@ class ConsensusState(BaseService):
                 timeouts.append(self._timeout_queue.get_nowait())
             except queue.Empty:
                 break
+        # how long the peers' messages lay in their queue, once a drain
+        now = time.perf_counter()
+        waits = [now - mi.enq_s for mi in msgs if mi.enq_s]
+        if waits:
+            _m.consensus_peer_queue_wait.observe(sum(waits), len(waits))
         return msgs, timeouts
 
     def _wal_write_msgs(self, msgs: List[MsgInfo]) -> int:

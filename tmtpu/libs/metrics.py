@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from tmtpu.libs import trace as _trace
@@ -207,6 +208,54 @@ class Histogram(_Metric):
             }
 
 
+class Summary(_Metric):
+    """A Prometheus summary without quantiles: ``_count`` and ``_sum``.
+    ``observe`` takes a whole batch at once (its seconds and how many
+    observations they are), so a hot loop moves it once."""
+
+    def __init__(self, name: str, help_: str):
+        super().__init__(name, help_, ())
+        self._count = 0
+        self._sum = 0.0
+
+    def observe(self, total: float, count: int = 1) -> None:
+        with self._lock:
+            self._count += count
+            self._sum += total
+
+    def totals(self) -> Tuple[int, float]:
+        with self._lock:
+            return self._count, self._sum
+
+    def render(self, kind: str) -> List[str]:
+        count, total = self.totals()
+        return [f"# HELP {self.name} {_esc_help(self.help)}",
+                f"# TYPE {self.name} summary",
+                f"{self.name}_sum {_fmt(total)}",
+                f"{self.name}_count {count}"]
+
+    def summary_series(self) -> Dict[str, Dict[str, float]]:
+        count, total = self.totals()
+        return {"": {"count": count, "sum": round(total, 6)}}
+
+
+class CounterView(_Metric):
+    """An unlabelled counter whose number something else keeps:
+    ``read()`` gives it when the registry is read."""
+
+    def __init__(self, name: str, help_: str, read):
+        super().__init__(name, help_, ())
+        self._read = read
+
+    def render(self, kind: str) -> List[str]:
+        return [f"# HELP {self.name} {_esc_help(self.help)}",
+                f"# TYPE {self.name} counter",
+                f"{self.name} {_fmt(self._read())}"]
+
+    def summary_series(self) -> Dict[str, float]:
+        return {"": round(self._read(), 6)}
+
+
 class SummaryView(_Metric):
     """A family of Prometheus summaries (``_count`` and ``_sum``, no
     quantiles) whose numbers another module keeps: ``read()`` gives
@@ -259,6 +308,16 @@ class Registry:
         return self._get(
             subsystem, name, "summary",
             lambda full: SummaryView(full, help_, label, read))
+
+    def summary_metric(self, subsystem: str, name: str,
+                       help_: str = "") -> Summary:
+        return self._get(subsystem, name, "summary",
+                         lambda full: Summary(full, help_))
+
+    def counter_view(self, subsystem: str, name: str, help_: str,
+                     read) -> CounterView:
+        return self._get(subsystem, name, "counter",
+                         lambda full: CounterView(full, help_, read))
 
     def _get(self, subsystem, name, kind, make):
         full = f"{_NAMESPACE}_{subsystem}_{name}"
@@ -350,6 +409,16 @@ consensus_wal_records = DEFAULT.counter(
 consensus_wal_appends = DEFAULT.counter(
     "consensus", "wal_appends_total",
     "File writes the WAL made: one a run of records")
+# The one queue of the vote path, between the reactor's threads and the
+# consensus thread. Internal messages and timeouts are not in it.
+consensus_peer_queue_blocked = DEFAULT.summary_metric(
+    "consensus", "peer_queue_blocked_seconds",
+    "Puts that found the peers' message queue full, and the seconds their "
+    "threads waited for room: the producer outruns the consensus thread")
+consensus_peer_queue_wait = DEFAULT.summary_metric(
+    "consensus", "peer_queue_wait_seconds",
+    "Peers' messages drained, and the seconds they lay in the queue, from "
+    "the reactor's put to the drain that took them (moved once a drain)")
 # Per-step latency breakdown (consensus/metrics.go StepDurationSeconds
 # in later reference releases: ONE histogram with a step label): time
 # spent in each round step, observed on every step transition by
@@ -541,6 +610,30 @@ trace_span_seconds = DEFAULT.summary_view(
     "trace", "span_seconds",
     "Spans ended since the process started and their total seconds, by "
     "span name (libs/trace.py)", "name", _trace.span_totals)
+trace_span_cpu_seconds = DEFAULT.summary_view(
+    "trace", "span_cpu_seconds",
+    "The same spans and the CPU seconds their own threads burned inside "
+    "them (time.thread_time): beside span_seconds, what a span did itself "
+    "and what it spent waiting, for the interpreter lock or for what it "
+    "is named for", "name", _trace.span_cpu_totals)
+
+
+# --- the runtime under every layer ------------------------------------------
+#
+# Read, not written: the collector's pauses are kept by libs/trace's
+# gc.callbacks hook (which also makes a collection of generation 1 or 2 a
+# gc.collect span), the process's CPU seconds by the OS.
+
+runtime_gc_pause_seconds = DEFAULT.summary_view(
+    "runtime", "gc_pause_seconds",
+    "Collections of the cyclic garbage collector since the process "
+    "started and the seconds they stopped the thread they ran on, by "
+    "generation", "generation", _trace.gc_pause_totals)
+runtime_process_cpu_seconds = DEFAULT.counter_view(
+    "runtime", "process_cpu_seconds",
+    "CPU seconds of the whole process, user and system, every thread "
+    "(time.process_time): over a window, the share of one core it burned",
+    time.process_time)
 
 
 # --- the node health engine metric set (libs/watchdog.py) -------------------

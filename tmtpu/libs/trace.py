@@ -15,13 +15,56 @@ ring buffer:
     def _enter_propose(self, ...): ...
 
 Spans nest per thread (a thread-local stack carries the current parent),
-carry arbitrary JSON-able attrs, and cost a few µs each (≈6 on the
-builders' CPU host) — cheap enough to leave on permanently. The ring holds the most recent ``capacity`` spans
+carry arbitrary JSON-able attrs, and cost a few µs each (3–4 on the
+builders' CPU host, 4.7 on the chip's) — cheap enough to leave on
+permanently.
+
+What a span records: its wall seconds (``perf_counter`` at both ends,
+``duration_s``) and the CPU seconds its own thread burned meanwhile
+(``time.thread_time()``, ``cpu_s``; enter and exit run on one thread, a
+``resume``d worker's spans on the worker). How to read the two side by
+side:
+
+- a span that does not block (a decode, ``vote_set.collect``, ``.apply``,
+  the sigcache, a store's encoding): ``wall - cpu`` is time the thread was
+  runnable and not running — another thread's turn at the interpreter
+  lock, or the OS;
+- a span named for what it waits on (``batch.dispatch``,
+  ``consensus.idle``, ``ed25519.execute``, ``mempool.verify``, the fsync in
+  ``consensus.wal``): ``wall - cpu`` is that wait;
+- C code that runs on threads of its own (``native/hostprep.c`` at eight
+  threads) is not in the caller's ``cpu_s``; the process's is
+  ``tendermint_runtime_process_cpu_seconds``.
+
+A read of a thread's CPU clock is a system call, which a span may not cost:
+a thread reads its clock when a span begins or ends and the last read is
+``_CPU_CLOCK_INTERVAL_S`` old (four hundred times what a read costs on
+the host, so the reading takes a quarter of a per cent of a thread's time
+at most: 2.4 ms on the chip's host, whose kernel steps the clock by 10 ms
+anyway, 0.1 ms on the builders' sandbox), and
+what it burned between two reads goes to the spans open at the second. A
+span longer than the interval is exact to within it; of shorter ones the
+per-name totals are right where a thread's spans fill its time (a relay's
+decodes) and blurred over the interval where short spans of unlike kinds
+alternate; a single short span reads 0 or a neighbour's share.
+
+The collector's pauses are recorded here too (``gc.callbacks``, hooked
+once when this module is imported): every collection moves
+``tendermint_runtime_gc_pause_seconds{generation}``, and one of generation
+1 or 2 is also a span ``gc.collect`` (attrs ``generation``, ``collected``)
+whose parent is the span open on the thread it interrupted — so a pause
+comes off its parent's self time under a name of its own. Generation 0
+runs hundreds of times a second on the lane loops: a span each would cost
+what it measures.
+
+The ring holds the most recent ``capacity`` spans
 (default 8192, env ``TMTPU_TRACE_CAPACITY``); older spans are evicted and
 counted, never blocking the hot path. Two things outlive the ring:
 
-- per-name cumulative ``(count, seconds)`` totals (``span_totals()``),
-  served as ``tendermint_trace_span_seconds{name}`` by libs/metrics, so a
+- per-name cumulative ``(count, seconds, cpu seconds)`` totals
+  (``span_totals()``, ``span_cpu_totals()``), served as
+  ``tendermint_trace_span_seconds{name}`` and
+  ``tendermint_trace_span_cpu_seconds{name}`` by libs/metrics, so a
   process nobody can profile is still differenced over a window;
 - while a ``jax.profiler`` session runs in this process every span is also
   a ``jax.profiler.TraceAnnotation`` of the same name, so the profiler's
@@ -32,7 +75,7 @@ counted, never blocking the hot path. Two things outlive the ring:
 Export formats:
 - ``to_chrome_trace(spans)``: the Chrome trace-event JSON (load in
   chrome://tracing or Perfetto) — complete "X" events, microsecond
-  timestamps on the perf_counter clock;
+  timestamps on the perf_counter clock, ``args.cpu_us`` the CPU time;
 - ``to_jsonl(spans)``: one JSON object per line (grep/jq-friendly).
 
 Drained over RPC at ``/debug/traces`` on the pprof server
@@ -42,6 +85,7 @@ Drained over RPC at ``/debug/traces`` on the pprof server
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import itertools
 import json
@@ -186,14 +230,45 @@ def _profiler_annotation(name: str):
     return cls(name)
 
 
+def _cpu_clock_interval() -> float:
+    """Seconds a thread lets pass between two reads of its CPU clock:
+    four hundred times what a read costs on this host, and at most the
+    interpreter's 5 ms switch interval. ``time.thread_time()`` is a real
+    system call where ``perf_counter`` is not: 0.3 µs on the builders'
+    sandbox, 6 µs under the sandboxed kernel of the chip's host, where two
+    reads a span cost live rounds a sixth of a traced height and the
+    served cell 2.6% of its rate (PERF.md, PR 36)."""
+    cost = 1.0
+    for _ in range(5):
+        t = time.perf_counter()
+        time.thread_time()
+        cost = min(cost, time.perf_counter() - t)
+    return min(400.0 * cost, 0.005)
+
+
+_CPU_CLOCK_INTERVAL_S = _cpu_clock_interval()
+
+
+def _thread_cpu(clock: list, now: float) -> float:
+    """The calling thread's CPU seconds as of its last read of the clock,
+    read again when ``now`` (``perf_counter``) is an interval past it.
+    ``clock`` is the thread's own [when to read next, the value read]."""
+    if now >= clock[0]:
+        clock[1] = time.thread_time()
+        clock[0] = now + _CPU_CLOCK_INTERVAL_S
+    return clock[1]
+
+
 class Span:
     """One completed (or in-flight) timed region. Times are
     ``time.perf_counter()`` seconds — monotonic, comparable across spans
-    in-process; ``wall_time`` anchors the trace to the epoch clock."""
+    in-process; ``wall_time`` anchors the trace to the epoch clock.
+    ``cpu_s`` is the ``time.thread_time()`` its thread spent inside (0 for
+    a mark and for a span still open)."""
 
     __slots__ = ("name", "span_id", "parent_id", "thread_id", "thread_name",
-                 "start_s", "end_s", "attrs", "trace_id", "ctx_parent",
-                 "origin")
+                 "start_s", "end_s", "cpu_s", "attrs", "trace_id",
+                 "ctx_parent", "origin")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
                  thread_id: int, thread_name: str, start_s: float,
@@ -205,6 +280,7 @@ class Span:
         self.thread_name = thread_name
         self.start_s = start_s
         self.end_s: Optional[float] = None
+        self.cpu_s = 0.0
         self.attrs = attrs
         # cross-process causal identity (None/0/"" ⇒ untraced span)
         self.trace_id: Optional[str] = None
@@ -221,6 +297,11 @@ class Span:
         """Attach attrs mid-span (e.g. a batch size known only later)."""
         self.attrs.update(attrs)
 
+    def set_context(self, ctx: "TraceContext") -> None:
+        self.trace_id = ctx.trace_id
+        self.ctx_parent = ctx.parent_span_id
+        self.origin = ctx.origin
+
     def to_dict(self) -> Dict:
         d = {
             "name": self.name, "id": self.span_id,
@@ -228,6 +309,7 @@ class Span:
             "thread": self.thread_name,
             "start_s": round(self.start_s, 9),
             "dur_s": round(self.duration_s, 9),
+            "cpu_s": round(self.cpu_s, 9),
             "attrs": self.attrs,
         }
         if self.trace_id:
@@ -248,14 +330,20 @@ class Tracer:
 
     def __init__(self, capacity: int = _DEFAULT_CAPACITY):
         self._buf: deque = deque(maxlen=max(1, capacity))
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start at an allocation made under
+        # it, and the collector's hook then records on the same thread
+        self._lock = threading.RLock()
         self._ids = itertools.count(1)
         self._tls = threading.local()
         self._enabled = True
         self._dropped = 0
-        # {span name: [count, seconds]} since process start; names are
-        # static strings, so its size is the number of call sites
+        # {span name: [count, seconds, cpu seconds]} since process start;
+        # names are static strings, so its size is the number of call sites
         self._totals: Dict[str, List] = {}
+        # the collector's pauses, [count, seconds] a generation, and the
+        # collection under way: [perf_counter, thread CPU | None, annotation]
+        self._gc_totals = [[0, 0.0], [0, 0.0], [0, 0.0]]
+        self._gc_open: List = [0.0, 0.0, None]
         # fleet identity + sampling for cross-process contexts
         self._node_id = ""
         self._chain_id = ""
@@ -301,6 +389,7 @@ class Tracer:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
+            self._tls.cpu_clock = [0.0, 0.0]    # see _thread_cpu
         return st
 
     @contextmanager
@@ -311,27 +400,38 @@ class Tracer:
         if not self._enabled:
             yield _NULL_SPAN
             return
+        tls = self._tls
+        stack = getattr(tls, "stack", None) or self._stack()
+        clock = tls.cpu_clock
         t = threading.current_thread()
-        stack = self._stack()
+        start_s = time.perf_counter()
         sp = Span(name, next(self._ids),
                   stack[-1].span_id if stack else None,
-                  t.ident or 0, t.name, time.perf_counter(), dict(attrs))
-        ctx = self.current_context()
-        if ctx is not None:
-            sp.trace_id = ctx.trace_id
-            sp.ctx_parent = ctx.parent_span_id
-            sp.origin = ctx.origin
+                  t.ident or 0, t.name, start_s, attrs)
+        ctxs = getattr(tls, "ctx", None)
+        if ctxs:
+            sp.set_context(ctxs[-1])
         stack.append(sp)
         ann = _profiler_annotation(name)
         if ann is not None:
             ann.__enter__()
+        # _thread_cpu and _record, written out: a call is a fifth of what
+        # a span may cost
+        if start_s >= clock[0]:
+            clock[1] = time.thread_time()
+            clock[0] = start_s + _CPU_CLOCK_INTERVAL_S
+        cpu0 = clock[1]
         try:
             yield sp
         except BaseException:
             sp.attrs["error"] = True
             raise
         finally:
-            sp.end_s = time.perf_counter()
+            sp.end_s = end_s = time.perf_counter()
+            if end_s >= clock[0]:
+                clock[1] = time.thread_time()
+                clock[0] = end_s + _CPU_CLOCK_INTERVAL_S
+            sp.cpu_s = cpu_s = clock[1] - cpu0
             if ann is not None:
                 ann.__exit__(None, None, None)
             stack.pop()
@@ -339,9 +439,75 @@ class Tracer:
                 if len(self._buf) == self._buf.maxlen:
                     self._dropped += 1
                 self._buf.append(sp)
-                tot = self._totals.setdefault(name, [0, 0.0])
+                tot = self._totals.setdefault(name, [0, 0.0, 0.0])
                 tot[0] += 1
-                tot[1] += sp.end_s - sp.start_s
+                tot[1] += end_s - start_s
+                tot[2] += cpu_s
+
+    def _record(self, sp: Span) -> None:
+        """An ended span into the ring and the totals."""
+        with self._lock:
+            if len(self._buf) == self._buf.maxlen:
+                self._dropped += 1
+            self._buf.append(sp)
+            tot = self._totals.setdefault(sp.name, [0, 0.0, 0.0])
+            tot[0] += 1
+            tot[1] += sp.end_s - sp.start_s
+            tot[2] += sp.cpu_s
+
+    # -- the collector ------------------------------------------------------
+
+    def hook_gc(self) -> None:
+        """Record every collection of this process from now on (once: the
+        module does it for ``DEFAULT``). The ``gc.collect`` series is made
+        here so that the hook never adds a key to a table a reader may be
+        walking on the same thread."""
+        with self._lock:
+            self._totals.setdefault("gc.collect", [0, 0.0, 0.0])
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        """``gc.callbacks`` entry. Runs on the thread whose allocation
+        started the collection, one collection at a time; nothing may
+        start another inside it, so the slots are fixed."""
+        gen = info["generation"]
+        slot = self._gc_open
+        if phase == "start":
+            slot[0] = time.perf_counter()
+            slot[1] = None                  # no span: the counter only
+            if gen and self._enabled:
+                slot[2] = ann = _profiler_annotation("gc.collect")
+                if ann is not None:
+                    ann.__enter__()
+                self._stack()               # makes the thread's clock
+                slot[1] = _thread_cpu(self._tls.cpu_clock, slot[0])
+            return
+        now = time.perf_counter()
+        tot = self._gc_totals[gen]
+        tot[0] += 1
+        tot[1] += now - slot[0]
+        cpu0 = slot[1]
+        if cpu0 is None:
+            return
+        ann, slot[2] = slot[2], None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        stack = self._stack()
+        t = threading.current_thread()
+        sp = Span("gc.collect", next(self._ids),
+                  stack[-1].span_id if stack else None, t.ident or 0, t.name,
+                  slot[0], {"generation": gen, "collected": info["collected"]})
+        ctx = self.current_context()
+        if ctx is not None:
+            sp.set_context(ctx)
+        sp.end_s = now
+        sp.cpu_s = _thread_cpu(self._tls.cpu_clock, now) - cpu0
+        self._record(sp)
+
+    def gc_pause_totals(self) -> Dict[str, Tuple[int, float]]:
+        """{generation: (collections, seconds)} since ``hook_gc``."""
+        return {str(g): (t[0], t[1]) for g, t in enumerate(self._gc_totals)}
 
     def annotate(self, **attrs) -> None:
         """Attach attrs to the innermost span open on this thread (none
@@ -447,9 +613,7 @@ class Tracer:
                   now, dict(attrs))
         sp.end_s = now
         if ctx is not None:
-            sp.trace_id = ctx.trace_id
-            sp.ctx_parent = ctx.parent_span_id
-            sp.origin = ctx.origin
+            sp.set_context(ctx)
         with self._lock:
             if len(self._buf) == self._buf.maxlen:
                 self._dropped += 1
@@ -507,23 +671,30 @@ class Tracer:
         with self._lock:
             return {n: (t[0], t[1]) for n, t in self._totals.items()}
 
+    def span_cpu_totals(self) -> Dict[str, Tuple[int, float]]:
+        """{name: (count, CPU seconds of the spans' own threads)}: the
+        same spans as ``span_totals``."""
+        with self._lock:
+            return {n: (t[0], t[2]) for n, t in self._totals.items()}
+
     def summary(self) -> Dict:
-        """Aggregate per span name: {name: {count, total_s, max_s}} plus
-        ring bookkeeping — the cheap form served by the ``metrics``
-        JSON-RPC method."""
+        """Aggregate per span name: {name: {count, total_s, cpu_s,
+        max_s}} plus ring bookkeeping — the cheap form served by the
+        ``metrics`` JSON-RPC method."""
         spans = self.snapshot()
         agg: Dict[str, Dict] = {}
         for sp in spans:
-            a = agg.setdefault(sp.name,
-                               {"count": 0, "total_s": 0.0, "max_s": 0.0})
+            a = agg.setdefault(sp.name, {"count": 0, "total_s": 0.0,
+                                         "cpu_s": 0.0, "max_s": 0.0})
             a["count"] += 1
             d = sp.duration_s
             a["total_s"] += d
+            a["cpu_s"] += sp.cpu_s
             if d > a["max_s"]:
                 a["max_s"] = d
         for a in agg.values():
-            a["total_s"] = round(a["total_s"], 6)
-            a["max_s"] = round(a["max_s"], 6)
+            for k in ("total_s", "cpu_s", "max_s"):
+                a[k] = round(a[k], 6)
         return {"spans": agg, "buffered": len(spans),
                 "dropped": self._dropped,
                 "capacity": self._buf.maxlen, "enabled": self._enabled}
@@ -550,7 +721,8 @@ def to_chrome_trace(spans: List[Span]) -> Dict:
     per thread. Span ids/parents ride in args for tooling."""
     events = []
     for sp in spans:
-        args = dict(sp.attrs, span_id=sp.span_id, parent_id=sp.parent_id)
+        args = dict(sp.attrs, span_id=sp.span_id, parent_id=sp.parent_id,
+                    cpu_us=sp.cpu_s * 1e6)
         if sp.trace_id:
             args["trace"] = sp.trace_id
             args["ctx_parent"] = sp.ctx_parent
@@ -586,6 +758,7 @@ def to_jsonl(spans: List[Span]) -> str:
 # -- process-global tracer + module-level API -------------------------------
 
 DEFAULT = Tracer()
+DEFAULT.hook_gc()
 
 
 def span(name: str, **attrs):
@@ -614,6 +787,14 @@ def summary() -> Dict:
 
 def span_totals() -> Dict[str, Tuple[int, float]]:
     return DEFAULT.span_totals()
+
+
+def span_cpu_totals() -> Dict[str, Tuple[int, float]]:
+    return DEFAULT.span_cpu_totals()
+
+
+def gc_pause_totals() -> Dict[str, Tuple[int, float]]:
+    return DEFAULT.gc_pause_totals()
 
 
 def handoff():
