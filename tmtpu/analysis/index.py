@@ -56,7 +56,7 @@ _TIMELINE_RECORD_RE = re.compile(
     r"record\(\s*[^,()]+,\s*[\"']([\w.]+)[\"']", re.S)
 _SPAN_RE = re.compile(
     r"""\btrace\.(?:traced|span)\(\s*["']([a-z0-9_.]+)["']""")
-METRIC_WRITE_RE = r"\.(?:inc|set|add|observe)\("
+METRIC_WRITE_RE = r"\.(?:inc|set|add|observe|bound)\("
 
 
 class FileInfo:

@@ -173,6 +173,12 @@ class ConsensusReactor(Reactor):
             cs.config, "gossip_sleep_ns", int(GOSSIP_SLEEP_S * 1e9)) / 1e9
         self._peer_threads: Dict[str, list] = {}
         self._stopped = threading.Event()
+        # the vote channel's decoder: it keeps the heads (type, height,
+        # round, block id) that the votes of a step share
+        self._vote_decoder = cm.VoteDecoder()
+        self._count_hand = _metrics.consensus_vote_decode.bound(path="hand")
+        self._count_reflective = _metrics.consensus_vote_decode.bound(
+            path="reflective")
         # outbound hooks from the state machine
         cs.on_own_vote = self._broadcast_own_vote
         cs.on_own_proposal = self._broadcast_own_proposal
@@ -305,6 +311,22 @@ class ConsensusReactor(Reactor):
             _metrics.trace_context_rx.inc(transport="gossip")
         return ctx
 
+    @staticmethod
+    def _peer_state(peer: Peer) -> PeerState:
+        ps: Optional[PeerState] = peer.get("consensus_peer_state")
+        if ps is None:
+            # never drop: a lost one-shot NewRoundStep wedges vote gossip
+            ps = PeerState()
+            peer.set("consensus_peer_state", ps)
+        return ps
+
+    def _vote_received(self, ps: PeerState, peer: Peer, vote: Vote):
+        vals = self.cs.round_state_nolock().validators
+        n = vals.size() if vals else 0
+        ps.set_has_vote(vote.height, vote.round, vote.type,
+                        vote.validator_index, n)
+        return self.cs.add_vote_msg, vote, peer.node_id
+
     def receive(self, channel_id: int, peer: Peer, msg_bytes: bytes) -> None:
         # decode and PeerState under the span; the hand-over to the state
         # machine after it, because a full queue blocks there and that wait
@@ -316,12 +338,19 @@ class ConsensusReactor(Reactor):
 
     def _receive(self, channel_id: int, peer: Peer, msg_bytes: bytes):
         """-> what to hand the state machine, as (method, *args), or None."""
+        if channel_id == VOTE_CHANNEL:
+            # a canonical vote message is decoded by hand; any other shape
+            # is "not mine" and takes the reflective path below
+            vote = self._vote_decoder.decode(msg_bytes)
+            if vote is not None:
+                self._count_hand()
+                ps = self._peer_state(peer)
+                if self.wait_sync:
+                    return
+                return self._vote_received(ps, peer, vote)
+            self._count_reflective()
         m = cm.ConsensusMessagePB.decode(msg_bytes)
-        ps: Optional[PeerState] = peer.get("consensus_peer_state")
-        if ps is None:
-            # never drop: a lost one-shot NewRoundStep wedges vote gossip
-            ps = PeerState()
-            peer.set("consensus_peer_state", ps)
+        ps = self._peer_state(peer)
         kind = m.which()
         if channel_id == STATE_CHANNEL:
             if kind == "new_round_step":
@@ -397,11 +426,7 @@ class ConsensusReactor(Reactor):
                     _trace.mark("gossip.vote_rx", ctx=ctx,
                                 height=vote.height, type=vote.type,
                                 peer=peer.node_id)
-                vals = self.cs.round_state_nolock().validators
-                n = vals.size() if vals else 0
-                ps.set_has_vote(vote.height, vote.round, vote.type,
-                                vote.validator_index, n)
-                return self.cs.add_vote_msg, vote, peer.node_id
+                return self._vote_received(ps, peer, vote)
         elif channel_id == VOTE_SET_BITS_CHANNEL:
             if kind == "vote_set_bits":
                 vb = m.vote_set_bits

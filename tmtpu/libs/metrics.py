@@ -12,7 +12,7 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from tmtpu.libs import trace as _trace
 
@@ -115,6 +115,18 @@ class Counter(_Metric):
         k = self._key(labels)
         with self._lock:
             self._values[k] = self._values.get(k, 0.0) + amount
+
+    def bound(self, **labels) -> Callable[..., None]:
+        """``inc`` for one label combination, its key made once: for a call
+        site that counts every message of a hot loop."""
+        k = self._key(labels)
+        values, lock = self._values, self._lock
+
+        def inc(amount: float = 1.0) -> None:
+            with lock:
+                values[k] = values.get(k, 0.0) + amount
+
+        return inc
 
 
 class Gauge(_Metric):
@@ -409,6 +421,16 @@ consensus_wal_records = DEFAULT.counter(
 consensus_wal_appends = DEFAULT.counter(
     "consensus", "wal_appends_total",
     "File writes the WAL made: one a run of records")
+# Every message a peer sends on the vote channel, by how the reactor turned
+# its bytes into a Vote (consensus/msgs.py VoteDecoder): by hand against the
+# step's shared head, or by the reflective decoder (another shape: a traced
+# height's trace_ctx, a peer that orders or repeats fields, a malformed one).
+consensus_vote_decode = DEFAULT.counter(
+    "consensus", "vote_decode_total",
+    "Vote-channel messages received, by how they were decoded: hand (the "
+    "canonical vote message, against its step's shared head) or reflective "
+    "(every other shape)",
+    labels=("path",))
 # The one queue of the vote path, between the reactor's threads and the
 # consensus thread. Internal messages and timeouts are not in it.
 consensus_peer_queue_blocked = DEFAULT.summary_metric(
