@@ -844,7 +844,16 @@ class TPUBatchVerifier(BatchVerifier):
 
         if cpu_idx:
             _serial(cpu_idx, "other", "unsupported")
-        for curve, (idx, pks, msgs, sigs, powers) in groups.items():
+        if groups:
+            _m.crypto_flush_curves.observe(len(groups))
+        # in the table's order, not the flush's: whichever lane a mixed
+        # flush starts with, a process traces its kernels in one order (the
+        # compile cache's keys were seen to differ with the kernel traced
+        # first: PERF.md section 6, PR 38)
+        for curve in _disp.CURVES:
+            if curve not in groups:
+                continue
+            idx, pks, msgs, sigs, powers = groups[curve]
             if len(idx) < _TPU_MIN_BATCH and not self.min_lanes:
                 # below this, dispatch overhead beats the serial path
                 _serial(idx, curve, "small-batch")

@@ -792,6 +792,15 @@ crypto_cpu_fallback = DEFAULT.counter(
     "crypto", "cpu_fallback_total",
     "Signatures verified on the serial CPU path instead of the device",
     labels=("curve", "reason"))
+crypto_flush_curves = DEFAULT.histogram(
+    "crypto", "flush_curves",
+    "Key types with a device batch in one flush of the device verifier "
+    "(each is a dispatch of its own, one after another)",
+    buckets=(1, 2, 3))
+crypto_sr_python_transcript_lanes = DEFAULT.counter(
+    "crypto", "sr_python_transcript_lanes_total",
+    "sr25519 lanes whose merlin challenge the host prep walked in pure "
+    "Python because the native library is not bound (some 300x slower)")
 # --- the verify-once hot path metric set (crypto/sigcache.py) ---------------
 #
 # Written by the process-wide verified-signature cache and the batch

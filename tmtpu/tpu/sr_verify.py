@@ -207,6 +207,9 @@ def prepare_sr_batch_packed(pks, msgs, sigs, padded: int = 0):
     # and differential oracle (tests/test_tpu_sr25519.py).
     k_arr = _native_challenges(pk_arr, r_arr, msgs)
     if k_arr is None:
+        from tmtpu.libs import metrics as _m
+
+        _m.crypto_sr_python_transcript_lanes.inc(B)
         k_arr = np.frombuffer(
             b"".join(
                 _challenge_k(p.tobytes(), bytes(m), r.tobytes())
