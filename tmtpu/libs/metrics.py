@@ -409,6 +409,14 @@ consensus_vote_flush_lanes = DEFAULT.histogram(
     "consensus", "vote_flush_lanes",
     "Votes one VoteSet.add_votes call handed to its batch verifier",
     buckets=(1, 8, 64, 256, 512, 1024, 2048, 4096, 8192, 16384))
+# The sign bytes of those flushes' lanes (types/vote_set.py add_votes): each
+# from its vote set's template for its block id, built at the first vote
+# that brought the block id; moved once a flush by the flush's exact counts.
+consensus_vote_sign_templates = DEFAULT.counter(
+    "consensus", "vote_sign_templates_total",
+    "Vote-flush lanes by where their sign-bytes template came from: hit "
+    "(one the vote set already held) or built (made for this lane)",
+    labels=("event",))
 # The WAL records of the receive loop (consensus/state.py _wal_write_msgs,
 # consensus/wal.py): a drain's votes are encoded from a per-group template,
 # everything else by the reflective encoder; moved once a drain by the

@@ -48,7 +48,9 @@ def vote_sign_bytes_template(chain_id: str, type: int, height: int,
     """The sign bytes of every vote that shares (chain_id, type, height,
     round, block_id), as a function of the one thing left to vary, the
     timestamp (unix nanos): byte for byte ``Vote.sign_bytes`` of such a
-    vote, without a CanonicalVote built and encoded per vote.
+    vote, without a CanonicalVote built and encoded per vote. Its callers:
+    ``Commit.vote_sign_bytes_for`` (a commit's precommits) and
+    ``VoteSet.add_votes`` (a vote set's flush, one template a block id).
 
     Fields 1-4 (up to the timestamp's tag) and field 6 (chain_id) are
     encoded once, by the encoder above, so what proto3 leaves off the wire

@@ -15,7 +15,7 @@ wrapper.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from tmtpu.crypto import batch as crypto_batch
 from tmtpu.libs import metrics as _metrics
@@ -26,7 +26,11 @@ from tmtpu.types.block import BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT, \
     BLOCK_ID_FLAG_NIL, BlockID, Commit, CommitSig
 from tmtpu.types.validator import ValidatorSet
 from tmtpu.types.vote import ErrVoteConflictingVotes, MAX_VOTES_COUNT, \
-    PRECOMMIT, Vote, VoteError, is_vote_type_valid
+    PRECOMMIT, Vote, VoteError, is_vote_type_valid, vote_sign_bytes_template
+
+# An honest set sees one or two block ids (the proposal's, nil); votes that
+# name more, signed or not, get a template each that the set does not keep.
+_MAX_SIGN_TEMPLATES = 16
 
 
 class _BlockVotes:
@@ -76,6 +80,11 @@ class VoteSet:
         self._maj23: Optional[BlockID] = None
         self._votes_by_block: Dict[bytes, _BlockVotes] = {}
         self._peer_maj23s: Dict[str, BlockID] = {}
+        # sign-bytes templates of the block ids this set's votes brought,
+        # keyed by the block id's whole content (BlockID.key() is not
+        # injective across hash lengths); read and filled under _lock
+        self._sign_templates: Dict[Tuple[bytes, int, bytes],
+                                   Callable[[int], bytes]] = {}
 
     # -- accessors ----------------------------------------------------------
 
@@ -179,12 +188,36 @@ class VoteSet:
                         and len({v.validator_index for _, v, *_r in prepared})
                         == len(prepared)
                     )
+                    # _pre_validate held every vote to the set's type,
+                    # height and round: only the block id and the timestamp
+                    # vary, so a lane's sign bytes are its block id's
+                    # template filled in with its timestamp
+                    templates = self._sign_templates
+                    hit = built = 0
                     for _, vote, val, _ in prepared:
-                        bv.add(val.pub_key, vote.sign_bytes(self.chain_id),
+                        bid = vote.block_id
+                        key = (bid.hash, bid.parts_total, bid.parts_hash)
+                        template = templates.get(key)
+                        if template is None:
+                            template = vote_sign_bytes_template(
+                                self.chain_id, self.signed_msg_type,
+                                self.height, self.round, bid)
+                            built += 1
+                            if len(templates) < _MAX_SIGN_TEMPLATES:
+                                templates[key] = template
+                        else:
+                            hit += 1
+                        bv.add(val.pub_key, template(vote.timestamp),
                                vote.signature,
                                power=val.voting_power if fused else 0)
 
             if prepared:
+                if hit:
+                    _metrics.consensus_vote_sign_templates.inc(
+                        hit, event="hit")
+                if built:
+                    _metrics.consensus_vote_sign_templates.inc(
+                        built, event="built")
                 _metrics.consensus_vote_flush_lanes.observe(len(prepared))
                 _, mask, dev_sum = bv.verify_tally()
                 applied_power = 0
