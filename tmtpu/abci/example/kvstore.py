@@ -93,6 +93,11 @@ class KVStoreApplication(abci.Application):
             vu = self._parse_val_tx(tx)
             if vu is None:
                 return abci.ResponseDeliverTx(code=1, log="invalid validator tx")
+            if vu.power == 0 and vu.pub_key.encode() not in self.validators:
+                # persistent_kvstore.go updateValidator: no update for it,
+                # which would fail UpdateWithChangeSet and halt the chain
+                return abci.ResponseDeliverTx(
+                    code=1, log="cannot remove non-existent validator")
             self.val_updates.append(vu)
             self._set_validator(vu)
         else:

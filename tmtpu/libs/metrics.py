@@ -856,6 +856,22 @@ types_valset_memo_misses = DEFAULT.counter(
     "types", "valset_memo_misses_total",
     "ValidatorSet.hash() / encode() calls that computed their bytes",
     labels=("what",))
+# The validator updates an EndBlock returned (state/execution.py
+# update_state, over ValidatorSet.update_with_change_set): a power change of
+# a member, a join, a leave (power 0); moved once a call by its exact counts.
+state_validator_updates = DEFAULT.counter(
+    "state", "validator_updates_total",
+    "Validator updates applied to the next-next validator set, by kind: "
+    "power (a member's power changed), join, leave",
+    labels=("kind",))
+# The lanes of a fused verify+tally flush (tpu/dispatch.py _flush) by the
+# power limbs they fill: one (the power fits the first 13-bit limb) or more
+# (it carries into limbs 1-4); moved once a flush by one count over the limbs.
+crypto_tally_power_lanes = DEFAULT.counter(
+    "crypto", "tally_power_lanes_total",
+    "Lanes of fused verify+tally flushes by the 13-bit power limbs their "
+    "power fills: one, or more",
+    labels=("limbs",))
 
 crypto_device_probe_attempts = DEFAULT.counter(
     "crypto", "device_probe_attempts_total",

@@ -15,6 +15,7 @@ from typing import List, Optional, Tuple
 from tmtpu.abci import types as abci
 from tmtpu.crypto.encoding import pubkey_from_proto
 from tmtpu.libs import faultinject, trace
+from tmtpu.libs import metrics as _metrics
 from tmtpu.state.state import State, median_time
 from tmtpu.state.store import ABCIResponses, StateStore
 from tmtpu.state.validation import validate_block
@@ -296,12 +297,16 @@ def update_state(state: State, block_id: BlockID, header,
                  abci_responses: ABCIResponses, val_updates: List[Validator]
                  ) -> State:
     """execution.go:403 updateState."""
-    n_val_set = state.next_validators.copy()
     last_height_vals_changed = state.last_height_validators_changed
-    if val_updates:
-        n_val_set.update_with_change_set(val_updates)
-        last_height_vals_changed = header.height + 1 + 1
-    n_val_set.increment_proposer_priority(1)
+    with trace.span("state.update_validators", changes=len(val_updates)):
+        n_val_set = state.next_validators.copy()
+        if val_updates:
+            kinds = n_val_set.update_with_change_set(val_updates)
+            for kind, n in kinds.items():
+                if n:
+                    _metrics.state_validator_updates.inc(n, kind=kind)
+            last_height_vals_changed = header.height + 1 + 1
+        n_val_set.increment_proposer_priority(1)
 
     params = state.consensus_params
     app_version = state.app_version

@@ -292,6 +292,9 @@ def _flush(row: Curve, pks, msgs, sigs, powers, min_lanes: int, mesh
                 sh.powers_to_limbs(
                     np.where(host_ok, np.asarray(powers, dtype=np.int64), 0),
                     out=limbs[:, :B])
+                more = int(np.count_nonzero(limbs[1:, :B].any(axis=0)))
+                _m.crypto_tally_power_lanes.inc(B - more, limbs="one")
+                _m.crypto_tally_power_lanes.inc(more, limbs="more")
         with trace.span(f"{row.name}.device_put"):
             args = (jnp.asarray(packed),)  # ONE transfer
         with trace.span(f"{row.name}.execute", impl=impl):

@@ -15,7 +15,7 @@ as one TPU dispatch instead of a serial CPU loop.
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from tmtpu.crypto.encoding import pubkey_from_proto, pubkey_to_proto
 from tmtpu.crypto.keys import PubKey
@@ -261,14 +261,16 @@ class ValidatorSet:
 
     # -- updates (validator_set.go:591 updateWithChangeSet) -----------------
 
-    def update_with_change_set(self, changes: List[Validator]) -> None:
-        self._update_with_change_set([v.copy() for v in changes],
-                                     allow_deletes=True)
+    def update_with_change_set(self, changes: List[Validator]) -> Dict[str, int]:
+        """validator_set.go:591 UpdateWithChangeSet -> how many of the
+        changes were a member's new power, a join and a leave."""
+        return self._update_with_change_set([v.copy() for v in changes],
+                                            allow_deletes=True)
 
     def _update_with_change_set(self, changes: List[Validator],
-                                allow_deletes: bool) -> None:
+                                allow_deletes: bool) -> Dict[str, int]:
         if not changes:
-            return
+            return {"power": 0, "join": 0, "leave": 0}
         # split & validate changes (processChanges)
         by_addr = {}
         for c in sorted(changes, key=lambda v: v.address):
@@ -283,20 +285,23 @@ class ValidatorSet:
         deletes = [c for c in by_addr.values() if c.voting_power == 0]
         if not allow_deletes and deletes:
             raise ValueError("cannot process validators with voting power 0")
-        num_new = sum(1 for u in updates if not self.has_address(u.address))
+        # the members by address, once: GetByAddress a change is a scan of
+        # the set in Go, 10,000 compares each at the protocol's cap
+        members = {v.address: v for v in self.validators}
+        num_new = sum(1 for u in updates if u.address not in members)
         if num_new == 0 and len(self.validators) == len(deletes):
             raise ValueError("applying the validator changes would result in empty set")
         # verifyRemovals
         removed_power = 0
         for d in deletes:
-            _, val = self.get_by_address(d.address)
+            val = members.get(d.address)
             if val is None:
                 raise ValueError(f"failed to find validator {d.address.hex()} to remove")
             removed_power += val.voting_power
         # verifyUpdates: total power after updates (before removals)
         delta = 0
         for u in updates:
-            _, old = self.get_by_address(u.address)
+            old = members.get(u.address)
             delta += u.voting_power - (old.voting_power if old else 0)
         tvp_after_updates = self.total_voting_power() + delta if self.validators \
             else delta
@@ -304,13 +309,13 @@ class ValidatorSet:
             raise OverflowError("total voting power would exceed maximum")
         # computeNewPriorities: new validators start deep negative
         for u in updates:
-            _, old = self.get_by_address(u.address)
+            old = members.get(u.address)
             if old is None:
                 u.proposer_priority = -(tvp_after_updates + (tvp_after_updates >> 3))
             else:
                 u.proposer_priority = old.proposer_priority
         # applyUpdates: address-sorted merge, updates win
-        merged = {v.address: v for v in self.validators}
+        merged = dict(members)
         for u in updates:
             merged[u.address] = u
         for d in deletes:
@@ -323,6 +328,8 @@ class ValidatorSet:
         )
         self._shift_by_avg_proposer_priority()
         self.validators = _sorted_by_power(self.validators)
+        return {"power": len(updates) - num_new, "join": num_new,
+                "leave": len(deletes)}
 
     # -- hashing / proto ----------------------------------------------------
 
