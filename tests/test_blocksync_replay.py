@@ -133,6 +133,7 @@ def test_every_span_and_counter_once_a_block(node_of):
     in_runs0 = _counter("blocksync_run_blocks", "sum")
     bad0 = _counter("blocksync_bad_blocks")
     memo0 = _valset_memo()
+    paths0 = dict(metrics.state_validate_block.summary_series())
     node.peer("a", chain)
     node.reactor.on_start()
     node.wait(lambda: node.height == 40, "the replay")
@@ -147,21 +148,24 @@ def test_every_span_and_counter_once_a_block(node_of):
                  "state.commit_app", "state.save_responses", "state.save"):
         assert count[name] == 40, name
     assert count["state.validate_block"] == 80     # the reactor's, apply's
+    assert {k: v - paths0.get(k, 0) for k, v in
+            metrics.state_validate_block.summary_series().items()} \
+        == {"path=full": 40, "path=repeat": 40}
     assert count["blocksync.verify_run"] == runs
     assert count["commit_verify.verify_commits_light_batch"] == runs
-    # the fused collect once a run; verify_commit of LastCommit twice a
-    # block from the second block on
-    assert count["commit_verify.collect"] == runs + 2 * 39
+    # the fused collect once a run; verify_commit of LastCommit once a
+    # block from the second block on: apply's validate_block is a repeat
+    assert count["commit_verify.collect"] == runs + 39
     assert count["blocksync.receive"] >= 41
     # the pool held every run whole: 512 blocks of 12 validators fit the
     # run's lanes, so the 41 blocks the peer served at once were one run
     assert runs == 1
-    # the sets' kept bytes: a block, validate_block's two hashes twice and
-    # three of save's four encodings (the new next_validators is the
+    # the sets' kept bytes: a block, the full validate_block's two hashes
+    # and three of save's four encodings (the new next_validators is the
     # fourth); a run, the reactor's hash; the chain's first sight of
     # validators and of next_validators are the only hashes computed
     memo = {k: v - memo0[k] for k, v in _valset_memo().items()}
-    assert memo == {"hits.hash": 4 * 40 + runs - 2, "misses.hash": 2,
+    assert memo == {"hits.hash": 2 * 40 + runs - 2, "misses.hash": 2,
                     "hits.encode": 3 * 40, "misses.encode": 40}
 
 

@@ -7,6 +7,7 @@ by the peer queue, and every span and counter of the vote path moves by
 exact counts. The device is faked where a shape is asked for; the chain
 runs on the serial backend."""
 import os
+import time
 
 import pytest
 
@@ -539,11 +540,42 @@ def test_validate_block_pins_its_last_commit_check_to_the_sets_shape(
     monkeypatch.setattr(commit_verify, "verify_commit", spy)
     live.start()
     live.play(range(1, 4), timeout=30)
-    # heights 2 and 3 carry a LastCommit: checked at prevote, at precommit
-    # and at finalize
-    assert {h for h, _m in pins} == {1, 2} and len(pins) >= 6
+    # heights 2 and 3 carry a LastCommit: checked once each, at prevote;
+    # precommit's lock, finalize and apply_block repeat that validation
+    assert sorted(h for h, _m in pins) == [1, 2]
     assert {m for _h, m in pins} == {
         crypto_batch.vote_flush_lanes(N_VAL, N_VAL)} == {N_VAL}
+
+
+def validate_block_paths_moved(before, height_done, timeout=20):
+    """``state_validate_block_total`` by path since ``before``, read once
+    ``height_done()`` holds and the counter has stopped moving."""
+    deadline = time.time() + timeout
+    moved, last = None, None
+    while time.time() < deadline:
+        moved = {k: v - before.get(k, 0) for k, v in
+                 metrics.state_validate_block.summary_series().items()}
+        if height_done() and moved == last:
+            break
+        last = moved
+        time.sleep(0.1)
+    return moved
+
+
+def test_a_height_validates_its_block_once_and_repeats_three_times(
+        chain, live):
+    """Prevote validates the proposal in full; precommit's lock, finalize
+    and apply_block ask again for the same (state, block) and are answered
+    from the executor's slot."""
+    before = dict(metrics.state_validate_block.summary_series())
+    spans0 = dict(trace.span_totals())
+    live.start()
+    live.play(range(1, 5), timeout=30)
+    moved = validate_block_paths_moved(
+        before, lambda: live.cs.state.last_block_height == 4)
+    assert moved == {"path=full": 4, "path=repeat": 3 * 4}
+    assert trace.span_totals()["state.validate_block"][0] \
+        - spans0.get("state.validate_block", (0, 0))[0] == 4 * 4
 
 
 def test_median_time_is_the_weighted_median_whatever_the_sets_size(chain):

@@ -19,6 +19,7 @@ import pytest
 from benchmarks.reference import commits as rc
 from benchmarks.reference import light as rl
 from benchmarks.reference import staking as st
+from tests.test_live_rounds import validate_block_paths_moved
 from tmtpu.abci import types as abci
 from tmtpu.config.config import CryptoConfig
 from tmtpu.crypto import batch as crypto_batch
@@ -177,6 +178,18 @@ def test_live_heights_commit_the_references_blocks_sets_and_tallies(
     spans = trace.span_totals()
     assert spans["state.update_validators"][0] \
         - spans0.get("state.update_validators", (0, 0))[0] == N_HEIGHTS
+
+
+def test_a_height_on_a_moving_set_validates_its_block_once(chain, live):
+    """Every height hands its executor a new state with new sets; the
+    block is still validated in full once, at prevote, and its three
+    repeats are answered from the executor's slot."""
+    before = _series("state_validate_block")
+    live.start()
+    live.play(range(1, 5), timeout=60)
+    moved = validate_block_paths_moved(
+        before, lambda: live.cs.state.last_block_height == 4)
+    assert moved == {"path=full": 4, "path=repeat": 3 * 4}
 
 
 def app_key(pub):

@@ -864,6 +864,14 @@ state_validator_updates = DEFAULT.counter(
     "Validator updates applied to the next-next validator set, by kind: "
     "power (a member's power changed), join, leave",
     labels=("kind",))
+# BlockExecutor.validate_block calls (state/execution.py): full (the block
+# checked against the state) or repeat (the (state, block) that last passed,
+# unchanged: the pure checks skipped); moved once a call.
+state_validate_block = DEFAULT.counter(
+    "state", "validate_block_total",
+    "BlockExecutor.validate_block calls by path: full, or repeat (the same "
+    "state and block objects that last passed, unchanged)",
+    labels=("path",))
 # The lanes of a fused verify+tally flush (tpu/dispatch.py _flush) by the
 # power limbs they fill: one (the power fits the first 13-bit limb) or more
 # (it carries into limbs 1-4); moved once a flush by one count over the limbs.

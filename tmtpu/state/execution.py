@@ -8,6 +8,7 @@ updateState (:403, valset + params changes) → app Commit under mempool lock
 
 from __future__ import annotations
 
+import operator
 import threading
 import time
 from typing import List, Optional, Tuple
@@ -51,6 +52,15 @@ class BlockExecutor:
         self.verify_backend = verify_backend
         self._exec_pool = None  # lazy single-worker pool for async apply
         self._exec_pool_mtx = threading.Lock()
+        # The (state, block) that last passed validate_block, held strongly,
+        # with what state.validation.validate_block read of them. Go's
+        # ValidateBlock runs at the same four places a height (prevote,
+        # precommit's lock, finalize, ApplyBlock; the blocksync reactor and
+        # ApplyBlock a block) on the same two objects, and it reads nothing
+        # but its two arguments, so a repeat returns what the first call
+        # returned. Replaced as one tuple: apply_block_async validates on
+        # the executor's thread.
+        self._validated: Optional[tuple] = None
 
     # -- proposal -----------------------------------------------------------
 
@@ -85,9 +95,23 @@ class BlockExecutor:
         """execution.go:117 ValidateBlock — structural/state checks, then
         every piece of block evidence is verified through the pool
         (execution.go:122 evpool.CheckEvidence). Without this a byzantine
-        proposer could embed fabricated evidence framing honest validators."""
-        with trace.span("state.validate_block", height=block.header.height):
-            validate_block(state, block, verify_backend=self.verify_backend)
+        proposer could embed fabricated evidence framing honest validators.
+
+        A repeat of the last (state, block) that passed — the same two
+        objects, reading as they did then — skips the pure part; the
+        evidence pool, whose state is no argument, is asked every call."""
+        with trace.span("state.validate_block",
+                        height=block.header.height) as sp:
+            held, reads = _validation_reads(state, block)
+            slot = self._validated
+            repeat = slot is not None and \
+                all(map(operator.is_, slot[0], held)) and slot[1] == reads
+            sp.set(repeat=repeat)
+            _metrics.state_validate_block.inc(
+                path="repeat" if repeat else "full")
+            if not repeat:
+                validate_block(state, block,
+                               verify_backend=self.verify_backend)
             if self.evidence_pool is not None and block.evidence:
                 from tmtpu.evidence.pool import EvidenceError
 
@@ -96,6 +120,7 @@ class BlockExecutor:
                 except EvidenceError as e:
                     raise BlockExecutionError(
                         f"invalid evidence: {e}") from e
+            self._validated = (held, reads)
 
     def apply_block(self, state: State, block_id: BlockID, block: Block
                     ) -> Tuple[State, int]:
@@ -291,6 +316,23 @@ class BlockExecutor:
             ))
         if val_updates:
             self.event_bus.publish_validator_set_updates(val_updates)
+
+
+def _validation_reads(state: State, block: Block) -> Tuple[tuple, tuple]:
+    """What state.validation.validate_block reads of its two arguments:
+    the objects, to be compared by identity (the two themselves, the
+    block's LastCommit, txs and evidence, State's params and three sets),
+    then what is compared by value: the header by its hash (computed anew:
+    ``Block.hash()`` keeps the first) and State's fields, which the
+    handshake writes in place (consensus/replay.py)."""
+    bid = state.last_block_id
+    return ((state, block, block.last_commit, block.txs, block.evidence,
+             state.consensus_params, state.validators,
+             state.next_validators, state.last_validators),
+            (block.header.hash(), state.chain_id, state.app_version,
+             state.initial_height, state.last_block_height,
+             (bid.hash, bid.parts_total, bid.parts_hash),
+             state.last_block_time, state.app_hash, state.last_results_hash))
 
 
 def update_state(state: State, block_id: BlockID, header,
