@@ -856,6 +856,16 @@ types_valset_memo_misses = DEFAULT.counter(
     "types", "valset_memo_misses_total",
     "ValidatorSet.hash() / encode() calls that computed their bytes",
     labels=("what",))
+# A Commit's protobuf encode and decode (types/pb.py Commit): by hand from
+# and into plain rows, or by the reflective codec (a Commit built of
+# CommitSig objects, or input of another shape than the canonical one);
+# moved once a Commit, never a signature.
+types_commit_codec = DEFAULT.counter(
+    "types", "commit_codec_total",
+    "Commit protobuf encodes and decodes by direction (encode, decode) and "
+    "path: hand (the signatures as plain rows) or reflective (the generic "
+    "codec, for every other shape)",
+    labels=("dir", "path"))
 # The validator updates an EndBlock returned (state/execution.py
 # update_state, over ValidatorSet.update_with_change_set): a power change of
 # a member, a join, a leave (power 0); moved once a call by its exact counts.

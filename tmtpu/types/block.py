@@ -153,20 +153,6 @@ class CommitSig:
             if len(self.signature) > 64:
                 raise ValueError("CommitSig signature too big")
 
-    def to_proto(self) -> pb.CommitSig:
-        return pb.CommitSig(
-            block_id_flag=self.block_id_flag,
-            validator_address=self.validator_address,
-            timestamp=pb.Timestamp.from_unix_nanos(self.timestamp),
-            signature=self.signature,
-        )
-
-    @classmethod
-    def from_proto(cls, m: pb.CommitSig) -> "CommitSig":
-        ts = m.timestamp.to_unix_nanos() if m.timestamp else 0
-        return cls(m.block_id_flag, bytes(m.validator_address), ts,
-                   bytes(m.signature))
-
     def __eq__(self, other):
         return (isinstance(other, CommitSig)
                 and self.block_id_flag == other.block_id_flag
@@ -254,24 +240,27 @@ class Commit:
             for cs in self.signatures:
                 cs.validate_basic()
 
+    def _rows(self) -> list:
+        """The signatures as ``pb.Commit`` rows, read now: what is encoded
+        is what the CommitSigs hold at the call, nothing kept."""
+        return [(cs.block_id_flag, cs.validator_address, cs.timestamp,
+                 cs.signature) for cs in self.signatures]
+
     def hash(self) -> bytes:
         if self._hash is None:
             self._hash = hash_from_byte_slices(
-                [cs.to_proto().encode() for cs in self.signatures]
-            )
+                pb.CommitSig.encode_rows(self._rows()))
         return self._hash
 
     def to_proto(self) -> pb.Commit:
-        return pb.Commit(
-            height=self.height, round=self.round,
-            block_id=self.block_id.to_proto(),
-            signatures=[cs.to_proto() for cs in self.signatures],
-        )
+        return pb.Commit.from_rows(self.height, self.round,
+                                   self.block_id.to_proto(), self._rows())
 
     @classmethod
     def from_proto(cls, m: pb.Commit) -> "Commit":
         return cls(m.height, m.round, BlockID.from_proto(m.block_id),
-                   [CommitSig.from_proto(s) for s in m.signatures])
+                   [CommitSig(flag, address, ns, signature)
+                    for flag, address, ns, signature in m.rows()])
 
     def __eq__(self, other):
         return (isinstance(other, Commit) and self.height == other.height
