@@ -336,6 +336,27 @@ def test_metrics_flags_dead_unknown_and_unrendered(tmp_path):
     assert "metrics::ctor::tmtpu/code.py::Counter" in keys
 
 
+def test_metrics_counts_a_metric_handed_to_its_writer(tmp_path):
+    """A metric passed as a value to the code that writes it (the
+    framed-daemon core takes each daemon's metrics so) is not dead; one
+    only read through an attribute still is."""
+    idx = _tree(tmp_path, {
+        "tmtpu/libs/metrics.py":
+            'handed = DEFAULT.gauge("p2p", "handed")\n'
+            'peeked = DEFAULT.gauge("p2p", "peeked")\n'
+            'passed = DEFAULT.counter("p2p", "passed")\n',
+        "tmtpu/code.py":
+            "class Daemon:\n"
+            "    gauge = _m.handed\n"
+            "queue = Queue(seen=_m.peeked.summary_series(),\n"
+            "              overloads=_m.passed)\n",
+    })
+    keys = _keys(_run(idx, "metrics"))
+    assert "metrics::dead::handed" not in keys
+    assert "metrics::dead::passed" not in keys
+    assert "metrics::dead::peeked" in keys
+
+
 # --------------------------------------------------------------- obs-docs
 
 

@@ -57,6 +57,10 @@ _TIMELINE_RECORD_RE = re.compile(
 _SPAN_RE = re.compile(
     r"""\btrace\.(?:traced|span)\(\s*["']([a-z0-9_.]+)["']""")
 METRIC_WRITE_RE = r"\.(?:inc|set|add|observe|bound)\("
+# a metric handed on as a value (``queued_gauge=_m.sidecar_server_
+# queue_lanes,``) to the code that writes it — the framed-daemon core
+# takes each daemon's metric objects this way — counts as written
+METRIC_BIND_RE = r"\s*(?=[,)\n])"
 
 
 class FileInfo:

@@ -12,15 +12,14 @@ from tmtpu.analysis.rules import (  # noqa: F401
     determinism,
     exception_safety,
     failpoints,
+    framed,
     jax_hygiene,
-    lightserve,
     lock_order,
     meta,
     metrics,
     obs_docs,
     recv_sync,
     scenarios,
-    sidecar,
     sigcache,
     timeline,
     wire_taint,

@@ -20,11 +20,14 @@ import re
 from typing import List
 
 from tmtpu.analysis.findings import Finding
-from tmtpu.analysis.index import METRIC_WRITE_RE, RepoIndex
+from tmtpu.analysis.index import METRIC_BIND_RE, METRIC_WRITE_RE, RepoIndex
 from tmtpu.analysis.registry import rule
 
 _WRITE_PAT = re.compile(
     r"\b(?:metrics\.|_m\.)?([a-z][a-z0-9_]*)" + METRIC_WRITE_RE)
+# a registered metric handed as a value to the code that writes it
+_BIND_PAT = re.compile(
+    r"\b(?:metrics\.|_m\.)([a-z][a-z0-9_]*)" + METRIC_BIND_RE)
 
 # subsystem prefixes whose writes must resolve against the catalog
 _KNOWN_PREFIXES = ("consensus_", "p2p_", "mempool_", "crypto_")
@@ -50,6 +53,8 @@ def check(index: RepoIndex) -> List[Finding]:
                 written.add(name)
             elif name.startswith(_KNOWN_PREFIXES):
                 referenced.setdefault(name, fi.rel)
+        written.update(name for name in _BIND_PAT.findall(fi.source)
+                       if name in attrs)
     findings = []
     for attr in sorted(set(attrs) - written):
         findings.append(Finding(

@@ -8,10 +8,10 @@ height-keyed coalescer, and answers repeat queries from a bounded
 trust-period-aware verified-height fact cache — so the Nth client
 asking about a height costs zero device dispatches.
 
-Deployment shape mirrors :mod:`tmtpu.sidecar`: a socket daemon
-(``python -m tmtpu.cmd lightserve``) speaking a length-prefixed frame
-protocol, plus an optional HTTP listener for ``/healthz`` and
-``/metrics``.
+It is a framed daemon on the core it shares with :mod:`tmtpu.sidecar`
+(:mod:`tmtpu.libs.framed`): ``python -m tmtpu.cmd lightserve`` speaks
+length-prefixed frames, with an optional ``/healthz`` + ``/metrics``
+listener.
 """
 
 from tmtpu.lightserve.cache import Fact, VerifiedFactCache  # noqa: F401

@@ -1,10 +1,8 @@
 """Lightserve client: one multiplexed connection, many in-flight sessions.
 
-Mirrors :class:`tmtpu.sidecar.client.SidecarClient`: a background
-reader thread demultiplexes replies to waiters by request id, so one
-connection can carry thousands of pipelined sessions — the shape the
-flood harness uses to hold 10k+ concurrent sessions with a handful of
-sockets. Reconnects are lazy with a flat backoff window.
+The connection is :class:`tmtpu.libs.framed.client.FramedClient`'s, so
+one connection carries thousands of pipelined sessions — the shape the
+flood harness uses to hold 10k+ sessions with a handful of sockets.
 
 Failure kinds, for caller policy:
 
@@ -24,14 +22,16 @@ The blocking :meth:`sync` wraps the async pair
 
 from __future__ import annotations
 
-import itertools
-import json
 import os
-import socket
-import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
+from tmtpu.libs import metrics as _m
+from tmtpu.libs.framed.client import (
+    DaemonUnavailable,
+    FramedClient,
+    Waiter,
+)
 from tmtpu.lightserve import protocol as proto
 
 ENV_ADDR = "TMTPU_LIGHTSERVE_ADDR"
@@ -52,7 +52,7 @@ class LightserveError(Exception):
     pass
 
 
-class LightserveUnavailable(LightserveError):
+class LightserveUnavailable(LightserveError, DaemonUnavailable):
     """Daemon unreachable / dead connection / deadline / hard error."""
 
 
@@ -89,15 +89,6 @@ class SyncResult:
         self.coalesced = coalesced
 
 
-class _Waiter:
-    __slots__ = ("event", "reply", "error")
-
-    def __init__(self):
-        self.event = threading.Event()
-        self.reply = None
-        self.error: Optional[Exception] = None
-
-
 class SyncHandle:
     """An in-flight session: ``wait`` then ``result`` (or just
     ``result``, which waits)."""
@@ -105,7 +96,7 @@ class SyncHandle:
     __slots__ = ("_client", "_rid", "_waiter", "submitted_at")
 
     def __init__(self, client: "LightserveClient", rid: int,
-                 waiter: _Waiter):
+                 waiter: Waiter):
         self._client = client
         self._rid = rid
         self._waiter = waiter
@@ -119,7 +110,13 @@ class SyncHandle:
                                      deadline_s, self.submitted_at)
 
 
-class LightserveClient:
+class LightserveClient(FramedClient):
+    proto = proto
+    prefix = "lightserve"
+    unavailable = LightserveUnavailable
+    reconnects = _m.lightserve_client_reconnects
+    up = _m.lightserve_client_up
+
     def __init__(self, addr: str, *,
                  client_id: str = "",
                  chain_id: str = "",
@@ -127,167 +124,16 @@ class LightserveClient:
                  request_deadline_s: float = 15.0,
                  retry_backoff_s: float = 1.0,
                  max_frame_bytes: int = proto.DEFAULT_MAX_FRAME_BYTES):
-        self.addr = addr
-        self.client_id = client_id or f"pid-{os.getpid()}"
+        super().__init__(addr, client_id=client_id,
+                         connect_timeout_s=connect_timeout_s,
+                         request_deadline_s=request_deadline_s,
+                         retry_backoff_s=retry_backoff_s,
+                         max_frame_bytes=max_frame_bytes)
         self.chain_id = chain_id       # "" = adopt the server's chain
-        self._connect_timeout_s = connect_timeout_s
-        self._request_deadline_s = request_deadline_s
-        self._retry_backoff_s = retry_backoff_s
-        self._max_frame_bytes = max_frame_bytes
-        self._sock: Optional[socket.socket] = None
-        self._rfile = None
-        self._wlock = threading.Lock()
-        self._conn_lock = threading.Lock()
-        self._waiters: Dict[int, _Waiter] = {}
-        self._waiters_lock = threading.Lock()
-        self._seq = itertools.count(1)
-        self._last_connect_fail = 0.0
-        self.hello_ack: Optional[proto.HelloAck] = None
 
-    # --- connection management ---
-
-    def connected(self) -> bool:
-        return self._sock is not None
-
-    def _ensure_connected(self) -> None:
-        if self._sock is not None:
-            return
-        with self._conn_lock:
-            if self._sock is not None:
-                return
-            now = time.monotonic()
-            if now - self._last_connect_fail < self._retry_backoff_s:
-                raise LightserveUnavailable(
-                    f"lightserve {self.addr}: in connect backoff")
-            try:
-                self._connect_locked()
-            except (OSError, proto.ProtocolError, EOFError,
-                    ValueError) as exc:
-                self._last_connect_fail = time.monotonic()
-                raise LightserveUnavailable(
-                    f"lightserve {self.addr}: {exc}") from exc
-
-    def _connect_locked(self) -> None:
-        from tmtpu.libs import metrics as _m
-
-        _m.lightserve_client_reconnects.inc()
-        kind, target = proto.parse_addr(self.addr)
-        if kind == "unix":
-            sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        else:
-            sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        sock.settimeout(self._connect_timeout_s)
-        sock.connect(target)
-        rfile = sock.makefile("rb")
-        reader = proto.FrameReader(rfile, self._max_frame_bytes)
-        sock.sendall(proto.encode_frame(proto.Hello(
-            version=proto.PROTOCOL_VERSION, client_id=self.client_id,
-            chain_id=self.chain_id)))
-        ack = reader.read_msg()
-        if isinstance(ack, proto.ErrorReply):
-            raise LightserveUnavailable(
-                f"lightserve rejected handshake (code {ack.code}): "
-                f"{ack.message}")
-        if not isinstance(ack, proto.HelloAck):
-            raise proto.ProtocolError(
-                f"expected HelloAck, got {type(ack).__name__}")
-        sock.settimeout(None)  # reader thread blocks; waiters time out
-        self.hello_ack = ack
-        self._sock = sock
-        self._rfile = rfile
-        _m.lightserve_client_up.set(1.0)
-        threading.Thread(target=self._read_loop, args=(reader, sock),
-                         name="lightserve-client-read",
-                         daemon=True).start()
-
-    def close(self) -> None:
-        with self._conn_lock:
-            self._teardown(LightserveUnavailable("client closed"))
-
-    def _teardown(self, err: Exception) -> None:
-        from tmtpu.libs import metrics as _m
-
-        sock, self._sock = self._sock, None
-        self._rfile = None
-        self.hello_ack = None
-        if sock is not None:
-            try:
-                sock.close()
-            except OSError:
-                pass
-            _m.lightserve_client_up.set(0.0)
-        with self._waiters_lock:
-            waiters, self._waiters = self._waiters, {}
-        for w in waiters.values():
-            w.error = err
-            w.event.set()
-
-    def _read_loop(self, reader: proto.FrameReader,
-                   sock: socket.socket) -> None:
-        try:
-            while True:
-                msg = reader.read_msg()
-                rid = getattr(msg, "request_id",
-                              getattr(msg, "nonce", 0))
-                if isinstance(msg, proto.ErrorReply) and rid == 0:
-                    raise LightserveUnavailable(
-                        f"lightserve connection error {msg.code}: "
-                        f"{msg.message}")
-                with self._waiters_lock:
-                    w = self._waiters.pop(rid, None)
-                if w is not None:
-                    w.reply = msg
-                    w.event.set()
-                # unmatched reply: waiter already timed out — drop it
-        except (EOFError, OSError, proto.ProtocolError,
-                LightserveUnavailable) as exc:
-            with self._conn_lock:
-                if self._sock is sock:
-                    self._teardown(LightserveUnavailable(
-                        f"lightserve connection lost: {exc}"))
-
-    # --- request primitives ---
-
-    def _send(self, rid: int, msg) -> _Waiter:
-        w = _Waiter()
-        with self._waiters_lock:
-            self._waiters[rid] = w
-        sock = None
-        try:
-            data = proto.encode_frame(msg)
-            sock = self._sock
-            if sock is None:
-                raise LightserveUnavailable("lightserve not connected")
-            with self._wlock:
-                sock.sendall(data)
-            return w
-        except BaseException as exc:
-            # EVERY failure path must unregister the waiter, including
-            # the sock-is-None raise above (a connect race would
-            # otherwise leak the entry until teardown)
-            with self._waiters_lock:
-                self._waiters.pop(rid, None)
-            if isinstance(exc, OSError):
-                with self._conn_lock:
-                    if self._sock is sock:
-                        self._teardown(LightserveUnavailable(str(exc)))
-                raise LightserveUnavailable(
-                    f"lightserve send failed: {exc}") from exc
-            raise
-
-    def _await(self, rid: int, w: _Waiter, deadline_s: float):
-        if not w.event.wait(deadline_s):
-            with self._waiters_lock:
-                self._waiters.pop(rid, None)
-            raise LightserveUnavailable(
-                f"lightserve request deadline ({deadline_s:.3f}s) "
-                f"exceeded")
-        if w.error is not None:
-            raise LightserveUnavailable(str(w.error)) from w.error
-        return w.reply
-
-    def _roundtrip(self, rid: int, msg, deadline_s: float):
-        return self._await(rid, self._send(rid, msg), deadline_s)
+    def _hello(self, version: int) -> proto.Hello:
+        return proto.Hello(version=version, client_id=self.client_id,
+                           chain_id=self.chain_id)
 
     # --- public API ---
 
@@ -305,11 +151,9 @@ class LightserveClient:
             now_ns=now_ns))
         return SyncHandle(self, rid, w)
 
-    def _collect(self, rid: int, w: _Waiter,
+    def _collect(self, rid: int, w: Waiter,
                  deadline_s: Optional[float],
                  submitted_at: float) -> SyncResult:
-        from tmtpu.libs import metrics as _m
-
         try:
             reply = self._await(rid, w,
                                 deadline_s or self._request_deadline_s)
@@ -350,24 +194,3 @@ class LightserveClient:
         handle = self.sync_submit(trusted_height, trusted_hash,
                                   target_height, now_ns)
         return handle.result(deadline_s)
-
-    def ping(self, deadline_s: Optional[float] = None) -> proto.Pong:
-        self._ensure_connected()
-        nonce = next(self._seq)
-        reply = self._roundtrip(nonce, proto.Ping(nonce=nonce),
-                                deadline_s or self._request_deadline_s)
-        if not isinstance(reply, proto.Pong):
-            raise LightserveUnavailable(
-                f"unexpected reply {type(reply).__name__}")
-        return reply
-
-    def stats(self, deadline_s: Optional[float] = None) -> Dict:
-        """Daemon snapshot; serializes on request id 0 like the sidecar
-        stats call — fine for a debug endpoint."""
-        self._ensure_connected()
-        reply = self._roundtrip(0, proto.StatsRequest(),
-                                deadline_s or self._request_deadline_s)
-        if not isinstance(reply, proto.StatsResponse):
-            raise LightserveUnavailable(
-                f"unexpected reply {type(reply).__name__}")
-        return json.loads(reply.stats_json.decode())

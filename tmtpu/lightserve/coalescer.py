@@ -7,25 +7,25 @@ fetches, one set of device dispatches — and every waiting session gets
 its own per-request slice of the outcome (the hop chain from ITS
 trusted height, cut from the shared verified path).
 
-Mirrors :mod:`tmtpu.sidecar.coalescer` deliberately: a private
-:class:`~tmtpu.crypto.batch.AdaptiveFlushScheduler` fed by real session
-arrivals and real resolve round-trips decides how long to linger for
-more same-height arrivals; queues are FIFO across target heights so a
-hot height cannot starve a cold one; ``submit`` applies admission
-control (:class:`Overloaded` past ``max_queue_sessions``).
-
-Whole-session granularity is trivial here — a session IS the unit — so
-unlike the lane coalescer there is no dispatch cap: every queued
-session for the chosen height rides the one resolve.
+The gathering — FIFO across heights, admission control past
+``max_queue_sessions`` — is the framed core's
+:class:`~tmtpu.libs.framed.gather.GatherQueue`, keyed by target height.
+A session IS the unit, so there is no cut cap: every queued session for
+the chosen height rides the one resolve.
 """
 
 from __future__ import annotations
 
-import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, List, Optional
 
 from tmtpu.crypto.batch import AdaptiveFlushScheduler
+from tmtpu.libs import metrics as _m
+from tmtpu.libs.framed.gather import (  # noqa: F401 — Overloaded re-exported
+    GatherQueue,
+    Overloaded,
+    Pending,
+)
 
 # resolve engine: (target_height, now_ns) -> resolution object
 # (opaque to the coalescer; the slice function interprets it)
@@ -35,106 +35,44 @@ ResolveFn = Callable[[int, int], object]
 SliceFn = Callable[["PendingSync", object], None]
 
 
-class Overloaded(Exception):
-    """Admission control rejected the session; queues are full."""
-
-
-class PendingSync:
+class PendingSync(Pending):
     """One client session riding toward a joint resolve."""
 
-    __slots__ = ("client_id", "target_height", "trusted_height",
-                 "trusted_hash", "now_ns", "deadline", "enqueued_at",
-                 "done", "status", "hops", "dispatches", "cache_hit",
-                 "error", "failure", "dispatch_id", "coalesced",
-                 "on_done")
+    __slots__ = ("target_height", "trusted_height", "trusted_hash",
+                 "now_ns", "status", "hops", "dispatches", "cache_hit",
+                 "coalesced")
 
     def __init__(self, client_id: str, target_height: int,
                  trusted_height: int, trusted_hash: bytes, now_ns: int,
-                 deadline: Optional[float],
+                 deadline_s: Optional[float],
                  on_done: Optional[Callable[["PendingSync"], None]]
                  = None):
-        self.client_id = client_id
+        super().__init__(client_id, 1, deadline_s, on_done)
         self.target_height = target_height
         self.trusted_height = trusted_height
         self.trusted_hash = trusted_hash
         self.now_ns = now_ns
-        self.deadline = deadline          # monotonic, None = no deadline
-        self.enqueued_at = time.monotonic()
-        self.done = threading.Event()
         self.status: Optional[int] = None
         self.hops: Optional[list] = None   # List[Fact], ascending
         self.dispatches = 0
         self.cache_hit = False
-        self.error = ""
-        self.failure = ""          # "" | "expired" | "engine" | "stopped"
-        self.dispatch_id = 0
         self.coalesced = 0
-        # completion hook: invoked exactly once, AFTER done is set, on
-        # whichever coalescer thread finished the session. Keep it
-        # cheap/non-blocking (the server hands off to a reply pool).
-        self.on_done = on_done
-
-    def wait(self, timeout: Optional[float] = None) -> bool:
-        return self.done.wait(timeout)
-
-    def finish(self) -> None:
-        """Mark the session complete and fire its completion hook."""
-        self.done.set()
-        cb, self.on_done = self.on_done, None   # once, ever
-        if cb is not None:
-            try:
-                cb(self)
-            except Exception:  # noqa: BLE001 — a reply-path bug must
-                pass           # not wedge the coalescer thread
 
 
-class SyncCoalescer:
+class SyncCoalescer(GatherQueue):
+    SNAPSHOT_KEYS = ("queued_sessions", "queued_by_height",
+                     "inflight_resolves", "resolves")
+
     def __init__(self, resolve_fn: ResolveFn, slice_fn: SliceFn, *,
                  max_queue_sessions: int = 65536,
                  scheduler: Optional[AdaptiveFlushScheduler] = None):
+        super().__init__(prefix="lightserve", unit="sessions",
+                         max_queued=max_queue_sessions,
+                         overloads=_m.lightserve_server_overloads_total,
+                         queued_gauge=_m.lightserve_server_backlog,
+                         scheduler=scheduler)
         self._resolve_fn = resolve_fn
         self._slice_fn = slice_fn
-        self._max_queue_sessions = max_queue_sessions
-        # a PRIVATE scheduler: the daemon's session-arrival/resolve-RTT
-        # profile, distinct from any crypto batch scheduler
-        self.scheduler = scheduler or AdaptiveFlushScheduler()
-        self._lock = threading.Lock()
-        self._cond = threading.Condition(self._lock)
-        self._queues: Dict[int, List[PendingSync]] = {}
-        self._queued = 0
-        self._inflight = 0            # resolves cut but not yet answered
-        self._resolve_seq = 0
-        self._running = False
-        self._thread: Optional[threading.Thread] = None
-
-    # --- lifecycle ---
-
-    def start(self) -> None:
-        with self._lock:
-            if self._running:
-                return
-            self._running = True
-        self._thread = threading.Thread(
-            target=self._run, name="lightserve-coalescer", daemon=True)
-        self._thread.start()
-
-    def stop(self) -> None:
-        with self._cond:
-            self._running = False
-            self._cond.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
-        with self._lock:
-            leftovers = [r for q in self._queues.values() for r in q]
-            self._queues.clear()
-            self._queued = 0
-        for req in leftovers:
-            req.error = "coalescer stopped"
-            req.failure = "stopped"
-            req.finish()
-
-    # --- client side ---
 
     def submit(self, client_id: str, target_height: int,
                trusted_height: int, trusted_hash: bytes, now_ns: int,
@@ -145,127 +83,22 @@ class SyncCoalescer:
         :class:`Overloaded` when the session backlog is full. Every
         admitted session's ``on_done`` hook fires exactly once — on
         resolve, slice failure, deadline lapse, or coalescer stop."""
-        from tmtpu.libs import metrics as _m
-
-        req = PendingSync(
+        return self._admit(target_height, PendingSync(
             client_id, target_height, trusted_height, trusted_hash,
-            now_ns,
-            None if deadline_s is None
-            else time.monotonic() + deadline_s,
-            on_done)
-        with self._cond:
-            if not self._running:
-                raise Overloaded("coalescer not running")
-            if self._queued + 1 > self._max_queue_sessions:
-                _m.lightserve_server_overloads_total.inc()
-                raise Overloaded(
-                    f"session backlog full: {self._queued} queued, cap "
-                    f"{self._max_queue_sessions}")
-            self._queues.setdefault(target_height, []).append(req)
-            self._queued += 1
-            _m.lightserve_server_backlog.set(self._queued)
-            self._cond.notify_all()
-        self.scheduler.note_arrivals(1)
-        return req
+            now_ns, deadline_s, on_done))
 
     def backlog(self) -> int:
         with self._lock:
             return self._queued + self._inflight
 
-    def drain(self, timeout: float = 30.0) -> bool:
-        """Block until every queued session has resolved and answered,
-        or the timeout passes (returns False)."""
-        deadline = time.monotonic() + timeout
-        with self._cond:
-            while self._running and (self._queued > 0
-                                     or self._inflight > 0):
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    return False
-                self._cond.wait(timeout=min(remaining, 0.25))
-            return self._queued == 0 and self._inflight == 0
-
-    def snapshot(self) -> Dict:
-        with self._lock:
-            per_height = {h: len(q)
-                          for h, q in self._queues.items() if q}
-            return {"queued_sessions": self._queued,
-                    "queued_by_height": per_height,
-                    "inflight_resolves": self._inflight,
-                    "resolves": self._resolve_seq,
-                    "scheduler": self.scheduler.snapshot()}
-
-    # --- dispatcher ---
-
-    def _pick_height_locked(self) -> Optional[int]:
-        """Height whose oldest session has waited longest (FIFO across
-        heights so a hot target cannot starve a cold one)."""
-        best, best_t = None, None
-        for height, q in self._queues.items():
-            if q and (best_t is None or q[0].enqueued_at < best_t):
-                best, best_t = height, q[0].enqueued_at
-        return best
-
-    def _run(self) -> None:
-        while True:
-            batch: List[PendingSync] = []
-            with self._cond:
-                while self._running:
-                    height = self._pick_height_locked()
-                    if height is None:
-                        self._cond.wait(timeout=0.5)
-                        continue
-                    q = self._queues[height]
-                    # gather: linger only while the adaptive window says
-                    # more same-height arrivals are worth the wait AND
-                    # the oldest session has slack before its deadline
-                    wait = self.scheduler.gather_wait_s(len(q))
-                    now = time.monotonic()
-                    elapsed = now - q[0].enqueued_at
-                    remaining = wait - elapsed
-                    if q[0].deadline is not None:
-                        remaining = min(remaining, q[0].deadline - now)
-                    if remaining > 1e-4:
-                        self._cond.wait(timeout=remaining)
-                        continue
-                    batch = q
-                    del self._queues[height]
-                    self._queued -= len(batch)
-                    self._inflight += 1
-                    from tmtpu.libs import metrics as _m
-
-                    _m.lightserve_server_backlog.set(self._queued)
-                    break
-                if not self._running:
-                    return
-            if batch:
-                try:
-                    self._resolve(batch[0].target_height, batch)
-                finally:
-                    with self._cond:
-                        self._inflight -= 1
-                        self._cond.notify_all()
-
-    def _resolve(self, target_height: int,
-                 batch: List[PendingSync]) -> None:
-        from tmtpu.libs import metrics as _m
-
+    def _dispatch(self, target_height: int, batch: List[PendingSync],
+                  cut_at: float) -> None:
         # sessions whose deadline already passed are answered without
         # wasting a resolve slot on them
-        now = time.monotonic()
-        live: List[PendingSync] = []
-        for req in batch:
-            if req.deadline is not None and now > req.deadline:
-                req.error = "deadline expired before resolve"
-                req.failure = "expired"
-                req.finish()
-            else:
-                live.append(req)
+        live = self._live(batch, "resolve")
         if not live:
             return
-        with self._lock:
-            self._resolve_seq += 1
-            resolve_id = self._resolve_seq
+        resolve_id = self._next_id()
         # the joint resolve judges expiry at the newest admission
         # stamp. Every now_ns is SERVER-stamped at admission (the
         # server never forwards a client clock here), so the max is
@@ -277,9 +110,7 @@ class SyncCoalescer:
         except Exception as exc:  # noqa: BLE001 — engine bug must not
             # wedge sessions; they get an error verdict, never a chain
             for req in live:
-                req.error = f"resolve engine failed: {exc}"
-                req.failure = "engine"
-                req.finish()
+                req.fail("engine", f"resolve engine failed: {exc}")
             return
         dt = time.perf_counter() - t0
         self.scheduler.note_dispatch(len(live), dt)

@@ -1,10 +1,8 @@
 """Lightserve wire protocol: commit-proof sessions over framed messages.
 
-Same framing as the sidecar (``uvarint(len(body)) || type_byte ||
-payload``) — the codec itself is imported from
-:mod:`tmtpu.sidecar.protocol` with this module's own message registry,
-so the two daemons share one tested frame reader without sharing a wire
-namespace.
+The frame, the handshake and the shared codes are
+:mod:`tmtpu.libs.framed.protocol`'s; this module is the lightserve
+namespace: its message classes, its statuses and its frame cap.
 
 A session is one :class:`SyncRequest`: "I trust ``(trusted_height,
 trusted_hash)``; prove ``target_height`` to me." The daemon answers
@@ -19,22 +17,29 @@ not the sidecar's 8.
 Handshake: client sends :class:`Hello` first (with the chain id it
 expects); server answers :class:`HelloAck` carrying its chain id,
 trust anchor, and latest verified height — a cold client with no
-social-consensus anchor of its own can adopt the server's. Version
-negotiation mirrors the sidecar: min(client, server), ``ERR_VERSION``
-on unsupported.
+social-consensus anchor of its own can adopt the server's.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Type
 
-from tmtpu.libs.protoio import ProtoMessage
-from tmtpu.sidecar.protocol import (  # noqa: F401 — re-exported codec
+from tmtpu.libs.framed.protocol import (  # noqa: F401 — the shared codes
+    ERR_PROTOCOL,
+    ERR_VERSION,
+    STATUS_BAD_REQUEST,
+    STATUS_NAMES as _SHARED_NAMES,
+    STATUS_OK,
+    STATUS_OVERLOADED,
+    Codec,
+    ErrorReply,
+    Ping,
     ProtocolError,
-    encode_uvarint,
+    StatsRequest,
+    StatsResponse,
     parse_addr,
 )
-from tmtpu.sidecar import protocol as _sidecar_proto
+from tmtpu.libs.protoio import ProtoMessage
 
 PROTOCOL_VERSION = 1
 SUPPORTED_VERSIONS = (1,)
@@ -43,29 +48,17 @@ SUPPORTED_VERSIONS = (1,)
 # a ~17k-hop chain with room, far past any O(log N) bisection path.
 DEFAULT_MAX_FRAME_BYTES = 1 * 1024 * 1024
 
-# --- SyncResponse.status ---
-STATUS_OK = 0
-STATUS_OVERLOADED = 1      # admission control rejected; retry later
+# --- SyncResponse.status: the shared codes plus ---
 STATUS_UPSTREAM_DOWN = 2   # provider unreachable / verification engine failed
-STATUS_BAD_REQUEST = 3     # zero target, malformed hash
-STATUS_SHUTTING_DOWN = 4   # daemon draining; do not resubmit
 STATUS_EXPIRED = 5         # no trusted state fresh enough to prove the target
 STATUS_UNTRUSTED = 6       # client's trusted hash conflicts with the spine
 
 STATUS_NAMES = {
-    STATUS_OK: "ok",
-    STATUS_OVERLOADED: "overloaded",
+    **_SHARED_NAMES,
     STATUS_UPSTREAM_DOWN: "upstream_down",
-    STATUS_BAD_REQUEST: "bad_request",
-    STATUS_SHUTTING_DOWN: "shutting_down",
     STATUS_EXPIRED: "expired",
     STATUS_UNTRUSTED: "untrusted",
 }
-
-# --- ErrorReply.code --- (numbering shared with the sidecar protocol)
-ERR_VERSION = 1
-ERR_PROTOCOL = 2
-ERR_INTERNAL = 3
 
 
 class Hello(ProtoMessage):
@@ -125,34 +118,11 @@ class SyncResponse(ProtoMessage):
     ]
 
 
-class Ping(ProtoMessage):
-    FIELDS = [(1, "nonce", "uint64")]
-
-
 class Pong(ProtoMessage):
     FIELDS = [
         (1, "nonce", "uint64"),
         (2, "latest_height", "uint64"),
         (3, "uptime_ms", "uint64"),
-    ]
-
-
-class StatsRequest(ProtoMessage):
-    FIELDS = []
-
-
-class StatsResponse(ProtoMessage):
-    """Introspection snapshot; JSON so the payload can grow without
-    protocol bumps (advisory, not consensus-critical)."""
-
-    FIELDS = [(1, "stats_json", "bytes")]
-
-
-class ErrorReply(ProtoMessage):
-    FIELDS = [
-        (1, "request_id", "uint64"),         # 0 when not tied to a request
-        (2, "code", "uint32"),
-        (3, "message", "string"),
     ]
 
 
@@ -170,27 +140,9 @@ MESSAGE_TYPES: Dict[int, Type[ProtoMessage]] = {
     10: Hop,
 }
 
-TYPE_BYTES: Dict[Type[ProtoMessage], int] = {
-    cls: tb for tb, cls in MESSAGE_TYPES.items()
-}
-
-
-def encode_frame(msg: ProtoMessage) -> bytes:
-    return _sidecar_proto.encode_frame(msg, TYPE_BYTES)
-
-
-def decode_frame(body: bytes) -> ProtoMessage:
-    return _sidecar_proto.decode_frame(body, MESSAGE_TYPES)
-
-
-def write_frame(stream, msg: ProtoMessage) -> None:
-    _sidecar_proto.write_frame(stream, msg, TYPE_BYTES)
-
-
-class FrameReader(_sidecar_proto.FrameReader):
-    """Sidecar frame reader bound to the lightserve message registry."""
-
-    def __init__(self, stream,
-                 max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES):
-        super().__init__(stream, max_frame_bytes,
-                         message_types=MESSAGE_TYPES)
+CODEC = Codec(MESSAGE_TYPES, DEFAULT_MAX_FRAME_BYTES)
+TYPE_BYTES = CODEC.type_bytes
+encode_frame = CODEC.encode_frame
+decode_frame = CODEC.decode_frame
+write_frame = CODEC.write_frame
+FrameReader = CODEC.reader

@@ -35,10 +35,10 @@ unauthenticated client's clock. Cold sessions are answered by a small
 fixed reply pool fed by coalescer completion hooks, not by
 per-session threads.
 
-Introspection mirrors the sidecar daemon: ``Ping``/``StatsRequest`` on
-the protocol socket, optional HTTP ``/healthz`` (verdict from
-``libs.watchdog.lightserve_check``: cache hit-rate floor + session
-backlog ceiling) and ``/metrics``.
+The socket side, ``Ping``/``StatsRequest`` and the health listener are
+:class:`tmtpu.libs.framed.server.FramedServer`'s; here ``/healthz`` is
+``libs.watchdog.lightserve_check``'s verdict (cache hit-rate floor +
+session backlog ceiling).
 
 Run it: ``python -m tmtpu.cmd lightserve --addr tcp://127.0.0.1:26680
 --upstream http://127.0.0.1:26657 --chain-id ... --trust-height 1
@@ -47,14 +47,14 @@ Run it: ``python -m tmtpu.cmd lightserve --addr tcp://127.0.0.1:26680
 
 from __future__ import annotations
 
-import json
-import os
 import queue
-import socket
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from tmtpu.libs import metrics as _m
+from tmtpu.libs.framed.gather import failure_status
+from tmtpu.libs.framed.server import FramedServer
 from tmtpu.light import provider as prov
 from tmtpu.light import verifier
 from tmtpu.light.client import DEFAULT_MAX_CLOCK_DRIFT_NS, TrustOptions
@@ -68,12 +68,6 @@ from tmtpu.lightserve.coalescer import (
     SyncCoalescer,
 )
 from tmtpu.types.light_block import LightBlock
-
-_FAILURE_STATUS = {
-    "expired": proto.STATUS_OVERLOADED,
-    "engine": proto.STATUS_UPSTREAM_DOWN,
-    "stopped": proto.STATUS_SHUTTING_DOWN,
-}
 
 # client.go:40 verifySkipping pivot — mirrored from light/client.py
 _PIVOT_NUM, _PIVOT_DEN = 1, 2
@@ -150,7 +144,14 @@ class _ReplyPool:
                 pass           # kill the worker
 
 
-class LightserveServer:
+class LightserveServer(FramedServer):
+    proto = proto
+    prefix = "lightserve"
+    LISTEN_BACKLOG = 128
+    connections = _m.lightserve_server_connections
+    requests = _m.lightserve_server_requests
+    protocol_errors = _m.lightserve_server_protocol_errors
+
     def __init__(self, addr: str, provider: prov.Provider,
                  trust_options: TrustOptions, chain_id: str, *,
                  backend: Optional[str] = None,
@@ -174,8 +175,8 @@ class LightserveServer:
 
         trust_options.validate_basic()
         verifier.validate_trust_level(*trust_level)
-        self.addr = addr
-        self._kind, self._target = proto.parse_addr(addr)
+        super().__init__(addr, max_frame_bytes=max_frame_bytes,
+                         health_laddr=health_laddr, server_id=server_id)
         self.provider = provider
         self.trust_options = trust_options
         self.chain_id = chain_id
@@ -186,12 +187,8 @@ class LightserveServer:
         self._store_max_blocks = store_max_blocks
         self.cache = VerifiedFactCache(
             chain_id, trust_options.period_ns, max_facts=cache_max_facts)
-        self._max_queue_sessions = max_queue_sessions
-        self._max_frame_bytes = max_frame_bytes
         self._default_deadline_s = request_deadline_s
         self._backwards_limit = backwards_limit
-        self._health_laddr = health_laddr
-        self.server_id = server_id or f"lightserve-{os.getpid()}"
         self._hit_rate_floor = hit_rate_floor
         self._hit_rate_min_lookups = hit_rate_min_lookups
         self._backlog_ceiling = backlog_ceiling
@@ -209,17 +206,9 @@ class LightserveServer:
             max_queue_sessions=max_queue_sessions)
         self.provider_calls = 0
         self.sessions_served = 0
+        self._routes[proto.SyncRequest] = ("sync", self._handle_sync)
         self._resolve_lock = threading.Lock()
-        self._listener: Optional[socket.socket] = None
-        self._accept_thread: Optional[threading.Thread] = None
-        self._health_httpd = None
-        self._health_thread: Optional[threading.Thread] = None
         self._health_check = None     # wired in start()
-        self._conns: set = set()
-        self._conns_lock = threading.Lock()
-        self._running = False
-        self._draining = False
-        self._started_at = 0.0
         self._anchor_fact: Optional[Fact] = None
 
     # --- the verified spine -------------------------------------------------
@@ -242,7 +231,6 @@ class LightserveServer:
     def init_anchor(self, now_ns: Optional[int] = None) -> Fact:
         """Fetch and verify the trust anchor (client.go:362
         initializeWithTrustOptions, server-side)."""
-        from tmtpu.libs import metrics as _m
         from tmtpu.types import commit_verify
 
         now_ns = now_ns if now_ns is not None else self._clock()
@@ -305,8 +293,6 @@ class LightserveServer:
         """One skipping hop; returns the device dispatches it cost
         (adjacent = 1 commit verify, non-adjacent = 2, a failed trust
         check = 1). Raises exactly like verifier.verify."""
-        from tmtpu.libs import metrics as _m
-
         period = self.trust_options.period_ns
         if untrusted.height() == verified.height() + 1:
             _m.lightserve_server_dispatches_total.inc()
@@ -467,106 +453,52 @@ class LightserveServer:
             return
         if init_anchor and self._anchor_fact is None:
             self.init_anchor()
-        if self._kind == "unix":
-            path = self._target
-            try:
-                os.unlink(path)
-            except FileNotFoundError:
-                pass
-            sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-            sock.bind(path)
-        else:
-            host, port = self._target
-            sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-            sock.bind((host, port))
-            if port == 0:
-                port = sock.getsockname()[1]
-                self._target = (host, port)
-                self.addr = f"tcp://{host}:{port}"
-        sock.listen(128)
-        self._listener = sock
-        self._running = True
-        self._started_at = time.monotonic()
-        self._reply_pool.start()
-        self.coalescer.start()
+        super().start()
+
+    def _start_engine(self) -> None:
         from tmtpu.libs import watchdog as _wd
 
+        self._reply_pool.start()
+        self.coalescer.start()
         self._health_check = _wd.lightserve_check(
             self.health_snapshot,
             hit_rate_floor=self._hit_rate_floor,
             min_lookups=self._hit_rate_min_lookups,
             backlog_ceiling=self._backlog_ceiling)
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, name="lightserve-accept",
-            daemon=True)
-        self._accept_thread.start()
-        if self._health_laddr:
-            self._start_health_http()
 
-    def drain(self, timeout: float = 30.0) -> bool:
-        """Stop taking new sessions (subsequent SyncRequests answer
-        STATUS_OVERLOADED), finish what's queued. Ping/Stats keep
-        working. Call stop() afterwards."""
-        self._draining = True
-        listener, self._listener = self._listener, None
-        if listener is not None:
-            try:
-                listener.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                listener.close()
-            except OSError:
-                pass
-        return self.coalescer.drain(timeout)
-
-    def stop(self) -> None:
-        self._running = False
-        if self._listener is not None:
-            try:
-                self._listener.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                self._listener.close()
-            except OSError:
-                pass
-            self._listener = None
-        with self._conns_lock:
-            conns = list(self._conns)
-        for c in conns:
-            try:
-                c.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                c.close()
-            except OSError:
-                pass
+    def _stop_engine(self) -> None:
         # coalescer first: its stop() finishes leftover sessions, whose
         # on_done hooks enqueue failure replies the pool then drains
         # ahead of its shutdown sentinels
         self.coalescer.stop()
         self._reply_pool.stop()
-        if self._health_httpd is not None:
-            try:
-                self._health_httpd.shutdown()
-                self._health_httpd.server_close()
-            except Exception:  # noqa: BLE001 — best-effort teardown
-                pass
-            self._health_httpd = None
-        ht = self._health_thread
-        if ht is not None and ht is not threading.current_thread():
-            ht.join(timeout=2.0)
-        if self._accept_thread is not None:
-            self._accept_thread.join(timeout=5.0)
-            self._accept_thread = None
-        if self._kind == "unix":
-            try:
-                os.unlink(self._target)
-            except OSError:
-                pass
+
+    def _hello_ack(self, hello: proto.Hello, version: int):
+        if hello.chain_id and hello.chain_id != self.chain_id:
+            self.protocol_errors.inc(kind="chain-mismatch")
+            return proto.ErrorReply(
+                code=proto.ERR_PROTOCOL,
+                message=f"daemon serves chain {self.chain_id!r}, "
+                        f"not {hello.chain_id!r}")
+        anchor = self._anchor_fact
+        return proto.HelloAck(
+            version=version, server_id=self.server_id,
+            chain_id=self.chain_id,
+            anchor_height=self.trust_options.height,
+            anchor_hash=anchor.header_hash if anchor
+            else self.trust_options.hash,
+            latest_height=max(0, self.latest_height()),
+            max_frame_bytes=self._max_frame_bytes)
+
+    def _pong(self, nonce: int, uptime_ms: int) -> proto.Pong:
+        return proto.Pong(nonce=nonce,
+                          latest_height=max(0, self.latest_height()),
+                          uptime_ms=uptime_ms)
+
+    def _health(self) -> Tuple[bool, Dict]:
+        healthy, reason, details = self._health_check()
+        return healthy, {"reason": reason, "check": details,
+                         **self.snapshot()}
 
     # --- introspection ------------------------------------------------------
 
@@ -581,157 +513,23 @@ class LightserveServer:
         }
 
     def snapshot(self) -> Dict:
-        with self._conns_lock:
-            n_conns = len(self._conns)
         return {
-            "server_id": self.server_id,
-            "addr": self.addr,
+            **super().snapshot(),
             "chain_id": self.chain_id,
-            "draining": self._draining,
-            "uptime_s": round(max(0.0, time.monotonic() -
-                                  self._started_at), 3),
-            "connections": n_conns,
             "anchor_height": self.trust_options.height,
             "latest_height": self.latest_height(),
             "spine_blocks": self._store.size(),
             "provider_calls": self.provider_calls,
             "sessions_served": self.sessions_served,
             "cache": self.cache.snapshot(),
-            "coalescer": self.coalescer.snapshot(),
         }
 
-    # --- connection handling ------------------------------------------------
-
-    def _accept_loop(self) -> None:
-        from tmtpu.libs import metrics as _m
-
-        while self._running:
-            listener = self._listener
-            if listener is None:
-                return
-            try:
-                conn, _peer = listener.accept()
-            except OSError:
-                return  # listener closed
-            with self._conns_lock:
-                self._conns.add(conn)
-                _m.lightserve_server_connections.set(len(self._conns))
-            threading.Thread(target=self._serve_conn, args=(conn,),
-                             name="lightserve-conn", daemon=True).start()
-
-    def _drop_conn(self, conn) -> None:
-        from tmtpu.libs import metrics as _m
-
-        with self._conns_lock:
-            self._conns.discard(conn)
-            _m.lightserve_server_connections.set(len(self._conns))
-        try:
-            conn.close()
-        except OSError:
-            pass
-
-    def _serve_conn(self, conn: socket.socket) -> None:
-        from tmtpu.libs import metrics as _m
-
-        rfile = conn.makefile("rb")
-        wlock = threading.Lock()
-
-        def send(msg) -> None:
-            data = proto.encode_frame(msg)
-            with wlock:
-                conn.sendall(data)
-
-        reader = proto.FrameReader(rfile, self._max_frame_bytes)
-        try:
-            try:
-                first = reader.read_msg()
-            except proto.ProtocolError as exc:
-                _m.lightserve_server_protocol_errors.inc(kind="bad-frame")
-                try:
-                    send(proto.ErrorReply(code=proto.ERR_PROTOCOL,
-                                          message=str(exc)))
-                except OSError:
-                    pass
-                return
-            if not isinstance(first, proto.Hello):
-                _m.lightserve_server_protocol_errors.inc(kind="no-hello")
-                send(proto.ErrorReply(
-                    code=proto.ERR_PROTOCOL,
-                    message=f"expected Hello, got "
-                            f"{type(first).__name__}"))
-                return
-            if first.version not in proto.SUPPORTED_VERSIONS:
-                _m.lightserve_server_protocol_errors.inc(
-                    kind="version-mismatch")
-                send(proto.ErrorReply(
-                    code=proto.ERR_VERSION,
-                    message=f"protocol version {first.version} not in "
-                            f"server-supported "
-                            f"{list(proto.SUPPORTED_VERSIONS)}"))
-                return
-            if first.chain_id and first.chain_id != self.chain_id:
-                _m.lightserve_server_protocol_errors.inc(
-                    kind="chain-mismatch")
-                send(proto.ErrorReply(
-                    code=proto.ERR_PROTOCOL,
-                    message=f"daemon serves chain {self.chain_id!r}, "
-                            f"not {first.chain_id!r}"))
-                return
-            client_id = first.client_id or "anon"
-            _m.lightserve_server_requests.inc(type="hello")
-            anchor = self._anchor_fact
-            send(proto.HelloAck(
-                version=min(first.version, proto.PROTOCOL_VERSION),
-                server_id=self.server_id,
-                chain_id=self.chain_id,
-                anchor_height=self.trust_options.height,
-                anchor_hash=anchor.header_hash if anchor
-                else self.trust_options.hash,
-                latest_height=max(0, self.latest_height()),
-                max_frame_bytes=self._max_frame_bytes))
-            while self._running:
-                try:
-                    msg = reader.read_msg()
-                except proto.ProtocolError as exc:
-                    _m.lightserve_server_protocol_errors.inc(
-                        kind="bad-frame")
-                    try:
-                        send(proto.ErrorReply(code=proto.ERR_PROTOCOL,
-                                              message=str(exc)))
-                    except OSError:
-                        pass
-                    return  # framing is lost; the stream cannot recover
-                if isinstance(msg, proto.SyncRequest):
-                    _m.lightserve_server_requests.inc(type="sync")
-                    self._handle_sync(client_id, msg, send)
-                elif isinstance(msg, proto.Ping):
-                    _m.lightserve_server_requests.inc(type="ping")
-                    send(proto.Pong(
-                        nonce=msg.nonce,
-                        latest_height=max(0, self.latest_height()),
-                        uptime_ms=int((time.monotonic() -
-                                       self._started_at) * 1000)))
-                elif isinstance(msg, proto.StatsRequest):
-                    _m.lightserve_server_requests.inc(type="stats")
-                    send(proto.StatsResponse(stats_json=json.dumps(
-                        self.snapshot()).encode()))
-                else:
-                    _m.lightserve_server_protocol_errors.inc(
-                        kind="unexpected-type")
-                    send(proto.ErrorReply(
-                        code=proto.ERR_PROTOCOL,
-                        message=f"unexpected {type(msg).__name__}"))
-        except (EOFError, OSError, BrokenPipeError):
-            pass  # peer went away
-        finally:
-            self._drop_conn(conn)
+    # --- the sync handler ---------------------------------------------------
 
     def _reply_sync(self, send, request_id: int, ps: PendingSync,
                     t0: float) -> None:
-        from tmtpu.libs import metrics as _m
-
         status = ps.status if ps.status is not None else \
-            _FAILURE_STATUS.get(ps.failure, proto.STATUS_UPSTREAM_DOWN)
+            failure_status(ps.failure, proto.STATUS_UPSTREAM_DOWN)
         hops = [proto.Hop(height=f.height, header_hash=f.header_hash,
                           header_time=f.header_time)
                 for f in (ps.hops or [])]
@@ -740,14 +538,11 @@ class LightserveServer:
             _m.lightserve_server_dispatches_avoided.inc()
         _m.lightserve_server_proof_latency.observe(
             time.perf_counter() - t0)
-        try:
-            send(proto.SyncResponse(
-                request_id=request_id, status=status, hops=hops,
-                dispatches=ps.dispatches, cache_hit=ps.cache_hit,
-                dispatch_id=ps.dispatch_id, coalesced=ps.coalesced,
-                error=ps.error))
-        except OSError:
-            pass  # client gone; the resolve already happened
+        send(proto.SyncResponse(
+            request_id=request_id, status=status, hops=hops,
+            dispatches=ps.dispatches, cache_hit=ps.cache_hit,
+            dispatch_id=ps.dispatch_id, coalesced=ps.coalesced,
+            error=ps.error))
 
     def _handle_sync(self, client_id: str, req: proto.SyncRequest,
                      send) -> None:
@@ -818,46 +613,3 @@ class LightserveServer:
         except Overloaded as exc:
             reject(proto.STATUS_OVERLOADED, str(exc))
             return
-
-    # --- health HTTP --------------------------------------------------------
-
-    def _start_health_http(self) -> None:
-        import http.server
-
-        server = self
-
-        class Handler(http.server.BaseHTTPRequestHandler):
-            def log_message(self, *a):  # quiet
-                pass
-
-            def do_GET(self):
-                if self.path.startswith("/healthz"):
-                    healthy, reason, details = server._health_check()
-                    body = json.dumps(
-                        {"healthy": healthy, "reason": reason,
-                         "check": details, **server.snapshot()}).encode()
-                    self.send_response(200 if healthy else 503)
-                    ctype = "application/json"
-                elif self.path.startswith("/metrics"):
-                    from tmtpu.libs import metrics as _m
-
-                    body = _m.render_prometheus().encode()
-                    self.send_response(200)
-                    ctype = "text/plain; version=0.0.4"
-                else:
-                    body = b"not found\n"
-                    self.send_response(404)
-                    ctype = "text/plain"
-                self.send_header("Content-Type", ctype)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        host, _sep, port = self._health_laddr.rpartition(":")
-        httpd = http.server.ThreadingHTTPServer(
-            (host or "127.0.0.1", int(port)), Handler)
-        self._health_httpd = httpd
-        self._health_thread = threading.Thread(
-            target=httpd.serve_forever, name="lightserve-health",
-            daemon=True)
-        self._health_thread.start()

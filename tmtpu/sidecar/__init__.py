@@ -5,7 +5,7 @@ sidecar"; this package is that daemon. One process owns the jax device
 (paying the ~35 s kernel compile exactly once per daemon lifetime) and
 serves batched ed25519/sr25519/secp256k1 verification plus fused
 verify+tally to any number of node processes over a length-prefixed
-unix-socket/TCP protocol (libs/protoio framing, tmtpu/sidecar/protocol.py).
+unix-socket/TCP protocol (tmtpu/libs/framed, tmtpu/sidecar/protocol.py).
 
 Why a daemon instead of per-process device access: committee-based
 consensus work (arXiv:2302.00418) shows batch amplitude is the dominant
@@ -17,20 +17,16 @@ dispatching ~400-lane joint batches. The server-side coalescer
 flush EWMAs from crypto/batch.py and returns exact per-lane masks to
 each submitter.
 
-Layers:
+Layers (the socket, handshake, frame loop, client connection and the
+gather queue under them are :mod:`tmtpu.libs.framed`'s):
 
-- ``protocol.py`` — wire messages (Hello/HelloAck handshake with version
-  check, VerifyRequest/VerifyResponse, Ping/Pong, Stats) and framing
-  (uvarint length prefix + 1-byte type tag), with hard frame-size caps.
-- ``coalescer.py`` — cross-client batch coalescing with bounded queues,
-  admission control, and explicit overload verdicts.
-- ``server.py`` — the daemon: socket listener, per-connection protocol
-  loop, the verify engine (crypto/batch verifiers — so the sidecar gets
-  the sigcache, the per-curve breakers and the serial fallback for
-  free), warm-start compilation, and an optional HTTP /healthz+/metrics
-  listener.
-- ``client.py`` — ``SidecarClient``: multiplexed request/response over
-  one connection, connection retry with backoff, per-request deadlines.
+- ``protocol.py`` — the sidecar's wire messages (VerifyRequest /
+  VerifyResponse, its Hello/HelloAck/Pong), versions and frame cap.
+- ``coalescer.py`` — the per-curve joint dispatch and mask slicing.
+- ``server.py`` — the daemon: the verify engine (crypto/batch verifiers
+  — so the sidecar gets the sigcache, the per-curve breakers and the
+  serial fallback for free), warm-start compilation, ``/debug/profile``.
+- ``client.py`` — ``SidecarClient.verify`` over the framed client.
 
 Node processes select the daemon with ``crypto.backend=sidecar``
 (config) — ``crypto/batch.py SidecarBatchVerifier`` slots UNDER the

@@ -14,23 +14,22 @@ client only distinguishes the failure KINDS the policy needs:
   The daemon is HEALTHY and saying "not now"; the caller verifies this
   batch in-process but does not penalize the breaker for it.
 
-One background reader thread demultiplexes responses to waiters by
-request id; callers block on their own event with their own deadline,
-so a slow joint dispatch never heads-of-line-blocks a Ping. Reconnects
-are lazy (next request attempts) with a flat backoff window so a dead
-daemon costs one failed ``connect()`` per window, not one per verify.
+The connection itself — backoff, handshake (with the retry at v1 when
+an old daemon refuses a v2 Hello), waiters, deadlines, ``ping`` and
+``stats`` — is :class:`tmtpu.libs.framed.client.FramedClient`.
 """
 
 from __future__ import annotations
 
-import itertools
-import json
 import os
-import socket
-import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from tmtpu.libs import metrics as _m
+from tmtpu.libs.framed.client import (
+    DaemonUnavailable,
+    FramedClient,
+)
 from tmtpu.sidecar import protocol as proto
 
 ENV_ADDR = "TMTPU_SIDECAR_ADDR"
@@ -52,7 +51,7 @@ class SidecarError(Exception):
     pass
 
 
-class SidecarUnavailable(SidecarError):
+class SidecarUnavailable(SidecarError, DaemonUnavailable):
     """Daemon unreachable / dead connection / deadline / hard error."""
 
 
@@ -60,198 +59,28 @@ class SidecarOverloaded(SidecarError):
     """Explicit backpressure: daemon healthy but queues are full."""
 
 
-class _Waiter:
-    __slots__ = ("event", "reply", "error")
+class SidecarClient(FramedClient):
+    proto = proto
+    prefix = "sidecar"
+    unavailable = SidecarUnavailable
+    reconnects = _m.sidecar_client_reconnects
+    up = _m.sidecar_client_up
 
-    def __init__(self):
-        self.event = threading.Event()
-        self.reply = None
-        self.error: Optional[Exception] = None
-
-
-class SidecarClient:
     def __init__(self, addr: str, *,
                  client_id: str = "",
                  connect_timeout_s: float = 2.0,
                  request_deadline_s: float = 10.0,
                  retry_backoff_s: float = 1.0,
                  max_frame_bytes: int = proto.DEFAULT_MAX_FRAME_BYTES):
-        self.addr = addr
-        self.client_id = client_id or f"pid-{os.getpid()}"
-        self._connect_timeout_s = connect_timeout_s
-        self._request_deadline_s = request_deadline_s
-        self._retry_backoff_s = retry_backoff_s
-        self._max_frame_bytes = max_frame_bytes
-        self._sock: Optional[socket.socket] = None
-        self._rfile = None
-        self._wlock = threading.Lock()
-        self._conn_lock = threading.Lock()
-        self._waiters: Dict[int, _Waiter] = {}
-        self._waiters_lock = threading.Lock()
-        self._seq = itertools.count(1)
-        self._last_connect_fail = 0.0
-        self.hello_ack: Optional[proto.HelloAck] = None
+        super().__init__(addr, client_id=client_id,
+                         connect_timeout_s=connect_timeout_s,
+                         request_deadline_s=request_deadline_s,
+                         retry_backoff_s=retry_backoff_s,
+                         max_frame_bytes=max_frame_bytes)
 
-    # --- connection management ---
-
-    def connected(self) -> bool:
-        return self._sock is not None
-
-    def _ensure_connected(self) -> None:
-        if self._sock is not None:
-            return
-        with self._conn_lock:
-            if self._sock is not None:
-                return
-            now = time.monotonic()
-            if now - self._last_connect_fail < self._retry_backoff_s:
-                raise SidecarUnavailable(
-                    f"sidecar {self.addr}: in connect backoff")
-            try:
-                self._connect_locked()
-            except (OSError, proto.ProtocolError, EOFError,
-                    ValueError) as exc:
-                self._last_connect_fail = time.monotonic()
-                raise SidecarUnavailable(
-                    f"sidecar {self.addr}: {exc}") from exc
-
-    def _connect_locked(self) -> None:
-        from tmtpu.libs import metrics as _m
-
-        _m.sidecar_client_reconnects.inc()
-        kind, target = proto.parse_addr(self.addr)
-        if kind == "unix":
-            sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        else:
-            sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        sock.settimeout(self._connect_timeout_s)
-        sock.connect(target)
-        rfile = sock.makefile("rb")
-        reader = proto.FrameReader(rfile, self._max_frame_bytes)
-        sock.sendall(proto.encode_frame(proto.Hello(
-            version=proto.PROTOCOL_VERSION, client_id=self.client_id,
-            features=["verify", "tally"])))
-        ack = reader.read_msg()
-        if isinstance(ack, proto.ErrorReply) and \
-                ack.code == proto.ERR_VERSION and \
-                proto.PROTOCOL_VERSION > min(proto.SUPPORTED_VERSIONS):
-            # version-skew tolerance: an old daemon hard-rejects a newer
-            # Hello (pre-v2 daemons knew no negotiation), so retry the
-            # handshake once at the oldest version we still speak. The
-            # old daemon closes the rejected connection, so reconnect.
-            try:
-                sock.close()
-            except OSError:
-                pass
-            if kind == "unix":
-                sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-            else:
-                sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-            sock.settimeout(self._connect_timeout_s)
-            sock.connect(target)
-            rfile = sock.makefile("rb")
-            reader = proto.FrameReader(rfile, self._max_frame_bytes)
-            sock.sendall(proto.encode_frame(proto.Hello(
-                version=min(proto.SUPPORTED_VERSIONS),
-                client_id=self.client_id,
-                features=["verify", "tally"])))
-            ack = reader.read_msg()
-        if isinstance(ack, proto.ErrorReply):
-            raise SidecarUnavailable(
-                f"sidecar rejected handshake (code {ack.code}): "
-                f"{ack.message}")
-        if not isinstance(ack, proto.HelloAck):
-            raise proto.ProtocolError(
-                f"expected HelloAck, got {type(ack).__name__}")
-        sock.settimeout(None)  # reader thread blocks; waiters time out
-        self.hello_ack = ack
-        self._sock = sock
-        self._rfile = rfile
-        _m.sidecar_client_up.set(1.0)
-        threading.Thread(target=self._read_loop, args=(reader, sock),
-                         name="sidecar-client-read",
-                         daemon=True).start()
-
-    def close(self) -> None:
-        with self._conn_lock:
-            self._teardown(SidecarUnavailable("client closed"))
-
-    def _teardown(self, err: Exception) -> None:
-        from tmtpu.libs import metrics as _m
-
-        sock, self._sock = self._sock, None
-        self._rfile = None
-        self.hello_ack = None
-        if sock is not None:
-            try:
-                sock.close()
-            except OSError:
-                pass
-            _m.sidecar_client_up.set(0.0)
-        with self._waiters_lock:
-            waiters, self._waiters = self._waiters, {}
-        for w in waiters.values():
-            w.error = err
-            w.event.set()
-
-    def _read_loop(self, reader: proto.FrameReader,
-                   sock: socket.socket) -> None:
-        try:
-            while True:
-                msg = reader.read_msg()
-                rid = getattr(msg, "request_id",
-                              getattr(msg, "nonce", 0))
-                if isinstance(msg, proto.ErrorReply) and rid == 0:
-                    raise SidecarUnavailable(
-                        f"sidecar connection error {msg.code}: "
-                        f"{msg.message}")
-                with self._waiters_lock:
-                    w = self._waiters.pop(rid, None)
-                if w is not None:
-                    w.reply = msg
-                    w.event.set()
-                # unmatched reply: waiter already timed out — drop it
-        except (EOFError, OSError, proto.ProtocolError,
-                SidecarUnavailable) as exc:
-            with self._conn_lock:
-                if self._sock is sock:
-                    self._teardown(SidecarUnavailable(
-                        f"sidecar connection lost: {exc}"))
-
-    # --- request primitives ---
-
-    def _roundtrip(self, rid: int, msg, deadline_s: float):
-        w = _Waiter()
-        with self._waiters_lock:
-            self._waiters[rid] = w
-        sock = None
-        try:
-            data = proto.encode_frame(msg)
-            sock = self._sock
-            if sock is None:
-                raise SidecarUnavailable("sidecar not connected")
-            with self._wlock:
-                sock.sendall(data)
-        except BaseException as exc:
-            # every failure path must unregister the waiter, including
-            # the sock-is-None raise (else a connect race leaks it)
-            with self._waiters_lock:
-                self._waiters.pop(rid, None)
-            if isinstance(exc, OSError):
-                with self._conn_lock:
-                    if self._sock is sock:
-                        self._teardown(SidecarUnavailable(str(exc)))
-                raise SidecarUnavailable(
-                    f"sidecar send failed: {exc}") from exc
-            raise
-        if not w.event.wait(deadline_s):
-            with self._waiters_lock:
-                self._waiters.pop(rid, None)
-            raise SidecarUnavailable(
-                f"sidecar request deadline ({deadline_s:.3f}s) exceeded")
-        if w.error is not None:
-            raise SidecarUnavailable(str(w.error)) from w.error
-        return w.reply
+    def _hello(self, version: int) -> proto.Hello:
+        return proto.Hello(version=version, client_id=self.client_id,
+                           features=["verify", "tally"])
 
     # --- public API ---
 
@@ -275,7 +104,6 @@ class SidecarClient:
         (libs.trace.activate) and the daemon speaks v2, the context
         rides the request so the daemon's joint dispatch is attributable
         to the height that caused it."""
-        from tmtpu.libs import metrics as _m
         from tmtpu.libs import trace as _trace
 
         deadline_s = deadline_s or self._request_deadline_s
@@ -296,7 +124,7 @@ class SidecarClient:
             trace_ctx=ctx_bytes)
         t0 = time.perf_counter()
         try:
-            reply = self._roundtrip(rid, req, deadline_s)
+            reply = self._await(rid, self._send(rid, req), deadline_s)
         except SidecarUnavailable:
             _m.sidecar_client_requests.inc(curve=curve, status="error")
             raise
@@ -324,25 +152,3 @@ class SidecarClient:
                 "dispatch_clients": reply.dispatch_clients,
                 "dispatch_traces": reply.dispatch_traces}
         return mask, reply.tallied, info
-
-    def ping(self, deadline_s: Optional[float] = None) -> proto.Pong:
-        self._ensure_connected()
-        nonce = next(self._seq)
-        reply = self._roundtrip(nonce, proto.Ping(nonce=nonce),
-                                deadline_s or self._request_deadline_s)
-        if not isinstance(reply, proto.Pong):
-            raise SidecarUnavailable(
-                f"unexpected reply {type(reply).__name__}")
-        return reply
-
-    def stats(self, deadline_s: Optional[float] = None) -> Dict:
-        """Daemon introspection snapshot. StatsResponse carries no id,
-        so stats calls serialize on request id 0 — fine for a debug
-        endpoint."""
-        self._ensure_connected()
-        reply = self._roundtrip(0, proto.StatsRequest(),
-                                deadline_s or self._request_deadline_s)
-        if not isinstance(reply, proto.StatsResponse):
-            raise SidecarUnavailable(
-                f"unexpected reply {type(reply).__name__}")
-        return json.loads(reply.stats_json.decode())

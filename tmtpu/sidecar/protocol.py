@@ -1,28 +1,18 @@
-"""Sidecar wire protocol: length-prefixed frames carrying typed messages.
+"""Sidecar wire protocol: the sidecar's namespace of framed messages.
 
-Framing (reuses :mod:`tmtpu.libs.protoio` primitives):
-
-    frame   = uvarint(len(body)) || body
-    body    = type_byte || payload
-    payload = protobuf encoding of the message class for type_byte
-
-One byte of type tag inside the length prefix keeps the stream
-self-describing without a wrapper message, and lets the reader reject
-unknown or oversized frames before decoding a single field. Both sides
+The frame, the handshake and the codes shared with every framed daemon
+are :mod:`tmtpu.libs.framed.protocol`'s; this module holds the
+sidecar's message classes, its versions and its frame cap. Both sides
 enforce ``max_frame_bytes`` (default 8 MiB) — a VerifyRequest for 40960
 lanes of (32B pk, ~110B msg, 64B sig) is ~8.5 MB, so real deployments
 raise the cap in lockstep with ``max_lanes_per_dispatch``; the default
 covers the 10k-validator north-star with headroom.
 
-Handshake: client sends :class:`Hello` first; server answers
-:class:`HelloAck` carrying the NEGOTIATED version (min of both sides,
-``SUPPORTED_VERSIONS`` only) or :class:`ErrorReply` (``ERR_VERSION``)
-and closes on an unsupported version. Anything else as a first message
-is a protocol error. ``PROTOCOL_VERSION`` bumps on any wire change;
-since v2 the daemon keeps serving v1 clients (version-skew tolerance:
-an old client on a new daemon just never sees the v2-only optional
-fields), and a v2 client that gets ``ERR_VERSION`` from a v1 daemon
-retries the handshake at version 1.
+``PROTOCOL_VERSION`` bumps on any wire change; since v2 the daemon keeps
+serving v1 clients (version-skew tolerance: an old client on a new
+daemon just never sees the v2-only optional fields), and a v2 client
+that gets ``ERR_VERSION`` from a v1 daemon retries the handshake at
+version 1.
 
 Version history:
 - v1: Hello/HelloAck/Verify/Ping/Stats base protocol.
@@ -38,14 +28,24 @@ lane i's verdict is bit ``i & 7`` of byte ``i >> 3``, LSB-first —
 
 from __future__ import annotations
 
-import io
-from typing import Dict, List, Optional, Tuple, Type
+from typing import Dict, List, Type
 
-from tmtpu.libs.protoio import (
-    DelimitedReader,
-    ProtoMessage,
-    encode_uvarint,
+from tmtpu.libs.framed.protocol import (  # noqa: F401 — the shared codes
+    ERR_PROTOCOL,
+    ERR_VERSION,
+    STATUS_BAD_REQUEST,
+    STATUS_NAMES as _SHARED_NAMES,
+    STATUS_OK,
+    STATUS_OVERLOADED,
+    Codec,
+    ErrorReply,
+    Ping,
+    ProtocolError,
+    StatsRequest,
+    StatsResponse,
+    parse_addr,
 )
+from tmtpu.libs.protoio import ProtoMessage
 
 PROTOCOL_VERSION = 2
 # every version this tree still speaks; the daemon accepts any of them
@@ -58,25 +58,10 @@ TRACE_CTX_MIN_VERSION = 2
 # sides always enforce *some* cap so a corrupt length prefix can't OOM.
 DEFAULT_MAX_FRAME_BYTES = 8 * 1024 * 1024
 
-# --- VerifyResponse.status ---
-STATUS_OK = 0
-STATUS_OVERLOADED = 1      # admission control rejected; retry or fall back
+# --- VerifyResponse.status: the shared codes plus ---
 STATUS_BACKEND_DOWN = 2    # device breaker open server-side; served serially
-STATUS_BAD_REQUEST = 3     # unknown curve, zero lanes, malformed lane
-STATUS_SHUTTING_DOWN = 4   # daemon draining; do not resubmit
 
-STATUS_NAMES = {
-    STATUS_OK: "ok",
-    STATUS_OVERLOADED: "overloaded",
-    STATUS_BACKEND_DOWN: "backend_down",
-    STATUS_BAD_REQUEST: "bad_request",
-    STATUS_SHUTTING_DOWN: "shutting_down",
-}
-
-# --- ErrorReply.code ---
-ERR_VERSION = 1        # Hello.version not in SUPPORTED_VERSIONS
-ERR_PROTOCOL = 2       # bad frame / unexpected message sequence
-ERR_INTERNAL = 3       # server bug; connection stays usable
+STATUS_NAMES = {**_SHARED_NAMES, STATUS_BACKEND_DOWN: "backend_down"}
 
 
 class Hello(ProtoMessage):
@@ -139,35 +124,11 @@ class VerifyResponse(ProtoMessage):
     ]
 
 
-class Ping(ProtoMessage):
-    FIELDS = [(1, "nonce", "uint64")]
-
-
 class Pong(ProtoMessage):
     FIELDS = [
         (1, "nonce", "uint64"),
         (2, "backend", "string"),
         (3, "uptime_ms", "uint64"),
-    ]
-
-
-class StatsRequest(ProtoMessage):
-    FIELDS = []
-
-
-class StatsResponse(ProtoMessage):
-    """Introspection snapshot; ``stats_json`` is a JSON object so the
-    payload can grow without protocol bumps (it is advisory, not
-    consensus-critical)."""
-
-    FIELDS = [(1, "stats_json", "bytes")]
-
-
-class ErrorReply(ProtoMessage):
-    FIELDS = [
-        (1, "request_id", "uint64"),         # 0 when not tied to a request
-        (2, "code", "uint32"),
-        (3, "message", "string"),
     ]
 
 
@@ -185,75 +146,12 @@ MESSAGE_TYPES: Dict[int, Type[ProtoMessage]] = {
     9: ErrorReply,
 }
 
-TYPE_BYTES: Dict[Type[ProtoMessage], int] = {
-    cls: tb for tb, cls in MESSAGE_TYPES.items()
-}
-
-
-class ProtocolError(Exception):
-    """Raised on malformed frames, unknown types, or bad sequencing."""
-
-
-def encode_frame(msg: ProtoMessage,
-                 type_bytes: Optional[Dict[Type[ProtoMessage], int]] = None
-                 ) -> bytes:
-    """Encode one frame. ``type_bytes`` defaults to the sidecar registry;
-    sibling frame protocols (tmtpu/lightserve) pass their own class→tag
-    map to reuse the codec without sharing a wire namespace."""
-    tb = (TYPE_BYTES if type_bytes is None else type_bytes).get(type(msg))
-    if tb is None:
-        raise ProtocolError(f"unregistered message type {type(msg).__name__}")
-    body = bytes([tb]) + msg.encode()
-    return encode_uvarint(len(body)) + body
-
-
-def decode_frame(body: bytes,
-                 message_types: Optional[Dict[int, Type[ProtoMessage]]] = None
-                 ) -> ProtoMessage:
-    """Decode one frame *body* (type byte + payload, length prefix already
-    stripped). ``message_types`` defaults to the sidecar registry; sibling
-    protocols pass their own tag→class map."""
-    if not body:
-        raise ProtocolError("empty frame")
-    cls = (MESSAGE_TYPES if message_types is None else message_types
-           ).get(body[0])
-    if cls is None:
-        raise ProtocolError(f"unknown message type {body[0]}")
-    try:
-        return cls.decode(body[1:])
-    except (EOFError, ValueError, UnicodeDecodeError) as exc:
-        raise ProtocolError(
-            f"malformed {cls.__name__} payload: {exc}") from exc
-
-
-class FrameReader:
-    """Reads framed messages from a binary stream, enforcing the frame cap.
-
-    Thin veneer over :class:`protoio.DelimitedReader`; EOF mid-frame
-    surfaces as ``EOFError`` (peer went away), anything else malformed as
-    :class:`ProtocolError` so the connection loop can answer
-    ``ERR_PROTOCOL`` before closing. ``message_types`` selects the tag
-    registry (defaults to the sidecar's).
-    """
-
-    def __init__(self, stream, max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES,
-                 message_types: Optional[Dict[int,
-                                              Type[ProtoMessage]]] = None):
-        self._rd = DelimitedReader(stream, max_size=max_frame_bytes)
-        self._message_types = message_types
-
-    def read_body(self) -> bytes:
-        """Block until one whole frame body has arrived."""
-        try:
-            return self._rd.read_msg()
-        except ValueError as exc:  # oversized frame / runaway varint
-            raise ProtocolError(str(exc)) from exc
-
-    def decode(self, body: bytes) -> ProtoMessage:
-        return decode_frame(body, self._message_types)
-
-    def read_msg(self) -> ProtoMessage:
-        return self.decode(self.read_body())
+CODEC = Codec(MESSAGE_TYPES, DEFAULT_MAX_FRAME_BYTES)
+TYPE_BYTES = CODEC.type_bytes
+encode_frame = CODEC.encode_frame
+decode_frame = CODEC.decode_frame
+write_frame = CODEC.write_frame
+FrameReader = CODEC.reader
 
 
 def pack_mask(mask: List[bool]) -> bytes:
@@ -269,30 +167,3 @@ def unpack_mask(packed: bytes, lane_count: int) -> List[bool]:
         raise ProtocolError(
             f"mask too short: {len(packed)} bytes for {lane_count} lanes")
     return [bool(packed[i >> 3] & (1 << (i & 7))) for i in range(lane_count)]
-
-
-def write_frame(stream: io.RawIOBase, msg: ProtoMessage,
-                type_bytes: Optional[Dict[Type[ProtoMessage], int]] = None
-                ) -> None:
-    stream.write(encode_frame(msg, type_bytes))
-    flush = getattr(stream, "flush", None)
-    if flush is not None:
-        flush()
-
-
-def parse_addr(addr: str) -> Tuple[str, object]:
-    """Parse ``unix:///path/to.sock`` or ``tcp://host:port`` into
-    ``("unix", path)`` / ``("tcp", (host, port))``."""
-    if addr.startswith("unix://"):
-        path = addr[len("unix://"):]
-        if not path:
-            raise ValueError(f"empty unix socket path in {addr!r}")
-        return "unix", path
-    if addr.startswith("tcp://"):
-        hostport = addr[len("tcp://"):]
-        host, sep, port = hostport.rpartition(":")
-        if not sep or not host:
-            raise ValueError(f"tcp address needs host:port: {addr!r}")
-        return "tcp", (host, int(port))
-    raise ValueError(
-        f"sidecar address must be unix:// or tcp://, got {addr!r}")

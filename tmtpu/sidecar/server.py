@@ -1,4 +1,4 @@
-"""The sidecar daemon: socket listener, protocol loop, verify engine.
+"""The sidecar daemon: the verify engine behind the framed server.
 
 One daemon process owns the JAX device for a whole host — the ONLY
 process on the chip: its clients never open JAX. It compiles the
@@ -17,12 +17,10 @@ daemon never returns a wrong mask: device failure degrades to the
 engine's exact serial re-verify, and engine failure degrades to an
 error verdict the client treats as "no answer, verify locally".
 
-Introspection: ``Ping``/``StatsRequest`` on the protocol socket, plus
-an optional HTTP listener (``health_laddr``) serving ``/healthz``
-(JSON snapshot, 200/503 by backend-breaker state), ``/metrics``
-(Prometheus text) for curl/scrapers that don't speak the frame
-protocol, and ``/debug/profile?seconds=N`` (a ``jax.profiler`` trace of
-the daemon, the program's spans in it).
+The socket side, ``Ping``/``StatsRequest`` and the health listener are
+:class:`tmtpu.libs.framed.server.FramedServer`'s; here ``/healthz`` is
+200/503 by the backend breaker, and ``/debug/profile?seconds=N`` is a
+``jax.profiler`` trace of the daemon, the program's spans in it.
 
 Run it: ``python -m tmtpu sidecar --addr unix:///tmp/tmtpu-sidecar.sock``
 (cmd/__main__.py), point nodes at it with ``crypto.backend=sidecar``.
@@ -30,9 +28,7 @@ Run it: ``python -m tmtpu sidecar --addr unix:///tmp/tmtpu-sidecar.sock``
 
 from __future__ import annotations
 
-import json
 import os
-import socket
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -43,19 +39,22 @@ from tmtpu.crypto import encoding as _enc  # noqa: F401 — registers all
 # against that registry before anything else imports the curve modules)
 from tmtpu.crypto.keys import KEY_TYPES
 from tmtpu.libs import breaker as _bk
+from tmtpu.libs import metrics as _m
 from tmtpu.libs import trace
+from tmtpu.libs.framed.gather import failure_status
+from tmtpu.libs.framed.server import FramedServer
 from tmtpu.sidecar import protocol as proto
 from tmtpu.sidecar.coalescer import Coalescer, Overloaded
 from tmtpu.tpu import compat
 
-_FAILURE_STATUS = {
-    "expired": proto.STATUS_OVERLOADED,
-    "engine": proto.STATUS_BACKEND_DOWN,
-    "stopped": proto.STATUS_SHUTTING_DOWN,
-}
 
+class SidecarServer(FramedServer):
+    proto = proto
+    prefix = "sidecar"
+    connections = _m.sidecar_server_connections
+    requests = _m.sidecar_server_requests
+    protocol_errors = _m.sidecar_server_protocol_errors
 
-class SidecarServer:
     def __init__(self, addr: str, *,
                  backend: str = "auto",
                  max_queue_lanes: int = 65536,
@@ -67,8 +66,6 @@ class SidecarServer:
                  mesh_devices: Optional[int] = None,
                  shard_min_lanes: Optional[int] = None,
                  profile_dir: str = ""):
-        self.addr = addr
-        self._kind, self._target = proto.parse_addr(addr)
         if backend not in ("auto", "cpu", "tpu"):
             raise ValueError(
                 f"sidecar daemon backend must be auto/cpu/tpu, got "
@@ -79,26 +76,17 @@ class SidecarServer:
         self._mesh_devices = mesh_devices
         self._shard_min_lanes = shard_min_lanes
         self._max_lanes_per_dispatch = max_lanes_per_dispatch
-        self._max_frame_bytes = max_frame_bytes
         self._default_deadline_s = request_deadline_s
-        self._health_laddr = health_laddr
+        super().__init__(addr, max_frame_bytes=max_frame_bytes,
+                         health_laddr=health_laddr, server_id=server_id)
         # where GET /debug/profile writes (cmd_sidecar: <home>/data/profile)
         self._profile_dir = profile_dir
         self._profile_lock = threading.Lock()
-        self.server_id = server_id or f"sidecar-{os.getpid()}"
         self.coalescer = Coalescer(
             self._engine_verify,
             max_queue_lanes=max_queue_lanes,
             max_lanes_per_dispatch=max_lanes_per_dispatch)
-        self._listener: Optional[socket.socket] = None
-        self._accept_thread: Optional[threading.Thread] = None
-        self._health_httpd = None
-        self._health_thread: Optional[threading.Thread] = None
-        self._conns: set = set()
-        self._conns_lock = threading.Lock()
-        self._running = False
-        self._draining = False
-        self._started_at = 0.0
+        self._routes[proto.VerifyRequest] = ("verify", self._handle_verify)
         self._warmed = False
         # (curve, lanes, tally, seconds) per warm() flush
         self.warmed_shapes: List[tuple] = []
@@ -195,34 +183,9 @@ class SidecarServer:
             self._profile_lock.release()
         return self._profile_dir
 
-    # --- lifecycle ---
+    # --- what the framed core asks of the daemon ---
 
-    def start(self) -> None:
-        if self._running:
-            return
-        if self._kind == "unix":
-            path = self._target
-            try:
-                os.unlink(path)
-            except FileNotFoundError:
-                pass
-            sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-            sock.bind(path)
-        else:
-            host, port = self._target
-            sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-            sock.bind((host, port))
-            if port == 0:
-                # ephemeral-port bind: rewrite addr so clients/tests can
-                # read the real endpoint back off server.addr
-                port = sock.getsockname()[1]
-                self._target = (host, port)
-                self.addr = f"tcp://{host}:{port}"
-        sock.listen(64)
-        self._listener = sock
-        self._running = True
-        self._started_at = time.monotonic()
+    def _start_engine(self) -> None:
         if self._mesh_devices is not None or \
                 self._shard_min_lanes is not None:
             from tmtpu.tpu import mesh_dispatch as _mesh
@@ -230,88 +193,38 @@ class SidecarServer:
             _mesh.set_overrides(mesh_devices=self._mesh_devices,
                                 shard_min_lanes=self._shard_min_lanes)
         self.coalescer.start()
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, name="sidecar-accept", daemon=True)
-        self._accept_thread.start()
-        if self._health_laddr:
-            self._start_health_http()
 
-    def drain(self, timeout: float = 30.0) -> bool:
-        """Graceful-shutdown phase one (the SIGTERM path): stop taking
-        new work, finish what's in flight. Closes the listener, answers
-        every subsequent VerifyRequest with STATUS_OVERLOADED (clients
-        treat ONLY overload as penalty-free fallback — a drain must not
-        cost every connected node a breaker-worth of errors), and blocks
-        until the coalescer has dispatched its queue and answered every
-        in-flight joint batch, or the timeout passes (returns False).
-        Ping/Stats keep working throughout. Call stop() afterwards."""
-        self._draining = True
-        listener, self._listener = self._listener, None
-        if listener is not None:
-            try:
-                listener.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                listener.close()
-            except OSError:
-                pass
-        return self.coalescer.drain(timeout)
+    def _hello_ack(self, hello: proto.Hello, version: int):
+        return proto.HelloAck(
+            version=version, server_id=self.server_id,
+            backend=self.backend_name(),
+            max_lanes=self._max_lanes_per_dispatch,
+            max_frame_bytes=self._max_frame_bytes)
 
-    def stop(self) -> None:
-        self._running = False
-        if self._listener is not None:
-            # shutdown() before close(): close() alone does not wake a
-            # thread blocked in accept(), which would leave stop() eating
-            # the full accept-thread join timeout
-            try:
-                self._listener.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                self._listener.close()
-            except OSError:
-                pass
-            self._listener = None
-        with self._conns_lock:
-            conns = list(self._conns)
-        for c in conns:
-            try:
-                c.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                c.close()
-            except OSError:
-                pass
-        self.coalescer.stop()
-        if self._health_httpd is not None:
-            try:
-                self._health_httpd.shutdown()
-                self._health_httpd.server_close()
-            except Exception:  # noqa: BLE001 — best-effort teardown
-                pass
-            self._health_httpd = None
-        ht = self._health_thread
-        if ht is not None and ht is not threading.current_thread():
-            ht.join(timeout=2.0)
-        if self._accept_thread is not None:
-            self._accept_thread.join(timeout=5.0)
-            self._accept_thread = None
-        if self._kind == "unix":
-            try:
-                os.unlink(self._target)
-            except OSError:
-                pass
+    def _pong(self, nonce: int, uptime_ms: int) -> proto.Pong:
+        return proto.Pong(nonce=nonce, backend=self.backend_name(),
+                          uptime_ms=uptime_ms)
+
+    def _health(self) -> Tuple[bool, Dict]:
+        snap = self.snapshot()
+        br = snap["breakers"].get(crypto_batch.BREAKER_NAME, {})
+        return br.get("state", "closed") != "open", snap
+
+    def _http_get(self, path: str) -> Optional[Tuple[int, Dict]]:
+        if not path.startswith("/debug/profile"):
+            return None
+        from urllib.parse import parse_qs, urlparse
+
+        query = parse_qs(urlparse(path).query)
+        try:
+            seconds = float(query.get("seconds", ["5"])[0])
+            return 200, {"seconds": seconds, "dir": self.profile(seconds)}
+        except (ValueError, RuntimeError) as exc:
+            return 409, {"error": str(exc)}
 
     def snapshot(self) -> Dict:
-        from tmtpu.libs import metrics as _m
-
-        with self._conns_lock:
-            n_conns = len(self._conns)
         return {
-            "server_id": self.server_id,
-            "addr": self.addr,
+            **super().snapshot(),
             "backend": self.backend_name(),
             # as JAX reports it; empty for the serial engine (no JAX)
             "device": (compat.device_info() if self._device_engine()
@@ -327,11 +240,6 @@ class SidecarServer:
                 {"curve": c, "lanes": b, "tally": t,
                  "seconds": round(sec, 3)}
                 for c, b, t, sec in self.warmed_shapes],
-            "draining": self._draining,
-            "uptime_s": round(max(0.0, time.monotonic() -
-                                  self._started_at), 3),
-            "connections": n_conns,
-            "coalescer": self.coalescer.snapshot(),
             "mesh": __import__(
                 "tmtpu.tpu.mesh_dispatch",
                 fromlist=["snapshot"]).snapshot(),
@@ -340,134 +248,10 @@ class SidecarServer:
                 "tmtpu.crypto.sigcache", fromlist=["stats"]).stats(),
         }
 
-    # --- connection handling ---
-
-    def _accept_loop(self) -> None:
-        from tmtpu.libs import metrics as _m
-
-        while self._running:
-            listener = self._listener
-            if listener is None:
-                return
-            try:
-                conn, _peer = listener.accept()
-            except OSError:
-                return  # listener closed
-            with self._conns_lock:
-                self._conns.add(conn)
-                _m.sidecar_server_connections.set(len(self._conns))
-            threading.Thread(target=self._serve_conn, args=(conn,),
-                             name="sidecar-conn", daemon=True).start()
-
-    def _drop_conn(self, conn) -> None:
-        from tmtpu.libs import metrics as _m
-
-        with self._conns_lock:
-            self._conns.discard(conn)
-            _m.sidecar_server_connections.set(len(self._conns))
-        try:
-            conn.close()
-        except OSError:
-            pass
-
-    def _serve_conn(self, conn: socket.socket) -> None:
-        from tmtpu.libs import metrics as _m
-
-        rfile = conn.makefile("rb")
-        wlock = threading.Lock()
-
-        def send(msg) -> None:
-            # a frame already encoded (the verify reply's) goes as it is
-            data = msg if isinstance(msg, bytes) else proto.encode_frame(msg)
-            with wlock:
-                conn.sendall(data)
-
-        reader = proto.FrameReader(rfile, self._max_frame_bytes)
-        try:
-            # handshake: Hello first, version within SUPPORTED_VERSIONS
-            try:
-                first = reader.read_msg()
-            except proto.ProtocolError as exc:
-                _m.sidecar_server_protocol_errors.inc(kind="bad-frame")
-                try:
-                    send(proto.ErrorReply(code=proto.ERR_PROTOCOL,
-                                          message=str(exc)))
-                except OSError:
-                    pass
-                return
-            if not isinstance(first, proto.Hello):
-                _m.sidecar_server_protocol_errors.inc(kind="no-hello")
-                send(proto.ErrorReply(
-                    code=proto.ERR_PROTOCOL,
-                    message=f"expected Hello, got "
-                            f"{type(first).__name__}"))
-                return
-            if first.version not in proto.SUPPORTED_VERSIONS:
-                _m.sidecar_server_protocol_errors.inc(
-                    kind="version-mismatch")
-                send(proto.ErrorReply(
-                    code=proto.ERR_VERSION,
-                    message=f"protocol version {first.version} not in "
-                            f"server-supported "
-                            f"{list(proto.SUPPORTED_VERSIONS)}"))
-                return
-            # version-skew tolerance: serve old clients at their version
-            # (they never see v2-only optional fields anyway — unknown
-            # fields are skipped — but the ack tells THEM not to send any)
-            negotiated = min(first.version, proto.PROTOCOL_VERSION)
-            client_id = first.client_id or "anon"
-            _m.sidecar_server_requests.inc(type="hello")
-            send(proto.HelloAck(
-                version=negotiated,
-                server_id=self.server_id,
-                backend=self.backend_name(),
-                max_lanes=self._max_lanes_per_dispatch,
-                max_frame_bytes=self._max_frame_bytes))
-            while self._running:
-                items = None
-                try:
-                    # the wait for the client's next frame gets no span
-                    body = reader.read_body()
-                    with trace.span("sidecar.conn.decode",
-                                    bytes=len(body)):
-                        msg = reader.decode(body)
-                        if isinstance(msg, proto.VerifyRequest):
-                            items = [(ln.pub_key, ln.msg, ln.sig, ln.power)
-                                     for ln in msg.lanes]
-                except proto.ProtocolError as exc:
-                    _m.sidecar_server_protocol_errors.inc(kind="bad-frame")
-                    try:
-                        send(proto.ErrorReply(code=proto.ERR_PROTOCOL,
-                                              message=str(exc)))
-                    except OSError:
-                        pass
-                    return  # framing is lost; the stream cannot recover
-                if isinstance(msg, proto.VerifyRequest):
-                    _m.sidecar_server_requests.inc(type="verify")
-                    self._handle_verify(client_id, msg, items, send)
-                elif isinstance(msg, proto.Ping):
-                    _m.sidecar_server_requests.inc(type="ping")
-                    send(proto.Pong(
-                        nonce=msg.nonce, backend=self.backend_name(),
-                        uptime_ms=int((time.monotonic() -
-                                       self._started_at) * 1000)))
-                elif isinstance(msg, proto.StatsRequest):
-                    _m.sidecar_server_requests.inc(type="stats")
-                    send(proto.StatsResponse(stats_json=json.dumps(
-                        self.snapshot()).encode()))
-                else:
-                    _m.sidecar_server_protocol_errors.inc(
-                        kind="unexpected-type")
-                    send(proto.ErrorReply(
-                        code=proto.ERR_PROTOCOL,
-                        message=f"unexpected {type(msg).__name__}"))
-        except (EOFError, OSError, BrokenPipeError):
-            pass  # peer went away
-        finally:
-            self._drop_conn(conn)
-
     def _handle_verify(self, client_id: str, req: proto.VerifyRequest,
-                       items: List[tuple], send) -> None:
+                       send) -> None:
+        items = [(ln.pub_key, ln.msg, ln.sig, ln.power) for ln in req.lanes]
+
         def reject(status: int, error: str) -> None:
             send(proto.VerifyResponse(
                 request_id=req.request_id, status=status,
@@ -496,8 +280,6 @@ class SidecarServer:
         # (never rejected — the context is advisory, not load-bearing)
         trace_ctx = None
         if req.trace_ctx:
-            from tmtpu.libs import metrics as _m
-
             trace_ctx = trace.adopt(bytes(req.trace_ctx))
             if trace_ctx is None:
                 _m.trace_context_invalid.inc(transport="sidecar")
@@ -515,19 +297,13 @@ class SidecarServer:
             # grace over the request deadline: the coalescer answers
             # expiry itself; this wait only guards a wedged dispatch
             if not pending.wait(deadline_s + 5.0):
-                try:
-                    reject(proto.STATUS_BACKEND_DOWN,
-                           "dispatch wedged past deadline")
-                except OSError:
-                    pass
+                reject(proto.STATUS_BACKEND_DOWN,
+                       "dispatch wedged past deadline")
                 return
             if pending.mask is None:
-                status = _FAILURE_STATUS.get(
-                    pending.failure, proto.STATUS_BACKEND_DOWN)
-                try:
-                    reject(status, pending.error or "verify failed")
-                except OSError:
-                    pass
+                reject(failure_status(pending.failure,
+                                      proto.STATUS_BACKEND_DOWN),
+                       pending.error or "verify failed")
                 return
             with trace.span("sidecar.conn.encode",
                             lanes=len(pending.mask)):
@@ -541,71 +317,9 @@ class SidecarServer:
                     dispatch_lanes=pending.dispatch_lanes,
                     dispatch_clients=pending.dispatch_clients,
                     dispatch_traces=pending.dispatch_traces))
-            try:
-                send(frame)
-            except OSError:
-                pass  # client gone; the dispatch already happened
+            send(frame)
 
         # answer off-thread so the connection keeps reading — one client
         # can pipeline many request_ids and they coalesce with each other
         threading.Thread(target=finish, name="sidecar-reply",
                          daemon=True).start()
-
-    # --- health HTTP ---
-
-    def _start_health_http(self) -> None:
-        import http.server
-
-        server = self
-
-        class Handler(http.server.BaseHTTPRequestHandler):
-            def log_message(self, *a):  # quiet
-                pass
-
-            def do_GET(self):
-                if self.path.startswith("/healthz"):
-                    snap = server.snapshot()
-                    br = snap["breakers"].get(
-                        crypto_batch.BREAKER_NAME, {})
-                    healthy = br.get("state", "closed") != "open"
-                    body = json.dumps(
-                        {"healthy": healthy, **snap}).encode()
-                    self.send_response(200 if healthy else 503)
-                    ctype = "application/json"
-                elif self.path.startswith("/metrics"):
-                    from tmtpu.libs import metrics as _m
-
-                    body = _m.render_prometheus().encode()
-                    self.send_response(200)
-                    ctype = "text/plain; version=0.0.4"
-                elif self.path.startswith("/debug/profile"):
-                    from urllib.parse import parse_qs, urlparse
-
-                    query = parse_qs(urlparse(self.path).query)
-                    try:
-                        seconds = float(query.get("seconds", ["5"])[0])
-                        body = json.dumps({
-                            "seconds": seconds,
-                            "dir": server.profile(seconds)}).encode()
-                        self.send_response(200)
-                    except (ValueError, RuntimeError) as exc:
-                        body = json.dumps({"error": str(exc)}).encode()
-                        self.send_response(409)
-                    ctype = "application/json"
-                else:
-                    body = b"not found\n"
-                    self.send_response(404)
-                    ctype = "text/plain"
-                self.send_header("Content-Type", ctype)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        host, _sep, port = self._health_laddr.rpartition(":")
-        httpd = http.server.ThreadingHTTPServer(
-            (host or "127.0.0.1", int(port)), Handler)
-        self._health_httpd = httpd
-        self._health_thread = threading.Thread(
-            target=httpd.serve_forever, name="sidecar-health",
-            daemon=True)
-        self._health_thread.start()

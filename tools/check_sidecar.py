@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Thin shim over the unified lint engine (tmtpu/analysis).
 
-These checks now live in tmtpu/analysis/rules/sidecar.py as the
+These checks now live in tmtpu/analysis/rules/framed.py as the
 ``sidecar`` rule, running off the shared repo index with the other
 rules; suppressions (with reviewed justifications) live in
 tools/lint_baseline.json. This CLI is kept so the old entry point
